@@ -29,8 +29,8 @@ from distegnn_tpu.ops.graph import GraphBatch, _round_up, pad_graphs
 _file_open = open
 
 # bounded retry around dataset file reads: epoch-start reads off NFS/GCS see
-# transient ESTALE/EIO-style hiccups, and a multi-hour unattended session
-# (scripts/convergence_session.sh) must not die to one
+# transient ESTALE/EIO-style hiccups, and a multi-hour unattended run must
+# not die to one
 _OPEN_ATTEMPTS = 3
 _OPEN_BACKOFF_S = 0.1
 
@@ -151,7 +151,7 @@ class GraphDataset:
             self.graphs = list(source)
         # 'morton': relabel nodes along a Z curve of their positions — static
         # locality preprocessing for the gather/aggregation hot loop
-        # (ops/order.py; VERDICT r3 #1). Permutation-equivariant models see
+        # (ops/order.py). Permutation-equivariant models see
         # an identical problem with cache-friendly edge indices.
         if node_order == "morton":
             from distegnn_tpu.ops.order import morton_reorder_graph

@@ -233,7 +233,7 @@ class HoistedEdgeMLP(nn.Module):
     matmul over N rows instead of E (E/N = mean degree, ~15 at LargeFluid
     scale), and (c) gathers compute-dtype (bf16) products instead of f32
     features — all exactly the same math as MLP([H, H], act_last=True) on
-    the concat, in a cheaper order (BASELINE.md round-2 optimization list).
+    the concat, in a cheaper order.
     Parameters: one fused (2H+S, H) kernel + bias with torch nn.Linear
     defaults at the FULL fan-in, so init parity matches the fused Dense.
 

@@ -208,7 +208,7 @@ class EGCLVel(nn.Module):
     # stream dtype of the packed aggregation ('bf16' halves the [E,3+H] read
     # bytes; accumulation stays f32). bf16 ROUNDS THE COORDINATE
     # TRANSLATIONS — equivariance becomes approximate at bf16 noise level.
-    # Measured opt-in (VERDICT r3 #1), None = f32.
+    # Opt-in (its speed: not measured on this machine), None = f32.
     agg_dtype: Optional[str] = None
     # real-edge lowering: 'plain' = per-edge streams through EdgeOps (any
     # layout), 'fused' = ONE Pallas pass per layer over the blocked in-window

@@ -9,6 +9,7 @@ torch-sparse METIS to its other splitters."""
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,58 +18,84 @@ from typing import Optional
 import numpy as np
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libdistegnn_native.so")
+_BUILD_DIR = os.path.join(_NATIVE_DIR, "build")
+_LIB_PATH = os.path.join(_BUILD_DIR, "libdistegnn_native.so")
+_STAMP_PATH = _LIB_PATH + ".sha256"
+_SOURCES = ("partition.cpp", "blockify.cpp")
 _lock = threading.Lock()
 _lib = None
-_build_failed = False
+_build_error: Optional[str] = None
 
 
-_SOURCES = ("partition.cpp", "blockify.cpp")
+def _source_digest() -> str:
+    """sha256 over the sources' CONTENT: a copied or freshly checked-out tree
+    does not keep mtimes, so staleness cannot be read from them."""
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()
 
 
-def _build() -> Optional[str]:
-    os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+def _build(digest: str) -> None:
+    """Compile the library and stamp it with the sources' digest; raises
+    RuntimeError naming why (no g++, compile error, timeout)."""
+    os.makedirs(_BUILD_DIR, exist_ok=True)
     srcs = [os.path.join(_NATIVE_DIR, s) for s in _SOURCES]
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", *srcs, "-o", _LIB_PATH]
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", *srcs, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return _LIB_PATH
-    except (subprocess.SubprocessError, FileNotFoundError):
+    except FileNotFoundError as exc:
+        raise RuntimeError("g++ not found") from exc
+    except subprocess.CalledProcessError as exc:
+        raise RuntimeError(
+            f"g++ failed: {exc.stderr.decode(errors='replace')[-500:]}") from exc
+    except subprocess.TimeoutExpired as exc:
+        raise RuntimeError("g++ timed out after 120 s") from exc
+    os.replace(tmp, _LIB_PATH)   # atomic: a concurrent loader never sees half
+    with open(_STAMP_PATH, "w") as f:
+        f.write(digest)
+
+
+def _stamp() -> Optional[str]:
+    try:
+        with open(_STAMP_PATH) as f:
+            return f.read().strip()
+    except OSError:
         return None
 
 
 def load_native() -> Optional[ctypes.CDLL]:
-    """The native library, building it if needed; None if unavailable."""
-    global _lib, _build_failed
+    """The native library, (re)built when ``native/build`` holds none built
+    from the current sources; None if it cannot be built — the callers then
+    take their NumPy fallbacks, and :func:`native_status` says why."""
+    global _lib, _build_error
     with _lock:
-        if _lib is not None or _build_failed:
+        if _lib is not None or _build_error is not None:
             return _lib
         try:
-            newest = max(os.path.getmtime(os.path.join(_NATIVE_DIR, s))
-                         for s in _SOURCES)
-            if (not os.path.exists(_LIB_PATH)
-                    or os.path.getmtime(_LIB_PATH) < newest):
-                if _build() is None:
-                    _build_failed = True
-                    return None
-            lib = _bind(ctypes.CDLL(_LIB_PATH))
-        except (OSError, AttributeError):
-            # stale/incompatible cached .so (load failure OR missing symbols
-            # from an older build): rebuild once, else numpy fallback
-            try:
-                if _build() is None:
-                    raise OSError
-                lib = _bind(ctypes.CDLL(_LIB_PATH))
-            except (OSError, AttributeError):
-                _build_failed = True
-                return None
-        _lib = lib
+            digest = _source_digest()
+            if not os.path.exists(_LIB_PATH) or _stamp() != digest:
+                _build(digest)
+            _lib = _bind(ctypes.CDLL(_LIB_PATH))
+        except (OSError, AttributeError, RuntimeError) as exc:
+            _build_error = f"{type(exc).__name__}: {exc}"
         return _lib
 
 
+def native_status() -> str:
+    """'native' when the C++ library is loaded, else 'numpy fallback
+    (<reason>)' — what the entry points print so that a missing compiler is
+    visible, not silent."""
+    if load_native() is not None:
+        return "native"
+    return f"numpy fallback ({_build_error})"
+
+
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
-    """Declare every exported symbol's signature (raises AttributeError on a
-    library built from older sources — caller rebuilds)."""
+    """Declare every exported symbol's signature (AttributeError if one is
+    missing)."""
     lib.partition_graph.restype = ctypes.c_int
     lib.partition_graph.argtypes = [
         ctypes.c_int64,

@@ -1,9 +1,9 @@
 """Blocked-CSR edge aggregation — MXU kernels for the scatter/gather hot loop.
 
-Round-2 profiling (BASELINE.md "Step-time breakdown") showed the LargeFluid
-train step is NOT compute-bound: XLA's scatter-add runs one [E=1.6M, 64]
+A plug-in-era profile (not reproduced on this machine) showed the LargeFluid
+train step is NOT compute-bound: XLA's scatter-add ran one [E=1.6M, 64]
 edge->node aggregation in 22-33 ms (~19 GB/s effective, vs ~800 GB/s HBM) and
-gathers at ~43 GB/s, so the step spends >80% of its time in what the reference
+gathers at ~43 GB/s, so the step spent >80% of its time in what the reference
 does with CUDA scatter kernels (models/FastEGNN.py:322-337, torch_scatter).
 
 The TPU-native fix is a LAYOUT, not a faster scatter. Edge lists are already
@@ -43,6 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from distegnn_tpu import runtime
 
 DEFAULT_BLOCK = 256       # nodes per block = one-hot matmul N dimension
 DEFAULT_EDGE_TILE = 512   # edges per grid step = one-hot matmul K dimension
@@ -275,10 +277,6 @@ def slot_ids(row: jnp.ndarray, edge_mask: jnp.ndarray, block: int, epb: int) -> 
 # Pallas kernels (single graph; batched wrappers vmap them)
 # ---------------------------------------------------------------------------
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _precision_for(dtype):
     # f32 operands: 'highest' makes the MXU one-hot contraction exact (the
     # one-hot factor is 0/1, so only data truncation matters — 3-pass bf16
@@ -345,7 +343,7 @@ def _seg_sum_impl(data, slot, n_nodes: int, block: int, tile: int):
         out_specs=pl.BlockSpec((block, F), lambda b, t: (b, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_nodes, F), jnp.float32),
-        interpret=_use_interpret(),
+        interpret=runtime.use_interpret(),
     )(slot[:, None], data)
 
 
@@ -369,7 +367,7 @@ def _gather_impl(h, slot, block: int, tile: int):
         out_specs=pl.BlockSpec((tile, F), lambda b, t: (b * ept + t, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((E, F), h.dtype),
-        interpret=_use_interpret(),
+        interpret=runtime.use_interpret(),
     )(slot[:, None], h)
 
 
@@ -441,7 +439,8 @@ _paired_gather.defvjp(_paired_gather_fwd, _paired_gather_bwd)
 # aggregation/gather is a plain batched dot XLA schedules itself. Rationale:
 # the Pallas kernels run one small (tile x block x F) MXU dot per grid step —
 # thousands of steps per call — and the first hardware run measured the
-# per-step overhead swamping the dot (BASELINE.md round-2 status). The einsum
+# per-step overhead swamping the dot (plug-in era; not measured on this
+# machine). The einsum
 # form trades ~E*block*2 bytes of HBM traffic per op (abundant: ~1ms at v5e
 # bandwidth for LargeFluid) for zero grid overhead and full XLA pipelining.
 #
@@ -726,7 +725,7 @@ class EdgeOps:
         f32 by construction; the scatter path scatters into an f32 output).
         NOTE: bf16 rounds the GEOMETRY stream (a = coordinate translations),
         trading exact-at-math-level equivariance for bandwidth — off by
-        default, a measured opt-in (VERDICT r3 #1 prepared attack).
+        default, an opt-in whose speed is not measured on this machine.
 
         Blocked layouts keep their two-call path (mean is a free inv_deg
         multiply there)."""
@@ -734,7 +733,7 @@ class EdgeOps:
             # two-call path (mean is a free inv_deg multiply here), but the
             # stream-dtype knob still applies: bf16 operands run the one-hot
             # kernels single-pass instead of f32 precision=HIGHEST 6-pass —
-            # the gen-2 blocked configuration (VERDICT r3 #1)
+            # the gen-2 blocked configuration
             if agg_dtype in ("bf16", jnp.bfloat16):
                 a = a.astype(jnp.bfloat16)
                 b = b.astype(jnp.bfloat16)

@@ -27,12 +27,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distegnn_tpu import runtime
+
 _TILE = 4096          # rows per grid step: [4096, 64] f32 = 1 MiB VMEM block
 _MIN_PALLAS_ROWS = 32768  # below this the dispatch isn't worth it
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _prefix_kernel(x_ref, out_ref, carry_ref):
@@ -109,7 +107,7 @@ def _prefix_pallas(x, tile: int = _TILE, reverse: bool = False):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_tiles * tile, F), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, F), jnp.float32)],
-        interpret=_use_interpret(),
+        interpret=runtime.use_interpret(),
     )(x)
     return out[:E] if pad else out
 

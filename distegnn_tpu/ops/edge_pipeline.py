@@ -31,6 +31,14 @@ N=113140 Morton-ordered): a 3x2048 window captures ~92% of edges, 3x4096
 `remote` plain-path arrays built by `split_remote_edges` (ordinary EdgeOps
 work at ~5-8% of E).
 
+Status on hardware (TPU v5e, jax 0.9.0 / libtpu 0.0.34): the kernel passes
+JAX's Pallas lowering (pinned from the CPU by the lowering checks) but
+Mosaic refuses to compile it — the `[T, lanes]` sublane gather below is "Not
+implemented: Multiple source vregs along gather dimension" (f32) / "Gather
+indices and result have different bitwidths" (bf16). It runs in interpret
+mode on the CPU only; on a TPU selecting it fails with that error. ROADMAP
+S2/D1 decides between a redesigned gather and deletion.
+
 Gather constraint: the Mosaic lowering of `jnp.take_along_axis(x, i, 0)`
 (tpu.dynamic_gather) requires source, indices and output to share one 2-D
 shape — so the edge tile T equals the node block NB and a 3-block window
@@ -63,13 +71,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distegnn_tpu import runtime
+
 DEFAULT_BLOCK = 2048   # node block NB == edge tile T (gather shape contract)
 OH_CHUNK = 512         # one-hot aggregation chunk (VMEM bound)
 XL = 8                 # x lane padding: [N, 3] f32 stored as [N, 8]
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 class EdgeWeights(NamedTuple):
@@ -90,12 +96,30 @@ class EdgeWeights(NamedTuple):
 
 
 def _silu(x):
-    return x * jax.nn.sigmoid(x)
+    # f32 inside, x's dtype out: the Mosaic lowering of a bf16 logistic
+    # broadcasts an f32 constant into a bf16 vector and fails verification
+    # (and the v5e VPU has no bf16 arithmetic to lose)
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.nn.sigmoid(x32)).astype(x.dtype)
 
 
 def _dsilu(x):
-    s = jax.nn.sigmoid(x)
-    return s * (1.0 + x * (1.0 - s))
+    x32 = x.astype(jnp.float32)
+    s = jax.nn.sigmoid(x32)
+    return (s * (1.0 + x32 * (1.0 - s))).astype(x.dtype)
+
+
+def _mm(a, b):
+    """``a @ b`` in ``a``'s dtype with an f32 accumulator (Mosaic refuses a
+    bf16 x bf16 dot whose accumulator is not 32-bit)."""
+    return jnp.matmul(a, b, preferred_element_type=jnp.float32).astype(a.dtype)
+
+
+def _mm_nt(a, b):
+    """``a @ b.T`` (2-D), same accumulator rule, no materialized transpose."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(a.dtype)
 
 
 def _split2(x):
@@ -249,9 +273,10 @@ def _onehot_agg(seg_row, data):
     out = jnp.zeros((T, F), jnp.float32)
     rows = jax.lax.broadcasted_iota(jnp.int32, (T, OH_CHUNK), 0)
     for c in range(T // OH_CHUNK):
-        seg = jax.lax.dynamic_slice(seg_row, (0, c * OH_CHUNK), (1, OH_CHUNK))
+        sl = slice(c * OH_CHUNK, (c + 1) * OH_CHUNK)
+        seg = seg_row[:, sl]
         oh = (rows == jnp.broadcast_to(seg, (T, OH_CHUNK))).astype(jnp.bfloat16)
-        chunk = jax.lax.dynamic_slice(data, (c * OH_CHUNK, 0), (OH_CHUNK, F))
+        chunk = data[sl, :]
         out = out + jax.lax.dot_general(
             oh, chunk.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -274,12 +299,12 @@ def _edge_fwd_math(x_own, x_win, p_own, p_win, row_t, col, kblk, scal,
     cd = (x_r - x_c) * mask                                # [T, XL] f32
     radial = jnp.sum(cd * cd, axis=1, keepdims=True)       # [T, 1] f32
     sfeat = jnp.concatenate([radial, scal[:, 0:2]], axis=1).astype(dtype)
-    t1 = ((hr_e + hc_e).astype(dtype) + sfeat @ w.ws.astype(dtype)
+    t1 = ((hr_e + hc_e).astype(dtype) + _mm(sfeat, w.ws.astype(dtype))
           + w.b1.astype(dtype))
     y1 = _silu(t1)
-    t2 = y1 @ w.w2.astype(dtype) + w.b2.astype(dtype)
+    t2 = _mm(y1, w.w2.astype(dtype)) + w.b2.astype(dtype)
     ef = _silu(t2)                                         # [T, H] edge_feat
-    t3 = ef @ w.w3.astype(dtype) + w.b3.astype(dtype)
+    t3 = _mm(ef, w.w3.astype(dtype)) + w.b3.astype(dtype)
     y2 = _silu(t3)
     g = jnp.sum(y2.astype(jnp.float32) * w.w4, axis=1, keepdims=True) * mask
     return mask, cd, sfeat, t1, y1, t2, ef, t3, y2, g
@@ -346,28 +371,27 @@ def _bwd_kernel(row_t_ref, col_ref, kblk_ref, scal_ref,
     # upstream per-edge grads: gather the own-block packed cotangent by row
     row_c = jnp.minimum(row_t, T - 1).reshape(T, 1)
     gt = jnp.take_along_axis(gp_ref[...], jnp.broadcast_to(row_c, (T, H + 8)), 0)
-    d_trans = gt[:, 0:3] * mask                            # [T, 3] f32
-    d_ef_up = gt[:, 8:] * mask                             # [T, H] f32
+    # lanes 3..XL-1 of the packed cotangent (and of cd) are zero, so the
+    # coordinate terms stay XL wide and need no lane scatter
+    d_trans = gt[:, 0:XL] * mask                           # [T, XL] f32
+    d_ef_up = gt[:, XL:] * mask                            # [T, H] f32
 
     # trans = cd[:, :3] * g
-    d_g = jnp.sum(cd[:, 0:3] * d_trans, axis=1, keepdims=True)   # [T, 1]
-    d_cd3 = d_trans * g                                    # [T, 3] f32
+    d_g = jnp.sum(cd * d_trans, axis=1, keepdims=True)     # [T, 1]
 
     # g = sum(y2 * w4) * mask
     d_y2 = (d_g * w.w4).astype(dtype)                      # [T, H]
     dw4_ref[...] += jnp.sum(y2.astype(jnp.float32) * d_g, axis=0,
                             keepdims=True)
     d_t3 = d_y2 * _dsilu(t3)
-    d_ef = d_ef_up.astype(dtype) + jax.lax.dot_general(
-        d_t3, w.w3.astype(dtype), (((1,), (1,)), ((), ())))      # @ w3^T
+    d_ef = d_ef_up.astype(dtype) + _mm_nt(d_t3, w.w3.astype(dtype))
     dw3_ref[...] += jax.lax.dot_general(
         ef, d_t3, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                      # ef^T d_t3
     db3_ref[...] += jnp.sum(d_t3.astype(jnp.float32), axis=0, keepdims=True)
 
     d_t2 = d_ef * _dsilu(t2)
-    d_y1 = jax.lax.dot_general(d_t2, w.w2.astype(dtype),
-                               (((1,), (1,)), ((), ())))         # @ w2^T
+    d_y1 = _mm_nt(d_t2, w.w2.astype(dtype))
     dw2_ref[...] += jax.lax.dot_general(
         y1, d_t2, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -379,14 +403,10 @@ def _bwd_kernel(row_t_ref, col_ref, kblk_ref, scal_ref,
         preferred_element_type=jnp.float32)[0:dws_ref.shape[0]]
     db1_ref[...] += jnp.sum(d_t1.astype(jnp.float32), axis=0, keepdims=True)
 
-    d_sfeat = jax.lax.dot_general(d_t1, w.ws.astype(dtype),
-                                  (((1,), (1,)), ((), ())))      # [T, S]
+    d_sfeat = _mm_nt(d_t1, w.ws.astype(dtype))          # [T, S]
     d_radial = d_sfeat[:, 0:1].astype(jnp.float32) * mask
-    # radial = sum(cd^2); cd rows are zero beyond lane 2, so the XL-wide
-    # update only populates the real lanes
-    d_cd = 2.0 * cd * d_radial
-    d_cd = d_cd.at[:, 0:3].add(d_cd3) if hasattr(d_cd, "at") else d_cd
-    # (jnp arrays always have .at — kept explicit for interpret clarity)
+    # radial = sum(cd^2); trans = cd * g
+    d_cd = 2.0 * cd * d_radial + d_trans * g               # [T, XL] f32
 
     # ---- aggregate: row side (own block), col side (3-slot window partials)
     d_t1m = d_t1 * mask.astype(d_t1.dtype)
@@ -432,7 +452,12 @@ def _common_specs(T, H, nb, nt, wshapes):
         return pl.BlockSpec(shape, lambda b, j: (0, 0),
                             memory_space=pltpu.VMEM)
 
-    return ([edge((1, T)), edge((T, 1)), edge((T, 1)), edge((T, XL)),
+    # row ids ride a [nb*nt, 1, T] array: a (1, T) block of a 2-D
+    # [nb*nt, T] array breaks the TPU (8, 128) block rule, a squeezed leading
+    # axis over full trailing dims does not
+    row_spec = pl.BlockSpec((None, 1, T), lambda b, j: (b * nt + j, 0, 0),
+                            memory_space=pltpu.VMEM)
+    return ([row_spec, edge((T, 1)), edge((T, 1)), edge((T, XL)),
              own(XL), win(0, XL), win(1, XL), win(2, XL),
              own(2 * H), win(0, 2 * H), win(1, 2 * H), win(2, 2 * H)]
             + [const(s) for s in wshapes])
@@ -478,8 +503,9 @@ def _fused_fwd_impl(x, hr, hc, row_t, col_l, kblk, scal, weights,
         out_specs=pl.BlockSpec((T, H + 8), lambda b, j: (b, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_nodes, H + 8), jnp.float32),
-        interpret=_use_interpret(),
-    )(row_t, col_l, kblk, scal, xp, xp, xp, xp, pk, pk, pk, pk, *wlist)
+        interpret=runtime.use_interpret(),
+    )(row_t[:, None, :], col_l, kblk, scal, xp, xp, xp, xp, pk, pk, pk, pk,
+      *wlist)
     trans = out[:, 0:3] + out[:, 3:6]       # 2-term bf16 recombine
     count = out[:, 6]
     ef_sum = out[:, 8:]
@@ -520,9 +546,9 @@ def _fused_bwd_impl(x, hr, hc, row_t, col_l, kblk, scal, weights,
         in_specs=_common_specs(T, H, nb, nt, wshapes) + [gp_spec],
         out_specs=out_specs,
         out_shape=out_shapes,
-        interpret=_use_interpret(),
-    )(row_t, col_l, kblk, scal, xp, xp, xp, xp, pk, pk, pk, pk, *wlist,
-      g_pack)
+        interpret=runtime.use_interpret(),
+    )(row_t[:, None, :], col_l, kblk, scal, xp, xp, xp, xp, pk, pk, pk, pk,
+      *wlist, g_pack)
 
     # row-side: d_x (+cd side) and d_hr live in the own block
     d_x = drow[:, 0:3] + drow[:, 3:6]

@@ -57,6 +57,10 @@ cannot cross a Pallas grid, so FastEGNN falls back to the per-layer fused
 path with the SAME param tree (models/fast_egnn.py) — the megakernel is the
 single-chip serving/training lowering.
 
+Status on hardware: the in-window pass is ops/edge_pipeline's tile math, so
+Mosaic refuses this kernel for the same sublane gather (see that module);
+interpret mode on the CPU only.
+
 Parity contract (tests/test_layer_pipeline.py): interpret-mode forward
 within 1e-6 and grads within 1e-5 of the per-layer fused path at
 L in {1, 2, 4}, including remote tails and trailing empty blocks. The
@@ -75,9 +79,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distegnn_tpu import runtime
 from distegnn_tpu.ops.edge_pipeline import (
-    OH_CHUNK, XL, EdgeWeights, _check_grid, _edge_fwd_math, _onehot_agg,
-    _silu, _split2, _use_interpret, fused_edge_layer,
+    OH_CHUNK, XL, EdgeWeights, _check_grid, _edge_fwd_math, _mm, _onehot_agg,
+    _silu, _split2, fused_edge_layer,
 )
 
 # Honest single-core VMEM budget (pallas_guide: ~16 MiB/core). The estimate
@@ -270,7 +275,7 @@ def _cast(dt):
 def _dense(x, k, b, dt):
     """nn.Dense(dtype=dt) on values: promote inputs AND params to dt."""
     c = _cast(dt)
-    y = c(x) @ c(k)
+    y = _mm(c(x), c(k))
     if b is not None:
         y = y + c(b)
     return y
@@ -298,9 +303,9 @@ def _remote_edge_math(x_r, x_c, hr_r, hc_c, rattr, rm, w, H, dt):
     cd_r = (x_r - x_c) * rm
     radial = jnp.sum(cd_r * cd_r, axis=-1, keepdims=True)
     sfeat = c(jnp.concatenate([radial, rattr[:, :2]], axis=-1))
-    t1 = hr_r + hc_c + sfeat @ c(w["e_w1"][2 * H:]) + c(w["e_b1"])
-    ef_r = _silu(_silu(t1) @ c(w["e_w2"]) + c(w["e_b2"]))
-    y2 = _silu(ef_r @ c(w["e_w3"]) + c(w["e_b3"]))
+    t1 = hr_r + hc_c + _mm(sfeat, c(w["e_w1"][2 * H:])) + c(w["e_b1"])
+    ef_r = _silu(_mm(_silu(t1), c(w["e_w2"])) + c(w["e_b2"]))
+    y2 = _silu(_mm(ef_r, c(w["e_w3"])) + c(w["e_b3"]))
     g_r = (y2.astype(jnp.float32) @ w["e_w4"].T) * rm
     return cd_r, g_r, ef_r
 
@@ -465,8 +470,8 @@ def _stack_kernel(*refs, cfg: StackConfig, names, nb, nt):
 
     # hoisted phi_e node products (HoistedEdgeMLP algebra)
     c = _cast(dt)
-    hr = c(h) @ c(w["e_w1"][:H])
-    hc = c(h) @ c(w["e_w1"][H:2 * H])
+    hr = _mm(c(h), c(w["e_w1"][:H]))
+    hc = _mm(c(h), c(w["e_w1"][H:2 * H]))
     pk = jnp.concatenate([hr, hc], axis=1).astype(dtype)
 
     # in-window blocked edges — the shared tile math, edge stream VMEM-hot
@@ -613,7 +618,7 @@ def _stack_fwd_impl(cfg: StackConfig, h0, x0, v, X0, Hv0, node_mask,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        interpret=_use_interpret(),
+        interpret=runtime.use_interpret(),
     )(*operands)
     (oh, ox, oX, oHv, ckh, ckx, ckX, ckHv) = outs
     out = (oh, ox[:, 0:3], oX[0:3, :], oHv)
@@ -638,8 +643,8 @@ def _layer_ref(cfg: StackConfig, h, x, v, X, Hv, node_mask, node_attr,
     N = x.shape[0]
 
     w1 = w["e_w1"]
-    hr = c(h) @ c(w1[:H])
-    hc = c(h) @ c(w1[H:2 * H])
+    hr = _mm(c(h), c(w1[:H]))
+    hc = _mm(c(h), c(w1[H:2 * H]))
     ew = EdgeWeights(ws=w1[2 * H:], b1=w["e_b1"], w2=w["e_w2"], b2=w["e_b2"],
                      w3=w["e_w3"], b3=w["e_b3"], w4=w["e_w4"])
     trans_sum, count, ef_sum = fused_edge_layer(

@@ -86,9 +86,10 @@ def segment_softmax(scores, segment_ids, num_segments, mask=None):
 # --------------------------------------------------------------------------
 # Scatter-free sorted-segment ops (``segment_impl='cumsum'``).
 #
-# XLA's TPU scatter-add runs far below HBM bandwidth at LargeFluid scale
-# (BASELINE.md: 22-33 ms per [1.6M, 64] aggregation, ~4% of peak), and both
-# blocked one-hot MXU lowerings measured slower end to end on hardware. This
+# XLA's TPU scatter-add ran far below HBM bandwidth at LargeFluid scale in a
+# plug-in-era profile (22-33 ms per [1.6M, 64] aggregation, ~4% of peak; not
+# measured on this machine), and both blocked one-hot MXU lowerings measured
+# slower end to end there. This
 # lowering uses only bandwidth-friendly primitives: for ascending segment ids
 # (GraphBatch.edges_sorted), segment sums are exclusive-prefix differences
 #

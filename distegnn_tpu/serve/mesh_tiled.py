@@ -33,10 +33,10 @@ contribute exactly nothing to the psums and their outputs are discarded.
 The schedule itself is device-count-agnostic state-free planning: a
 ``TilePlan`` built (or session-cached) at ``devices: 1`` serves at any D
 without a rebuild — ``plan_rounds`` derives rounds from the plan on the
-fly. Everything here is CPU-testable on 8 virtual devices via
-``--xla_force_host_platform_device_count`` (tests/test_tiled_mesh.py);
-measured multi-chip speedups land through the ``bench_tiled_mesh``
-hw_session leg per the ROADMAP evidence rule.
+fly. Everything here is CPU-testable on 8 virtual devices
+(tests/test_tiled_mesh.py); it ran once on a four-chip host in chip_smoke.py's
+multichip phase (parity against the sequential walk). Multi-chip speed: not
+measured.
 """
 
 from __future__ import annotations
@@ -139,6 +139,11 @@ def run_rounds(ex, plan: TilePlan, batches, h_full: np.ndarray,
     valid_0 = np.asarray(0.0, np.float32)
 
     halo_gather_s = 0.0
+    # one [D, ...] array per leaf, its leading axis split over the round's
+    # devices: what pmap takes without a reshard
+    round_sharding = jax.sharding.NamedSharding(
+        jax.sharding.Mesh(np.array(devices), (ROUND_AXIS,)),
+        jax.sharding.PartitionSpec(ROUND_AXIS))
 
     def stage_round(ri: int, h_src: np.ndarray, x_src: np.ndarray):
         """Gather round ri's tile inputs from the layer-input snapshot and
@@ -163,7 +168,8 @@ def run_rounds(ex, plan: TilePlan, batches, h_full: np.ndarray,
             else:
                 shards.append((zeros_h, zeros_x, pad_batch, valid_0))
         halo_gather_s += time.perf_counter() - t0
-        return jax.device_put_sharded(shards, devices)
+        stacked = jax.tree.map(lambda *xs: np.stack(xs), *shards)
+        return jax.device_put(stacked, round_sharding)
 
     stall_s = 0.0
     round_s = 0.0

@@ -305,7 +305,7 @@ class WorkerHandle:
             _LIVE.add(handle)
         _obs_event("gateway/worker_spawn", model=model, replica=idx,
                    pid=handle.pid, params_digest=ready.get("params_digest"),
-                   warmed=ready.get("warmed"))
+                   device=ready.get("device"), warmed=ready.get("warmed"))
         return handle
 
     # ---- channel ---------------------------------------------------------
@@ -565,10 +565,13 @@ def _child_serve(sock: socket.socket) -> int:
     heartbeat_s = max(float(init.get("heartbeat_s", 0.5)), 0.01)
 
     try:
+        import jax
+
+        from distegnn_tpu import runtime
+
+        runtime.configure_compile_cache()
         prec = init.get("matmul_precision")
         if prec:
-            import jax
-
             jax.config.update("jax_default_matmul_precision", prec)
         obs_cfg = init.get("obs") or {}
         if obs_cfg.get("dir"):
@@ -590,6 +593,7 @@ def _child_serve(sock: socket.socket) -> int:
                    {"ok": True,
                     "result": {"pid": os.getpid(),
                                "params_digest": engine.params_digest(),
+                               "device": runtime.device_summary(),
                                "warmed": [[b.n, b.e] for b in warmed]}})
     except Exception as exc:
         sys.stderr.write("worker: init failed\n" + traceback.format_exc())

@@ -534,9 +534,8 @@ def resolve_resume(config, state) -> Optional[RestoredRun]:
 
 def write_preempt_marker(ckpt_dir: str, ckpt_name: str, epoch: int,
                          step_in_epoch: int) -> None:
-    """Drop the resumable marker scripts key off (lib_resume_paused.sh
-    newest_resumable_ckpt / convergence_session.sh): the run exited on
-    purpose mid-training and the named checkpoint continues it."""
+    """Drop the resumable marker a wrapper script can key off: the run
+    exited on purpose mid-training and the named checkpoint continues it."""
     if jax.process_index() != 0:
         return
     import time as _time

@@ -18,7 +18,7 @@ import sys
 import jax
 import numpy as np
 
-from distegnn_tpu import obs
+from distegnn_tpu import obs, runtime
 from distegnn_tpu.config import build_arg_parser, derive_runtime_fields, load_config
 from distegnn_tpu.data import GraphDataset, GraphLoader, process_nbody_cutoff
 from distegnn_tpu.models.registry import get_model
@@ -34,8 +34,8 @@ from distegnn_tpu.train import (
 from distegnn_tpu.train.checkpoint import adopt_resume_seed, resolve_resume
 from distegnn_tpu.utils.seed import fix_seed
 
-# exit code of a preempted-but-resumable run (BSD EX_TEMPFAIL); session
-# scripts (lib_resume_paused.sh) key retry-with-resume off it
+# exit code of a preempted-but-resumable run (BSD EX_TEMPFAIL): a wrapper
+# script can key retry-with-resume off it
 EXIT_PREEMPTED = 75
 
 
@@ -235,6 +235,7 @@ def _point_at_events():
 
 
 if __name__ == "__main__":
+    runtime.configure_compile_cache()
     _best = main()
     if isinstance(_best, dict) and _best.get("preempted"):
         sys.exit(EXIT_PREEMPTED)

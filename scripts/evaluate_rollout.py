@@ -1,4 +1,4 @@
-"""Autoregressive rollout MSE evaluation (the BASELINE.md "rollout MSE"
+"""Autoregressive rollout MSE evaluation (the BASELINE.json "rollout MSE"
 surface).
 
 The reference evaluates one-step MSE only; this drives the framework's
@@ -13,7 +13,7 @@ Wired datasets (dispatch on config data.dataset_name):
              horizons keyed by rollout STEP (each spanning delta_t frames);
              rollout displacement rescaled to the pipeline's one-frame
              velocity convention.
-  Fluid113K — zstd/msgpack simulations (the BASELINE.md headline dataset);
+  Fluid113K — zstd/msgpack simulations (the headline dataset);
              horizons keyed by rollout STEP; velocity convention converted
              with a data-estimated frame duration.
 
@@ -231,7 +231,7 @@ def evaluate_fluid113k_rollout(config, checkpoint=None, samples=2, split="test",
                                edge_block=256, seed=0, max_steps=5,
                                degree_margin=2.0):
     """Multi-step rollout over Fluid113K (LargeFluid) simulations — the
-    BASELINE.md headline dataset. Horizons keyed by rollout step (delta_t
+    headline dataset. Horizons keyed by rollout step (delta_t
     frames each, starting at frame 0). The sim's own velocity field is the
     model input; the rollout's delta_t-frame displacement is converted back
     to that convention with a data-estimated frame duration."""

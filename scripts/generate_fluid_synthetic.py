@@ -53,6 +53,24 @@ def synth_sim(rng: np.random.Generator, n: int, frames: int, radius: float):
     return np.stack(poss), np.stack(vels)
 
 
+def generate(out: str, dataset_name: str = "Fluid113K", particles: int = 113_140,
+             frames: int = 48, radius: float = 0.075, sims_train: int = 2,
+             sims_valid: int = 1, sims_test: int = 1, seed: int = 0) -> None:
+    """Write ``sims_*`` synthetic simulations per split under
+    ``out/dataset_name`` (importable: a caller that already holds an
+    accelerator must not shell out to a second JAX process)."""
+    rng = np.random.default_rng(seed)
+    counts = {"train": sims_train, "valid": sims_valid, "test": sims_test}
+    for split, (lo, _) in SIM_SPLITS.items():
+        for k in range(counts[split]):
+            pos, vel = synth_sim(rng, particles, frames, radius)
+            visc = np.full((particles,), 0.01, np.float32)
+            mass = np.full((particles,), 0.1, np.float32)
+            write_fluid_sim(out, dataset_name, lo + k, pos, vel, visc, mass)
+            print(f"wrote sim {lo + k} ({split}): {particles} particles x "
+                  f"{frames} frames", flush=True)
+
+
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--out", type=str, required=True)
@@ -64,18 +82,7 @@ def main():
     p.add_argument("--sims-valid", type=int, default=1)
     p.add_argument("--sims-test", type=int, default=1)
     p.add_argument("--seed", type=int, default=0)
-    args = p.parse_args()
-
-    rng = np.random.default_rng(args.seed)
-    counts = {"train": args.sims_train, "valid": args.sims_valid, "test": args.sims_test}
-    for split, (lo, _) in SIM_SPLITS.items():
-        for k in range(counts[split]):
-            pos, vel = synth_sim(rng, args.particles, args.frames, args.radius)
-            visc = np.full((args.particles,), 0.01, np.float32)
-            mass = np.full((args.particles,), 0.1, np.float32)
-            write_fluid_sim(args.out, args.dataset_name, lo + k, pos, vel, visc, mass)
-            print(f"wrote sim {lo + k} ({split}): {args.particles} particles x "
-                  f"{args.frames} frames", flush=True)
+    generate(**vars(p.parse_args()))
 
 
 if __name__ == "__main__":

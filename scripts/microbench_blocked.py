@@ -3,7 +3,8 @@ the XLA sorted-scatter path, at LargeFluid shape.
 
 The round-2 prediction (docs/PERFORMANCE.md) was that the blocked kernels
 bound the hot aggregations near HBM bandwidth; the first hardware run of the
-full step measured SLOWER than the plain path (BASELINE.md). This isolates
+full step measured SLOWER than the plain path (plug-in era; not measured on
+this machine). This isolates
 the primitives to find out which one lies: times blocked_segment_sum /
 blocked_gather across (dtype, tile) against scatter/segment-sum/gather on the
 same data, plus the paired backward-gather path.

@@ -29,8 +29,7 @@ steady-state dispatch, not compiles.
 
 Obs: the run's structured event stream (serve/batch, serve/execute,
 jax/compile, ...) lands at --obs-dir/obs/events.jsonl (default
-logs/serve_bench/, gitignored) so hw_session.sh can archive it next to the
-BENCH line; render with `python scripts/obs_report.py <path>`. Stdout stays
+logs/serve_bench/, gitignored); render with `python scripts/obs_report.py <path>`. Stdout stays
 EXACTLY one JSON line — the obs pointer goes to stderr.
 """
 

@@ -14,7 +14,8 @@ flush (every accepted request gets a real response), then the process exits
 
   python scripts/serve_gateway.py --config_path configs/nbody_serve.yaml
 
-CPU works (JAX_PLATFORMS=cpu); the same gateway runs unchanged on TPU.
+CPU works (JAX_PLATFORMS=cpu); the same gateway runs unchanged on TPU, and
+the listening line says which (``platform=... device_kind=... devices=N``).
 ``--port 0`` binds an ephemeral port (printed in the listening line — the
 smoke drill in tests/test_cli_e2e.py parses it). Obs events land at
 ``--obs-dir/obs/events.jsonl``; warmup is marked done after all models
@@ -29,6 +30,55 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def build_gateway(cfg, *, host=None, port=None, max_inflight=None,
+                  warmup_nodes=None):
+    """config -> (started + warmed ModelRegistry, bound Gateway): the wiring
+    ``main`` serves, importable so chip_smoke.py drives exactly this path.
+    ``None`` arguments fall back to the config's ``serve.gateway`` section.
+    Logs the listening line, which names the device the engines run on."""
+    from distegnn_tpu import obs, runtime
+    from distegnn_tpu.obs import jaxprobe
+    from distegnn_tpu.serve.registry import ModelRegistry
+    from distegnn_tpu.serve.transport import Gateway
+
+    g = cfg.serve.gateway
+    if warmup_nodes is None:
+        warmup_nodes = [int(n) for n in g.warmup_nodes]
+    registry = ModelRegistry.from_config(cfg)
+    registry.start()
+    obs.log(f"gateway: warming {len(registry)} model(s) at node sizes "
+            f"{warmup_nodes}")
+    registry.warmup(warmup_nodes)
+    # compiles past this point are regressions obs_report --check flags
+    jaxprobe.mark_warmup_done()
+    jaxprobe.set_phase("serve/http")
+
+    s = cfg.serve
+    gateway = Gateway(
+        registry,
+        host=host if host is not None else str(g.host),
+        port=port if port is not None else int(g.port),
+        max_inflight=(max_inflight if max_inflight is not None
+                      else int(g.max_inflight)),
+        drain_grace_s=float(g.drain_grace_s),
+        slo_window_s=float((cfg.get("slo") or {}).get("window_s", 60.0)
+                           or 60.0),
+        autoscale=dict(s.autoscale),
+        priority=dict(s.priority),
+        stream_chunk_steps=int(s.stream.chunk_steps),
+        promote=dict(cfg.get("promote") or {}))
+    bound_host, bound_port = gateway.address
+    dev = runtime.device_summary()
+    obs.event("gateway/listening", host=bound_host, port=bound_port,
+              platform=dev["platform"], device_kind=dev["kind"],
+              devices=dev["count"], models=registry.names())
+    obs.log(f"gateway: listening on http://{bound_host}:{bound_port} "
+            f"(models: {', '.join(registry.names())}; "
+            f"ready={gateway.ready()}; platform={dev['platform']} "
+            f"device_kind={dev['kind']} devices={dev['count']})")
+    return registry, gateway
 
 
 def main(argv=None) -> int:
@@ -54,48 +104,17 @@ def main(argv=None) -> int:
 
     from distegnn_tpu import obs
     from distegnn_tpu.config import ConfigDict, _DEFAULTS, load_config
-    from distegnn_tpu.obs import jaxprobe
-    from distegnn_tpu.serve.registry import ModelRegistry
-    from distegnn_tpu.serve.transport import Gateway
 
     cfg = (load_config(args.config_path) if args.config_path
            else ConfigDict(_DEFAULTS))
     if args.obs_dir:
         obs.configure_from_config(cfg, args.obs_dir,
                                   tags={"run": "serve_gateway"})
-    g = cfg.serve.gateway
-    warmup_nodes = ([int(n) for n in args.warmup_nodes.split(",") if n]
-                    if args.warmup_nodes else [int(n) for n in
-                                               g.warmup_nodes])
-
-    registry = ModelRegistry.from_config(cfg)
-    registry.start()
-    obs.log(f"gateway: warming {len(registry)} model(s) at node sizes "
-            f"{warmup_nodes}")
-    registry.warmup(warmup_nodes)
-    # compiles past this point are regressions obs_report --check flags
-    jaxprobe.mark_warmup_done()
-    jaxprobe.set_phase("serve/http")
-
-    s = cfg.serve
-    gateway = Gateway(
-        registry,
-        host=args.host if args.host is not None else str(g.host),
-        port=args.port if args.port is not None else int(g.port),
-        max_inflight=(args.max_inflight if args.max_inflight is not None
-                      else int(g.max_inflight)),
-        drain_grace_s=float(g.drain_grace_s),
-        slo_window_s=float((cfg.get("slo") or {}).get("window_s", 60.0)
-                           or 60.0),
-        autoscale=dict(s.autoscale),
-        priority=dict(s.priority),
-        stream_chunk_steps=int(s.stream.chunk_steps),
-        promote=dict(cfg.get("promote") or {}))
+    registry, gateway = build_gateway(
+        cfg, host=args.host, port=args.port, max_inflight=args.max_inflight,
+        warmup_nodes=([int(n) for n in args.warmup_nodes.split(",") if n]
+                      if args.warmup_nodes else None))
     gateway.install_signal_handlers()
-    host, port = gateway.address
-    obs.log(f"gateway: listening on http://{host}:{port} "
-            f"(models: {', '.join(registry.names())}; "
-            f"ready={gateway.ready()})")
     gateway.serve_forever()          # returns after a signal-driven drain
 
     gateway.close()
@@ -106,4 +125,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from distegnn_tpu import runtime
+
+    runtime.configure_compile_cache()
     raise SystemExit(main())

@@ -986,7 +986,10 @@ def main(argv=None) -> int:
                     choices=("thread", "process"),
                     help="override serve.workers (in-process gateway only): "
                          "'process' runs each replica in its own worker "
-                         "child behind IPC supervision")
+                         "child behind IPC supervision. CPU only: on a TPU "
+                         "the registry takes the chip before it spawns, the "
+                         "children cannot open it, and every replica "
+                         "degrades to an in-process queue (ROADMAP D5)")
     ap.add_argument("--chaos", type=str, default=None,
                     help="serving fault schedule, e.g. 'kill@0.3:replica=0;"
                          "swap@1.0:ckpt=/p/b.ckpt' (in-process gateway only)")

@@ -10,20 +10,15 @@ parity with reference equivariant_test.py:62) hold on any backend.
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# No persistent compile cache in the child processes the suite spawns: the
+# entry points place one under the checkout (runtime.configure_compile_cache),
+# and with it one run's compiles would depend on what an earlier run left.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # jax < 0.5: the option doesn't exist; the XLA flag is read when the CPU
-    # backend initializes (first device use), which hasn't happened yet even
-    # though jax is imported — so the env route still works here.
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _flags:
-        os.environ["XLA_FLAGS"] = (
-            _flags + " --xla_force_host_platform_device_count=8").strip()
+jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_default_matmul_precision", "highest")
 
 import numpy as np  # noqa: E402

@@ -1,7 +1,6 @@
 """The remaining cutoff-mode reference configs executed through main.main():
 protein_fastegnn.yaml and water3d_fastegnn.yaml on synthetic raw data (the
-real datasets are network downloads). The n-body config is exercised against
-the real generated dataset by scripts/convergence_session.sh; the two
+real datasets are network downloads). The two
 distribute-mode configs have their own e2e tests (test_largefluid_e2e.py,
 test_water3d_e2e.py). Covers the full CLI path: yaml load + CLI overrides →
 preprocessing → loaders → model factory → train loop → log.json.

@@ -16,7 +16,6 @@ from distegnn_tpu.data import GraphDataset, ShardedGraphLoader, build_nbody_grap
 from distegnn_tpu.data.partition import assign_partitions, split_graph
 from distegnn_tpu.models.fast_egnn import FastEGNN
 from distegnn_tpu.ops.graph import pad_graphs
-from distegnn_tpu.parallel.compat import shard_map
 from distegnn_tpu.parallel.launch import make_distributed_steps
 from distegnn_tpu.parallel.mesh import GRAPH_AXIS, make_mesh
 from distegnn_tpu.train import TrainState, make_eval_step, make_optimizer, make_train_step
@@ -100,7 +99,7 @@ def test_distributed_forward_matches_union(dist_setup):
 
     loc_1, X_1 = jax.jit(model_1.apply)(params, union_batch)
 
-    fwd = jax.jit(shard_map(
+    fwd = jax.jit(jax.shard_map(
         lambda pr, b: model_P.apply(pr, jax.tree.map(lambda x: x[0], b)),
         mesh=mesh, in_specs=(P(), P(GRAPH_AXIS)),
         out_specs=(P(GRAPH_AXIS), P()), check_vma=False,
@@ -243,7 +242,7 @@ def test_distributed_cumsum_matches_scatter(dist_setup):
     assert stacked.edge_pair is not None
 
     def fwd_of(m):
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             lambda pr, b: m.apply(pr, jax.tree.map(lambda x: x[0], b)),
             mesh=mesh, in_specs=(P(), P(GRAPH_AXIS)),
             out_specs=(P(GRAPH_AXIS), P()), check_vma=False,

@@ -177,8 +177,7 @@ def test_stack_sharded_drops_pair_on_asymmetric_partition():
 
 def _assert_resume_equivalent(make_runner, params, tx):
     """4 scanned epochs == 2 epochs + checkpoint round-trip into a FRESH
-    runner + 2 more — the staged TPU convergence protocol
-    (scripts/convergence_session.sh: scan_epochs on, resume from
+    runner + 2 more — a staged convergence run (scan_epochs on, resume from
     last_model.ckpt between stages)."""
     import os
     import tempfile

@@ -340,6 +340,30 @@ def test_serve_bench_cli_one_json_line(capsys):
 
 # ------------------------------------------------- fused_stack executables
 
+def test_serve_bench_rollout_leg_traces_on_cpu(capsys):
+    """The rollout BENCH line can never silently vanish: a tiny CPU trace of
+    `serve_bench.py --workload rollout` must emit exactly ONE JSON line with
+    the batched-vs-baseline fields."""
+    from scripts.serve_bench import main as bench_main
+
+    rc = bench_main(["--workload", "rollout", "--rollout-scenes", "2",
+                     "--rollout-steps", "2", "--sizes", "24",
+                     "--max-batch", "2", "--rate", "500", "--obs-dir", "",
+                     "--seed", "7"])
+    assert rc == 0
+    lines = [ln for ln in capsys.readouterr().out.strip().splitlines() if ln]
+    assert len(lines) == 1
+    rec = json.loads(lines[0])
+    assert rec["metric"] == "serve_rollout_throughput"
+    assert rec["unit"] == "scenes*steps/s"
+    assert rec["value"] > 0
+    assert rec["baseline_b1"] > 0 and rec["baseline_solo"] > 0
+    assert rec["vs_baseline"] > 0
+    assert rec["max_batch"] == 2 and rec["steps"] == 2
+    assert rec["scenes_completed"] == 2   # value credits only finished work
+    assert rec["snapshot"]["requests_completed"] == 2
+
+
 def test_fused_stack_one_executable_per_rung_and_obs_check(tmp_path):
     """Cross-layer megakernel serving gate: a warmed rung under
     ``edge_impl='fused_stack'`` serves every subsequent predict from exactly

@@ -5,9 +5,8 @@ round-boundary disconnect contract, tile-plan portability across a devices
 reconfig, the one-executable-per-(shape_key, D) invariant, and — slow lane —
 a million-node scene through rounds of 8 with zero recompiles after warmup.
 
-Runs on 8 virtual CPU devices via ``--xla_force_host_platform_device_count``
-(tests/conftest.py); real multi-chip numbers come from the hw_session
-``bench_tiled_mesh`` leg.
+Runs on 8 virtual CPU devices (tests/conftest.py); on a real four-chip host
+the rounds run once in chip_smoke.py. Multi-chip speed: not measured.
 """
 
 import json

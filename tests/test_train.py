@@ -285,9 +285,8 @@ def test_resume_equals_uninterrupted(tiny_setup, tmp_path):
     reshuffles from (seed, epoch) (trainer.run_epoch_train), so restoring
     last_model.ckpt at epoch k and continuing with start_epoch=k must yield
     the exact trajectory the unbroken run took — the property the reference's
-    --checkpoint restart flow (main.py:208-220) provides and our convergence
-    automation (scripts/convergence_session.sh) relies on after a mid-run
-    abort."""
+    --checkpoint restart flow (main.py:208-220) provides and a resumed
+    convergence run relies on after a mid-run abort."""
     from distegnn_tpu.config import ConfigDict
     from distegnn_tpu.train.trainer import train
 

@@ -23,10 +23,12 @@ configs' seeds:
              sequential tile walk
 
 Any failed check raises, so a failed phase can only end in a non-zero exit
-and no result line. On success the last line of stdout is one JSON object
-with ``ok``, the device as JAX reports it, and per phase its seconds,
-compile requests, compile seconds and persistent-cache hits. It makes no
-statement about speed (``"claim": null``).
+and no result line. On success the last two lines of stdout are
+``summary: {...}`` (per phase its seconds, compile requests, compile seconds
+and persistent-cache hits; ``"claim": null``, no statement about speed) and
+then the result line the driver parses, which holds exactly
+``{"ok": true, "device": {"platform", "kind", "count"}}`` with the device as
+JAX reports it.
 
 The chip belongs to one process at a time, so nothing here starts another
 process after JAX is up; the data generator is imported, not shelled out to.
@@ -145,7 +147,8 @@ def phase_device(dev: dict) -> None:
 def phase_data() -> str:
     import generate_fluid_synthetic as gen
 
-    out = os.path.join(WORK_DATA, "LargeFluid")
+    # keyed by size: a debug run at a tiny PARTICLES must not be reused
+    out = os.path.join(WORK_DATA, f"LargeFluid_n{PARTICLES}")
     have = glob.glob(os.path.join(out, "Fluid113K", "sim_*.msgpack.zst"))
     if len(have) == 3 * 16:   # three sims of 16 shards: an earlier run's data
         print(f"data: reusing {out}")
@@ -503,6 +506,15 @@ def phase_multichip(data_dir: str, train_cfg_path: str,
             "tiled_rounds": mesh_out["rounds"], "tiled_err": err}
 
 
+def result_line(dev: dict) -> str:
+    """The last line of stdout, which the driver parses: exactly ``ok`` and
+    ``device`` = ``platform``, ``kind`` (text), ``count`` (int). Everything
+    else belongs on the ``summary:`` line before it."""
+    return json.dumps({"ok": True, "device": {
+        "platform": str(dev["platform"]), "kind": str(dev["kind"]),
+        "count": int(dev["count"])}})
+
+
 def main() -> int:
     from distegnn_tpu import runtime
 
@@ -539,11 +551,11 @@ def main() -> int:
         with ph.phase("multichip"):
             detail["multichip"] = phase_multichip(data_dir, train_cfg,
                                                   serve_cfg)
-    print(json.dumps({
-        "ok": True, "device": dev, "claim": None,
-        "seconds": round(time.perf_counter() - t0, 1),
+    print("summary: " + json.dumps({
+        "claim": None, "seconds": round(time.perf_counter() - t0, 1),
         "compile_cache": jax.config.jax_compilation_cache_dir,
         "phases": ph.done, "detail": detail}))
+    print(result_line(dev), flush=True)     # nothing after it on stdout
     return 0
 
 

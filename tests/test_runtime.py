@@ -77,6 +77,25 @@ def test_chip_smoke_refuses_cpu_quickly():
         assert not line.lstrip().startswith("{"), line
 
 
+def test_chip_smoke_result_line_has_exactly_the_contract_keys():
+    """The driver refuses a last line with any key beyond ok/device."""
+    import json
+
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    line = chip_smoke.result_line(runtime.device_summary())
+    assert "\n" not in line
+    out = json.loads(line)
+    assert set(out) == {"ok", "device"} and out["ok"] is True
+    assert set(out["device"]) == {"platform", "kind", "count"}
+    assert isinstance(out["device"]["platform"], str)
+    assert isinstance(out["device"]["kind"], str)
+    assert type(out["device"]["count"]) is int
+
+
 # ---------------------------------------------------------- TPU lowering
 
 # a shape no other test uses: the inner jit caches its trace per shape, and

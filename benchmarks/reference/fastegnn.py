@@ -1,0 +1,257 @@
+"""Plain reference of FastEGNN / DistEGNN training: forward, loss (MSE + MMD),
+gradient and the torch-Adam update, in straightforward ``jax.numpy`` float32
+at matmul precision ``highest``.
+
+Written from the paper's equations (arXiv:2506.19482, FastEGNN layer: real
+edge messages, C virtual nodes, three global means a layer) and the public
+EGNN code it extends (``E_GCL_vel``: ``coord_diff / (sqrt(radial).detach() +
+eps)`` under ``normalize``; coordinate heads without bias). It works on RAW
+graphs (no node padding or masks, no node reordering, an unsorted edge list
+padded with zero-weight edges to one length so that one program serves a pool),
+imports nothing of ``distegnn_tpu`` and takes its weights from
+``benchmarks/weights.py``. A batch is ``G`` graphs of equal size stacked on a
+leading axis and processed ``block`` graphs at a time, each layer
+rematerialized, so that 1.64 M edges (one LargeFluid graph) and 2.475 M
+(250 n-body graphs) fit beside nothing else on one chip.
+
+Departures from the published training script, each because the
+configuration as run states it: the MMD term draws its ``samples * C`` target
+nodes with replacement (the drawn indices are an input here, so both sides
+see the same nodes); MMD distances are floored at 1e-12 before the square
+root.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+EPS = 1e-8
+
+
+def _silu(x):
+    return x * jax.nn.sigmoid(x)
+
+
+def _round_mantissa(x, bits):
+    """float32 ``x`` rounded to nearest at ``bits`` explicit mantissa bits
+    (an 8-bit float with an ideal scale per element when ``bits`` is 3), with
+    a straight-through gradient."""
+    drop = 23 - bits
+    u = jax.lax.bitcast_convert_type(jax.lax.stop_gradient(x), jnp.uint32)
+    u = (u + jnp.uint32(1 << (drop - 1))) & jnp.uint32(~((1 << drop) - 1) & 0xFFFFFFFF)
+    return x + jax.lax.stop_gradient(jax.lax.bitcast_convert_type(u, jnp.float32) - x)
+
+
+def _dense(x, w, mantissa):
+    """``mantissa``: None = float32 as it is; the control rounds both matmul
+    operands of every MLP to that many mantissa bits (3: one step below
+    bfloat16's 7)."""
+    if mantissa is not None:
+        x, w = _round_mantissa(x, mantissa), _round_mantissa(w, mantissa)
+    return x @ w
+
+
+def _mlp(w, name, x, act_last=False, mantissa=None):
+    """Dense -> SiLU -> Dense [-> SiLU]; the second Dense has no bias where
+    the weights carry none (coordinate heads)."""
+    x = _silu(_dense(x, w[name + ".0.w"], mantissa) + w[name + ".0.b"])
+    x = _dense(x, w[name + ".1.w"], mantissa)
+    if name + ".1.b" in w:
+        x = x + w[name + ".1.b"]
+    return _silu(x) if act_last else x
+
+
+def _layer(w, l, normalize, mantissa, h, x, X, Hv, vel, attr, row, col, eattr, ew):
+    """One FastEGNN layer on one graph. h [n,H], x [n,3], X [3,C] virtual
+    coordinates, Hv [H,C] virtual features. Edge (row, col) carries a message
+    to ``row`` from ``col``; ``ew`` is 1 for an edge and 0 for list padding."""
+    p = f"l{l}."
+    n, H = h.shape
+    C = X.shape[1]
+    mlp = functools.partial(_mlp, mantissa=mantissa)
+
+    # real edges
+    diff = x[row] - x[col]
+    radial = jnp.sum(diff * diff, axis=-1, keepdims=True)
+    if normalize:
+        diff = diff / (jax.lax.stop_gradient(jnp.sqrt(radial)) + EPS)
+    m = mlp(w, p + "phi_e", jnp.concatenate([h[row], h[col], radial, eattr], -1),
+            act_last=True) * ew[:, None]                         # [e,H]
+
+    # virtual edges: every node sees the C virtual nodes
+    vdiff = X[None, :, :] - x[:, :, None]                        # [n,3,C]
+    vrad = jnp.sqrt(jnp.sum(vdiff * vdiff, axis=1))              # [n,C]
+    Xc = X - jnp.mean(x, axis=0)[:, None]
+    mX = Xc.T @ Xc                                               # [C,C]
+    v_in = jnp.concatenate([
+        jnp.broadcast_to(h[:, None, :], (n, C, H)),
+        jnp.broadcast_to(Hv.T[None], (n, C, H)),
+        vrad[:, :, None],
+        jnp.broadcast_to(mX[None], (n, C, C)),
+    ], axis=-1)
+    mv = mlp(w, p + "phi_ev", v_in, act_last=True)              # [n,C,H]
+
+    # coordinates: mean over incoming edges, mean over virtual nodes, velocity
+    deg = jnp.maximum(jax.ops.segment_sum(ew[:, None], row, n), 1.0)
+    x_new = x + jax.ops.segment_sum(diff * mlp(w, p + "phi_x", m) * ew[:, None], row, n) / deg
+    x_new = x_new + jnp.mean(-vdiff * mlp(w, p + "phi_xv", mv)[:, None, :, 0], axis=-1)
+    x_new = x_new + mlp(w, p + "phi_v", h) * vel
+
+    # virtual coordinates: global mean over nodes
+    X_new = X + jnp.mean(vdiff * mlp(w, p + "phi_X", mv)[:, None, :, 0], axis=0)
+
+    # node features
+    agg = jax.ops.segment_sum(m, row, n) / deg
+    n_in = jnp.concatenate([h, agg, jnp.mean(mv, axis=1), attr], axis=-1)
+    h_new = h + mlp(w, p + "phi_h", n_in)
+
+    # virtual features: global mean over nodes
+    hv_in = jnp.concatenate([Hv.T, jnp.mean(mv, axis=0)], axis=-1)   # [C,2H]
+    Hv_new = Hv + mlp(w, p + "phi_hv", hv_in).T
+    return h_new, x_new, X_new, Hv_new
+
+
+def forward(w, model, g, mantissa=None):
+    """One graph -> (predicted positions [n,3], virtual coordinates [3,C])."""
+    C = model["virtual_channels"]
+    h = g["feat"] @ w["embed.w"] + w["embed.b"]
+    x = g["loc"]
+    X = jnp.repeat(g["loc_mean"][:, None], C, axis=1)
+    Hv = w["virtual_feat"]
+    for l in range(model["n_layers"]):
+        lay = jax.checkpoint(functools.partial(_layer, w, l, bool(model["normalize"]), mantissa))
+        h, x, X, Hv = lay(h, x, X, Hv, g["vel"], g["attr"], g["row"], g["col"],
+                          g["eattr"], g["ew"])
+    return x, X
+
+
+def _kernel_sum(a, b, sigma):
+    d2 = jnp.sum((a[:, None, :] - b[None, :, :]) ** 2, axis=-1)
+    return jnp.sum(jnp.exp(-jnp.sqrt(jnp.maximum(d2, 1e-24)) / (2.0 * sigma * sigma)))
+
+
+def _block_terms(w, model, mmd, blk, mantissa):
+    """Sums over one block of graphs: squared error over the rows that count
+    (``loss_rows``, all ones unless a fault is planted), k(V,V), k(samples,V)."""
+    def one(g):
+        pred, X = forward(w, model, g, mantissa)
+        sse = jnp.sum((pred - g["target"]) ** 2 * g["loss_rows"][:, None])
+        V = X.T
+        k_vv = _kernel_sum(V, V, mmd["sigma"])
+        k_rv = _kernel_sum(g["target"][g["mmd_idx"]], V, mmd["sigma"])
+        return sse, k_vv, k_rv
+
+    sse, k_vv, k_rv = jax.vmap(one)(blk)
+    return jnp.sum(sse), jnp.sum(k_vv), jnp.sum(k_rv)
+
+
+@functools.partial(jax.jit, static_argnames=("model_key", "mmd_key", "G", "mantissa"))
+def _block_grad(w, blk, rows, *, model_key, mmd_key, G, mantissa=None):
+    """(mse share, mmd share), gradient of their weighted sum, for one block
+    of a batch of ``G`` graphs in which ``rows`` rows count towards the MSE.
+    ``mantissa``: the control, see ``_dense``."""
+    model, mmd = dict(model_key), dict(mmd_key)
+    C = model["virtual_channels"]
+    S = mmd["samples"] * C
+
+    def loss(w):
+        sse, k_vv, k_rv = _block_terms(w, model, mmd, blk, mantissa)
+        mse = sse / (rows * 3)
+        mmd_l = k_vv / G / C / C - 2.0 * k_rv / G / S / C
+        return mse + mmd["weight"] * mmd_l, (mse, mmd_l)
+
+    (_, (mse, mmd_l)), grads = jax.value_and_grad(loss, has_aux=True)(w)
+    return mse, mmd_l, grads
+
+
+def _hashable(d):
+    return tuple(sorted(d.items()))
+
+
+def micro_step(w, model, mmd, batch, block, half=False, mantissa=None):
+    """Loss and gradient of one micro-batch (``G`` stacked graphs), summed
+    over blocks of ``block`` graphs. Returns (mse, mse + weight*mmd, grads).
+
+    ``half`` plants the fault "half of the batch left out, the mean taken
+    over the rest": of several graphs the second half, of one graph the rows
+    its ``second_half`` marks (the half the program's loader puts last)."""
+    G, n = batch["loc"].shape[:2]
+    second = batch.pop("second_half") if "second_half" in batch else jnp.zeros((G, n))
+    if not half:
+        keep = jnp.ones((G, n), jnp.float32)
+    elif G > 1:
+        keep = jnp.broadcast_to((jnp.arange(G) < (G + 1) // 2)[:, None], (G, n)).astype(jnp.float32)
+    else:
+        keep = 1.0 - second.astype(jnp.float32)
+    batch = dict(batch, loss_rows=keep)
+    rows = jnp.sum(keep)
+    if G % block:
+        raise ValueError(f"batch of {G} graphs is not a multiple of block {block}")
+    mse = mm = 0.0
+    grads = None
+    with jax.default_matmul_precision("highest"):
+        for s in range(0, G, block):
+            blk = {k: v[s:s + block] for k, v in batch.items()}
+            a, b, g = _block_grad(w, blk, rows, model_key=_hashable(model),
+                                  mmd_key=_hashable(mmd), G=G, mantissa=mantissa)
+            mse, mm = mse + a, mm + b
+            grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
+    return mse, mse + mmd["weight"] * mm, grads
+
+
+@functools.partial(jax.jit, static_argnames=("lr", "wd", "clip"))
+def _adam_update(w, g, mu, nu, t, *, lr, wd, clip):
+    """torch.optim.Adam with L2 weight decay folded into the gradient, after
+    an optional clip of the global norm; ``t`` counts updates from 1."""
+    if clip is not None:
+        norm = jnp.sqrt(sum(jnp.sum(x * x) for x in g.values()))
+        scale = jnp.where(norm < clip, 1.0, clip / norm)
+        g = {k: x * scale for k, x in g.items()}
+    g = {k: g[k] + wd * w[k] for k in g}
+    mu = {k: 0.9 * mu[k] + 0.1 * g[k] for k in g}
+    nu = {k: 0.999 * nu[k] + 0.001 * g[k] * g[k] for k in g}
+    c1, c2 = 1.0 - 0.9 ** t, 1.0 - 0.999 ** t
+    w = {k: w[k] - lr * (mu[k] / c1) / (jnp.sqrt(nu[k] / c2) + 1e-8) for k in g}
+    return w, mu, nu
+
+
+def follow(w0, model, train, batches, block, half=False, mlp_mantissa=None):
+    """Follow the first ``len(batches)`` micro-steps of training from ``w0``.
+
+    ``train``: learning_rate, weight_decay, clip_norm (or None),
+    accumulation_steps, mmd {sigma, weight, samples}. Returns host numpy:
+    ``loss`` [steps] (the logged MSE), ``loss_total`` [steps], ``grad_first``
+    (the first micro-batch's gradient), ``mu`` (Adam's first moment after the
+    last update), ``w`` (weights after the last micro-step)."""
+    acc_k = int(train["accumulation_steps"])
+    w = dict(w0)
+    mu = {k: jnp.zeros_like(v) for k, v in w.items()}
+    nu = {k: jnp.zeros_like(v) for k, v in w.items()}
+    acc, t = None, 0
+    losses, totals, grad_first = [], [], None
+    for i, batch in enumerate(batches):
+        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+        mse, total, g = micro_step(w, model, train["mmd"], batch, block, half=half,
+                                   mantissa=mlp_mantissa)
+        losses.append(mse)
+        totals.append(total)
+        if i == 0:
+            grad_first = g
+        acc = g if acc is None else jax.tree.map(jnp.add, acc, g)
+        if (i + 1) % acc_k == 0:
+            t += 1
+            mean = {k: v / acc_k for k, v in acc.items()}
+            clip = train.get("clip_norm")
+            w, mu, nu = _adam_update(
+                w, mean, mu, nu, float(t), lr=float(train["learning_rate"]),
+                wd=float(train["weight_decay"]),
+                clip=None if clip is None else float(clip))
+            acc = None
+    get = lambda tree: {k: np.asarray(v) for k, v in tree.items()}
+    return {"loss": np.asarray(jnp.stack(losses)),
+            "loss_total": np.asarray(jnp.stack(totals)),
+            "grad_first": get(grad_first), "mu": get(mu), "w": get(w)}

@@ -1,0 +1,138 @@
+#!/usr/bin/env python3
+"""Readings for the limits of ``correct``, many seeds in one process (set-up
+is long; the benchmark's own runs never call this):
+
+    python3 benchmarks/tools/read_limits.py --workload W --seeds 1,2,3 \
+        [--control] [--half 3] [--out chiprun_out/limits_W.jsonl]
+
+For each seed the cell's driver starts a fresh state from the seed, drives the
+first steps through the window's own call, and the plain reference follows
+them: one JSON line of the compared numbers. ``--control`` switches on the
+configuration's lower-precision path (``control`` in its meta file).
+``--half N`` also reads, on the first N seeds, the planted fault "half of the
+rows left out, the mean taken over the rest" with the reference put in the
+program's place.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import importlib
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", required=True)
+    ap.add_argument("--control", action="store_true")
+    ap.add_argument("--half", type=int, default=0)
+    ap.add_argument("--mantissa", type=int, default=0,
+                    help="also read, on the first N seeds, the control 'reference with its MLPs' "
+                         "matmul operands rounded to 3 mantissa bits, put in the program's place'")
+    ap.add_argument("--mantissa-bits", type=int, default=3)
+    ap.add_argument("--leaves", action="store_true", help="emit every leaf's gap")
+    ap.add_argument("--out", default=None)
+    ap.add_argument("--platform", default="tpu")
+    ap.add_argument("--benchmark-file", default=os.path.join(ROOT, "BENCHMARK.json"))
+    args = ap.parse_args()
+
+    import jax
+    import numpy as np
+
+    from benchmarks import compare, run, weights
+    from benchmarks.drivers import common
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", os.path.join(ROOT, ".jax_cache"))
+    bench, cell, config = run.load_cell(args.benchmark_file, args.workload)
+    run.check_device(int(cell["chips"]), args.platform)
+    base = os.path.dirname(os.path.abspath(args.benchmark_file))
+    cfg_file = os.path.join(base, config["file"])
+    beside = os.path.dirname(os.path.dirname(cfg_file))
+    with open(os.path.join(beside, "traffic", cell["traffic"] + ".json")) as f:
+        mix = json.load(f)
+    overrides = common.load_meta(cfg_file)["control"] if args.control else None
+    if overrides and any(k.startswith("reference.") for k in overrides):
+        raise SystemExit("this configuration's control is the reference at lower precision: "
+                         "use --mantissa N --mantissa-bits B")
+    mod = importlib.import_module("benchmarks.drivers." + mix["kind"])
+    out = open(args.out, "a") if args.out else None
+
+    def emit(line: dict) -> None:
+        text = json.dumps(line)
+        print(text, flush=True)
+        if out:
+            out.write(text + "\n")
+            out.flush()
+
+    with contextlib.redirect_stdout(sys.stderr):
+        driver = mod.Driver(cfg_file, mix, 0, overrides=overrides)
+        t0 = time.perf_counter()
+        driver.build()
+        build_s = time.perf_counter() - t0
+    variant = "control" if args.control else "sound"
+
+    def leaves(rec, ref):
+        keep = compare.moving_leaves(ref["grad_first"])
+        delta = lambda r: {k: np.asarray(r[k], np.float64) - rec["w0"][k] for k in r}
+        out = {"moment": compare.leaf_gaps(rec["mu"], ref["mu"]),
+               "change": compare.leaf_gaps(delta(rec["w"]), delta(ref["w"]), keep),
+               "ref_grad_norm": compare._norms(ref["grad_first"]),
+               "ref_change_norm": compare._norms(delta(ref["w"]))}
+        if rec.get("grad") is not None:
+            out["grad"] = compare.leaf_gaps(rec["grad"], ref["grad_first"])
+        return out
+
+    for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
+        with contextlib.redirect_stdout(sys.stderr):
+            t0 = time.perf_counter()
+            w0 = weights.make_weights(seed, driver.dims)
+            driver.start(w0, seed)
+            rec = driver.program_record()
+            inputs = driver.reference_inputs()
+            t1 = time.perf_counter()
+            ref = compare.reference_record(inputs, rec["w0"])
+            t2 = time.perf_counter()
+            nums = compare.numbers(rec, ref)
+        emit({"workload": args.workload, "seed": seed,
+              "variant": variant,
+              "numbers": {k: [v[0], v[1]] for k, v in nums.items()},
+              "loss_program": rec["loss"].tolist(), "loss_reference": ref["loss"].tolist(),
+              "program_s": t1 - t0, "reference_s": t2 - t1, "build_s": build_s})
+        if args.leaves:
+            emit({"workload": args.workload, "seed": seed, "variant": variant + "_leaves",
+                  **leaves(rec, ref)})
+        for on, name, kw in ((i < args.half, "fault_half_rows", {"half": True}),
+                             (i < args.mantissa, f"control_mantissa{args.mantissa_bits}",
+                              {"mlp_mantissa": args.mantissa_bits})):
+            if not on:
+                continue
+            with contextlib.redirect_stdout(sys.stderr):
+                bad = compare.reference_record(inputs, rec["w0"], **kw)
+                loss = bad["loss"] if rec["loss"].shape == bad["loss"].shape \
+                    else np.asarray([bad["loss"].mean()])
+                fake = {"loss": loss, "grad": bad["grad_first"] if rec.get("grad") is not None else None,
+                        "mu": bad["mu"], "w": bad["w"], "w0": rec["w0"]}
+                nums = compare.numbers(fake, ref)
+            emit({"workload": args.workload, "seed": seed, "variant": name,
+                  "numbers": {k: [v[0], v[1]] for k, v in nums.items()}})
+            if args.leaves:
+                emit({"workload": args.workload, "seed": seed, "variant": name + "_leaves",
+                      **leaves(fake, ref)})
+    emit({"workload": args.workload, "variant": variant, "memory_stats":
+          {k: int(v) for k, v in (jax.local_devices()[0].memory_stats() or {}).items()}})
+    if out:
+        out.close()
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

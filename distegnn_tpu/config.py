@@ -370,8 +370,9 @@ _DEFAULTS: dict = {
         # install the jax.monitoring compile watcher (recompiles-after-warmup
         # are the #1 silent perf bug; see scripts/obs_report.py --check)
         "jax_probe": True,
-        # per-step train/step events from the host epoch loop (scan-epoch
-        # runs never have them; epoch events are always emitted)
+        # epoch/step/dispatch_s/stall_s on the host epoch loop's train/step
+        # spans (a scanned epoch is one span; train/epoch_end events are
+        # always emitted)
         "step_events": True,
         # writer buffering: flush every N events or T seconds
         "buffer_events": 256,

@@ -20,6 +20,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from distegnn_tpu import obs
 from distegnn_tpu.data.nbody import _find_tag, build_nbody_graph
 from distegnn_tpu.data.partition import split_graph
 
@@ -45,13 +46,14 @@ def write_partitioned_split(
     if all(os.path.exists(p) for p in paths):
         return paths
     shards: List[List[dict]] = [[] for _ in range(world_size)]
-    for i, g in enumerate(graphs):
-        parts = split_graph(
-            g, world_size, split_mode, inner_radius,
-            outer_radius=outer_radius, seed=seed + i,
-        )
-        for p in range(world_size):
-            shards[p].append(parts[p])
+    with obs.span("data/partition", graphs=len(graphs), parts=world_size):
+        for i, g in enumerate(graphs):
+            parts = split_graph(
+                g, world_size, split_mode, inner_radius,
+                outer_radius=outer_radius, seed=seed + i,
+            )
+            for p in range(world_size):
+                shards[p].append(parts[p])
     assert len({len(s) for s in shards}) == 1, "unequal shard lengths"
     os.makedirs(processed_dir, exist_ok=True)
     for p, path in enumerate(paths):
@@ -95,11 +97,12 @@ def process_nbody_distribute(
             loc = np.load(os.path.join(base, f"loc_{split}_{t}.npy"))[:max_samples]
             vel = np.load(os.path.join(base, f"vel_{split}_{t}.npy"))[:max_samples]
             charges = np.load(os.path.join(base, f"charges_{split}_{t}.npy"))[:max_samples]
-            graphs = [
-                build_nbody_graph(loc[k, frame_0], vel[k, frame_0], charges[k],
-                                  loc[k, frame_T], with_edges=False)
-                for k in range(loc.shape[0])
-            ]
+            with obs.span("data/build_graph", graphs=loc.shape[0]):
+                graphs = [
+                    build_nbody_graph(loc[k, frame_0], vel[k, frame_0], charges[k],
+                                      loc[k, frame_T], with_edges=False)
+                    for k in range(loc.shape[0])
+                ]
             write_partitioned_split(
                 graphs, processed_dir, key, world_size, split_mode,
                 inner_radius, outer_radius, seed=seed,

@@ -21,6 +21,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from distegnn_tpu import obs
 from distegnn_tpu.data.distribute import write_partitioned_split
 from distegnn_tpu.data.water3d import _split_seed
 
@@ -146,17 +147,18 @@ def process_large_fluid_distribute(data_dir: str, dataset_name: str, world_size:
             continue
         rng = np.random.default_rng(_split_seed(seed, split))
         graphs = []
-        for idx in range(lo, hi):
-            if len(graphs) >= max_samples:
-                break
-            pos, vel, viscosity, mass = read_sim(data_dir, dataset_name, idx)
-            n = min(FRAMES_PER_SIM, max_samples - len(graphs))
-            hi_f = min(FRAME_RANGE, pos.shape[0] - delta_t - 1)
-            if hi_f <= 0:
-                continue  # simulation too short for this delta_t
-            for frame in rng.integers(0, hi_f, size=n):
-                graphs.append(build_fluid_graph(pos[frame], vel[frame], viscosity,
-                                                mass, pos[frame + delta_t]))
+        with obs.span("data/build_graph", split=split):
+            for idx in range(lo, hi):
+                if len(graphs) >= max_samples:
+                    break
+                pos, vel, viscosity, mass = read_sim(data_dir, dataset_name, idx)
+                n = min(FRAMES_PER_SIM, max_samples - len(graphs))
+                hi_f = min(FRAME_RANGE, pos.shape[0] - delta_t - 1)
+                if hi_f <= 0:
+                    continue  # simulation too short for this delta_t
+                for frame in rng.integers(0, hi_f, size=n):
+                    graphs.append(build_fluid_graph(pos[frame], vel[frame], viscosity,
+                                                    mass, pos[frame + delta_t]))
         write_partitioned_split(graphs, processed_dir, key, world_size,
                                 split_mode, inner_radius, outer_radius, seed=seed)
     return out

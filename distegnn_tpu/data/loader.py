@@ -162,8 +162,9 @@ class GraphDataset:
                 self.graphs = list(self.graphs)
             # per-slot replacement so peak payload residency stays one
             # dataset + one graph, not two full array sets
-            for i in range(len(self.graphs)):
-                self.graphs[i] = morton_reorder_graph(self.graphs[i])
+            with obs.span("data/reorder", graphs=len(self.graphs)):
+                for i in range(len(self.graphs)):
+                    self.graphs[i] = morton_reorder_graph(self.graphs[i])
         elif node_order not in ("none", None):
             raise ValueError(f"GraphDataset: unknown node_order {node_order!r}")
         _log_host_bytes(graphs_nbytes(self.graphs),
@@ -361,6 +362,7 @@ class ShardedGraphLoader:
     parallelism, which the reference lacks: its ranks all see the same batch,
     SURVEY.md §2.10)."""
 
+    @obs.spanned("data/loader_init")
     def __init__(
         self,
         datasets: Sequence[GraphDataset],

@@ -16,6 +16,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from distegnn_tpu import obs
 from distegnn_tpu.ops.radius import cutoff_edges_np, full_graph_np, radius_graph_np
 
 
@@ -105,13 +106,14 @@ def process_nbody_cutoff(
         vel = np.load(os.path.join(base, f"vel_{split}_{t}.npy"))[:max_samples]
         charges = np.load(os.path.join(base, f"charges_{split}_{t}.npy"))[:max_samples]
 
-        graphs = [
-            build_nbody_graph(
-                loc[k, frame_0], vel[k, frame_0], charges[k], loc[k, frame_T],
-                radius=radius, cutoff_rate=cutoff_rate,
-            )
-            for k in range(loc.shape[0])
-        ]
+        with obs.span("data/build_graph", graphs=loc.shape[0]):
+            graphs = [
+                build_nbody_graph(
+                    loc[k, frame_0], vel[k, frame_0], charges[k], loc[k, frame_T],
+                    radius=radius, cutoff_rate=cutoff_rate,
+                )
+                for k in range(loc.shape[0])
+            ]
         with open(out, "wb") as f:
             pickle.dump(graphs, f, protocol=pickle.HIGHEST_PROTOCOL)
     return paths

@@ -17,8 +17,8 @@ graphs" item, streaming rationale per arXiv:1906.11786):
    work to a bounded background thread (``data.prefetch_depth`` deep, default
    2) so disk read + collate + put overlap the previous step's compute;
    ``data/stall_s`` then measures only true starvation, with the overlapped
-   producer time visible separately as ``data/produce_s`` and the consumer
-   wait as ``data/prefetch_stall_s``.
+   producer time visible separately as ``data/produce_s`` and, batch by
+   batch, as ``data/produce`` spans on the producer's thread.
 
 Determinism is untouched: epoch order lives entirely in
 ``GraphLoader._order()`` (seeded permutation), the shard format round-trips
@@ -328,10 +328,14 @@ class PrefetchLoader:
     Accounting contract (trainer reads per-step deltas of ``data/stall_s``):
     the producer thread runs under ``stall_attribution("data/produce_s")`` so
     the overlapped collate work no longer pollutes the stall counter; only
-    the consumer's real wait on the queue lands on ``data/stall_s`` (and,
-    disaggregated, ``data/prefetch_stall_s``). ``data/prefetch_depth`` gauge
-    reports the configured depth. ``depth=0`` degrades to the old fully
-    synchronous behavior (useful for A/B: bench.py --layout io runs both).
+    the consumer's real wait on the queue lands on ``data/stall_s``.
+    ``data/prefetch_depth`` gauge reports the configured depth. ``depth=0``
+    degrades to the old fully synchronous behavior (useful for A/B: bench.py
+    --layout io runs both).
+
+    Spans: each batch is built under ``data/produce`` > ``data/collate`` (the
+    inner loader's ``next``), ``data/put``. The producer's wait on a full
+    queue lies outside them.
 
     Failure contract: a producer crash propagates as
     :class:`PrefetchCrashError` (original chained as ``__cause__``) on the
@@ -351,9 +355,20 @@ class PrefetchLoader:
     def __len__(self) -> int:
         return len(self.loader)
 
-    def _produce_one(self, batch):
-        self._meter.h2d(batch)
-        return self.put(batch) if self.put is not None else batch
+    def _produce(self, it, stall=None):
+        """The next batch of ``it``, collated and put, or None at its end;
+        with ``stall``, the put's time is added to that counter."""
+        with obs.span("data/produce"):
+            with obs.span("data/collate"):
+                batch = next(it, None)
+            if batch is None:
+                return None
+            with obs.span("data/put") as put:
+                self._meter.h2d(batch)
+                out = self.put(batch) if self.put is not None else batch
+            if stall is not None:
+                stall.add((put.end_ns - put.start_ns) / 1e9)
+            return out
 
     def __iter__(self):
         reg = obs.get_registry()
@@ -361,10 +376,8 @@ class PrefetchLoader:
         if self.depth == 0:
             # synchronous path: put time is trainer stall by definition
             stall = reg.counter("data/stall_s")
-            for batch in self.loader:
-                t0 = time.perf_counter()
-                out = self._produce_one(batch)
-                stall.add(time.perf_counter() - t0)
+            it = iter(self.loader)
+            while (out := self._produce(it, stall)) is not None:
                 yield out
             return
 
@@ -385,8 +398,9 @@ class PrefetchLoader:
         def _producer():
             try:
                 with stall_attribution("data/produce_s"):
-                    for batch in self.loader:
-                        if not _offer(("item", self._produce_one(batch))):
+                    it = iter(self.loader)
+                    while (out := self._produce(it)) is not None:
+                        if not _offer(("item", out)):
                             return
                 _offer(("done", None))
             except BaseException as e:  # must reach the consumer, whatever it is
@@ -396,7 +410,6 @@ class PrefetchLoader:
                              name="distegnn-prefetch")
         t.start()
         stall = reg.counter("data/stall_s")
-        pf_stall = reg.counter("data/prefetch_stall_s")
         try:
             while True:
                 t0 = time.perf_counter()
@@ -409,9 +422,7 @@ class PrefetchLoader:
                             raise PrefetchCrashError(
                                 "prefetch producer thread died without "
                                 "reporting (queue empty, thread dead)")
-                waited = time.perf_counter() - t0
-                stall.add(waited)
-                pf_stall.add(waited)
+                stall.add(time.perf_counter() - t0)
                 if kind == "done":
                     return
                 if kind == "err":

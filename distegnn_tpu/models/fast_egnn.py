@@ -330,21 +330,26 @@ class EGCLVel(nn.Module):
                     c(w1[2 * H:]), c(b1), c(w2), c(b2), c(w3), c(b3), w4)
             rr, rc = g.remote_edge_index[:, 0], g.remote_edge_index[:, 1]
             rm = g.remote_edge_mask[..., None]                   # [B, R, 1]
-            cd_r = (gather_nodes(xk, rr) - gather_nodes(xk, rc)) * rm
+            with jax.named_scope("edge_gather"):
+                x_r, x_c = gather_nodes(xk, rr), gather_nodes(xk, rc)
+                hr_r, hc_c = gather_nodes(hr, rr), gather_nodes(hc, rc)
+            cd_r = (x_r - x_c) * rm
             radial_r = jnp.sum(cd_r * cd_r, axis=-1, keepdims=True)
-            sfeat = c(jnp.concatenate(
-                [radial_r, g.remote_edge_attr[..., :2]], axis=-1))
-            t1 = (gather_nodes(hr, rr) + gather_nodes(hc, rc)
-                  + sfeat @ cws + cb1)
-            ef_r = nn.silu(nn.silu(t1) @ cw2 + cb2)              # [B, R, H]
-            y2 = nn.silu(ef_r @ cw3 + cb3)
-            g_r = (y2.astype(jnp.float32) @ w4r) * rm            # [B, R, 1]
+            with jax.named_scope("edge_mlp"):
+                sfeat = c(jnp.concatenate(
+                    [radial_r, g.remote_edge_attr[..., :2]], axis=-1))
+                t1 = hr_r + hc_c + sfeat @ cws + cb1
+                ef_r = nn.silu(nn.silu(t1) @ cw2 + cb2)          # [B, R, H]
+            with jax.named_scope("coord_update"):
+                y2 = nn.silu(ef_r @ cw3 + cb3)
+                g_r = (y2.astype(jnp.float32) @ w4r) * rm        # [B, R, 1]
             N_ = x.shape[1]
             seg = jax.vmap(
                 lambda val, r: jax.ops.segment_sum(val, r, num_segments=N_))
-            trans_sum = trans_sum + seg(cd_r * g_r, rr)
-            count = count + seg(g.remote_edge_mask, rr)
-            ef_sum = ef_sum + seg(ef_r.astype(jnp.float32) * rm, rr)
+            with jax.named_scope("edge_aggregate"):
+                trans_sum = trans_sum + seg(cd_r * g_r, rr)
+                count = count + seg(g.remote_edge_mask, rr)
+                ef_sum = ef_sum + seg(ef_r.astype(jnp.float32) * rm, rr)
             if tx is not None:
                 # close phi_x with its ONE node-level psum; ef_sum/count were
                 # computed redundantly on every tensor rank — tp_once makes
@@ -365,58 +370,60 @@ class EGCLVel(nn.Module):
                 coord_diff = coord_diff / norm
 
             # --- real edge messages phi_e (:144-150)
-            if self.hoist_edge_mlp:
-                scalars = (jnp.concatenate([radial, g.edge_attr], axis=-1)
-                           if self.edge_attr_nf else radial)
-                edge_feat = HoistedEdgeMLP(H, 1 + self.edge_attr_nf,
-                                           name="phi_e", dtype=dt,
-                                           tensor_axis=self.tensor_axis)(
-                                               h, scalars, ops)
-            else:
-                if self.tensor_axis is not None:
-                    raise ValueError(
-                        "tensor parallelism requires hoist_edge_mlp=True "
-                        "(phi_e's collective is the node-level gather of the "
-                        "hoisted products; the concat-shaped phi_e would "
-                        "need a per-edge gather)")
-                e_in = [ops.gather_rows(h), ops.gather_cols(h), radial]
-                if self.edge_attr_nf:
-                    e_in.append(g.edge_attr)
-                edge_feat = MLP([H, H], act_last=True, name="phi_e", dtype=dt)(
-                    jnp.concatenate(e_in, axis=-1))
-            if self.attention:
-                gate_e = jax.nn.sigmoid(TorchDense(1, name="att", dtype=dt)(edge_feat))
-                edge_feat = edge_feat * gate_e                           # [B, E, H]
-            edge_feat = edge_feat * edge_mask[..., None].astype(edge_feat.dtype)
+            if not self.hoist_edge_mlp and self.tensor_axis is not None:
+                raise ValueError(
+                    "tensor parallelism requires hoist_edge_mlp=True "
+                    "(phi_e's collective is the node-level gather of the "
+                    "hoisted products; the concat-shaped phi_e would "
+                    "need a per-edge gather)")
+            with jax.named_scope("edge_mlp"):
+                if self.hoist_edge_mlp:
+                    scalars = (jnp.concatenate([radial, g.edge_attr], axis=-1)
+                               if self.edge_attr_nf else radial)
+                    edge_feat = HoistedEdgeMLP(H, 1 + self.edge_attr_nf,
+                                               name="phi_e", dtype=dt,
+                                               tensor_axis=self.tensor_axis)(
+                                                   h, scalars, ops)
+                else:
+                    e_in = [ops.gather_rows(h), ops.gather_cols(h), radial]
+                    if self.edge_attr_nf:
+                        e_in.append(g.edge_attr)
+                    edge_feat = MLP([H, H], act_last=True, name="phi_e", dtype=dt)(
+                        jnp.concatenate(e_in, axis=-1))
+                if self.attention:
+                    gate_e = jax.nn.sigmoid(TorchDense(1, name="att", dtype=dt)(edge_feat))
+                    edge_feat = edge_feat * gate_e                       # [B, E, H]
+                edge_feat = edge_feat * edge_mask[..., None].astype(edge_feat.dtype)
 
         # --- virtual-edge geometry (:252-253): every node sees all C virtual nodes
-        vcd = X[:, None, :, :] - x[..., None]                           # [B, N, 3, C]
-        virtual_radial = jnp.linalg.norm(vcd, axis=2, keepdims=True)    # [B, N, 1, C]
+        with jax.named_scope("virtual_update"):
+            vcd = X[:, None, :, :] - x[..., None]                       # [B, N, 3, C]
+            virtual_radial = jnp.linalg.norm(vcd, axis=2, keepdims=True)  # [B, N, 1, C]
 
-        # ---------- psum #1: exact global coordinate mean (:258-261)
-        coord_mean = (tile_coord_mean if tile_coord_mean is not None
-                      else global_node_mean(x, node_mask, self.axis_name))  # [B, 3]
+            # ---------- psum #1: exact global coordinate mean (:258-261)
+            coord_mean = (tile_coord_mean if tile_coord_mean is not None
+                          else global_node_mean(x, node_mask, self.axis_name))  # [B, 3]
 
-        # --- invariant virtual mixing m_X: Gram of centered virtual coords (:263-264)
-        Xc = X - coord_mean[:, :, None]                                  # [B, 3, C]
-        m_X = jnp.einsum("bdc,bde->bce", Xc, Xc)                        # [B, C, C]
+            # --- invariant virtual mixing m_X: Gram of centered virtual coords (:263-264)
+            Xc = X - coord_mean[:, :, None]                              # [B, 3, C]
+            m_X = jnp.einsum("bdc,bde->bce", Xc, Xc)                    # [B, C, C]
 
-        # --- virtual edge messages phi_ev (:153-163): [B, N, C, 2H+1+C] -> [B, N, C, H]
-        B, N = h.shape[0], h.shape[1]
-        v_in = jnp.concatenate(
-            [
-                jnp.broadcast_to(h[:, :, None, :], (B, N, C, H)),
-                jnp.broadcast_to(jnp.swapaxes(Hv, 1, 2)[:, None, :, :], (B, N, C, H)),
-                jnp.swapaxes(virtual_radial, 2, 3),                      # [B, N, C, 1]
-                jnp.broadcast_to(m_X[:, None, :, :], (B, N, C, C)),
-            ],
-            axis=-1,
-        )
-        vef = MLP([H, H], act_last=True, name="phi_ev", dtype=dt)(v_in)  # [B, N, C, H]
-        if self.attention:
-            gate = jax.nn.sigmoid(TorchDense(1, name="att_v", dtype=dt)(vef))
-            vef = vef * gate
-        vef = vef * node_mask[:, :, None, None].astype(vef.dtype)        # zero padded nodes
+            # --- virtual edge messages phi_ev (:153-163): [B, N, C, 2H+1+C] -> [B, N, C, H]
+            B, N = h.shape[0], h.shape[1]
+            v_in = jnp.concatenate(
+                [
+                    jnp.broadcast_to(h[:, :, None, :], (B, N, C, H)),
+                    jnp.broadcast_to(jnp.swapaxes(Hv, 1, 2)[:, None, :, :], (B, N, C, H)),
+                    jnp.swapaxes(virtual_radial, 2, 3),                  # [B, N, C, 1]
+                    jnp.broadcast_to(m_X[:, None, :, :], (B, N, C, C)),
+                ],
+                axis=-1,
+            )
+            vef = MLP([H, H], act_last=True, name="phi_ev", dtype=dt)(v_in)  # [B, N, C, H]
+            if self.attention:
+                gate = jax.nn.sigmoid(TorchDense(1, name="att_v", dtype=dt)(vef))
+                vef = vef * gate
+            vef = vef * node_mask[:, :, None, None].astype(vef.dtype)    # zero padded nodes
 
         # --- real coordinate update (coord_model_vel, :166-188); the fused
         # path already holds the aggregated translations in `agg`
@@ -426,10 +433,11 @@ class EGCLVel(nn.Module):
             # node axis, where ONE psum of [B, N, 3] closes the MLP —
             # per-edge traffic never crosses the tensor axis. coord_diff is
             # tp_copy-wrapped so its cotangent (partial per rank) is summed.
-            cdm = (tp_copy(coord_diff, self.tensor_axis)
-                   if self.tensor_axis is not None else coord_diff)
-            trans = cdm * CoordMLP(H, tanh=self.tanh, name="phi_x", dtype=dt,
-                                   tensor_axis=self.tensor_axis)(edge_feat)  # [B, E, 3]
+            with jax.named_scope("coord_update"):
+                cdm = (tp_copy(coord_diff, self.tensor_axis)
+                       if self.tensor_axis is not None else coord_diff)
+                trans = cdm * CoordMLP(H, tanh=self.tanh, name="phi_x", dtype=dt,
+                                       tensor_axis=self.tensor_axis)(edge_feat)  # [B, E, 3]
             if self.fuse_agg:
                 # both per-layer aggregations (+ the count) in ONE pass (blocked
                 # layouts keep two calls inside but honor the agg_dtype knob)
@@ -442,51 +450,56 @@ class EGCLVel(nn.Module):
                 agg_h_f = None
             if self.tensor_axis is not None:
                 agg = tp_reduce(agg, self.tensor_axis)
-        x = x + agg
+        with jax.named_scope("coord_update"):
+            x = x + agg
 
-        phi_xv = CoordMLP(H, tanh=self.tanh, name="phi_xv", dtype=dt)(vef)  # [B, N, C, 1]
-        trans_v = jnp.mean(-vcd * jnp.swapaxes(phi_xv, 2, 3), axis=-1)   # [B, N, 3]
-        x = x + trans_v
-        x = x + MLP([H, 1], name="phi_v", dtype=dt)(h).astype(jnp.float32) * v
-        if self.has_gravity:
-            x = x + MLP([H, 1], name="phi_g", dtype=dt)(h).astype(jnp.float32) * gravity
-        x = x * nm  # keep padding clean
+            phi_xv = CoordMLP(H, tanh=self.tanh, name="phi_xv", dtype=dt)(vef)  # [B, N, C, 1]
+            trans_v = jnp.mean(-vcd * jnp.swapaxes(phi_xv, 2, 3), axis=-1)  # [B, N, 3]
+            x = x + trans_v
+            x = x + MLP([H, 1], name="phi_v", dtype=dt)(h).astype(jnp.float32) * v
+            if self.has_gravity:
+                x = x + MLP([H, 1], name="phi_g", dtype=dt)(h).astype(jnp.float32) * gravity
+            x = x * nm  # keep padding clean
 
         # ---------- psum #2: virtual coordinate update (coord_model_virtual, :191-200)
-        trans_X = vcd * jnp.swapaxes(CoordMLP(H, tanh=self.tanh, name="phi_X", dtype=dt)(vef), 2, 3)  # [B, N, 3, C]
-        if tile_partials:
-            transX_part = masked_sum(trans_X, node_mask, axis=1)         # [B, 3, C]
-        else:
-            X = X + global_node_mean(trans_X, node_mask, self.axis_name)  # [B, 3, C]
+        with jax.named_scope("virtual_update"):
+            trans_X = vcd * jnp.swapaxes(CoordMLP(H, tanh=self.tanh, name="phi_X", dtype=dt)(vef), 2, 3)  # [B, N, 3, C]
+            if tile_partials:
+                transX_part = masked_sum(trans_X, node_mask, axis=1)     # [B, 3, C]
+            else:
+                X = X + global_node_mean(trans_X, node_mask, self.axis_name)  # [B, 3, C]
 
         # --- node feature update (node_model, :203-217)
         agg_h = agg_h_f if agg_h_f is not None else ops.agg_rows_mean(edge_feat)
-        agg_v = jnp.mean(vef, axis=2)                                    # [B, N, H]
-        n_in = [h, agg_h, agg_v]
-        if self.node_attr_nf:
-            n_in.append(g.node_attr)
-        out = MLP([H, H], name="phi_h", dtype=dt,
-                  tensor_axis=self.tensor_axis)(jnp.concatenate(
-                      [a.astype(jnp.float32) for a in n_in], axis=-1))
-        h = (h + out) if self.residual else out
-        h = h * nm
+        with jax.named_scope("node_update"):
+            agg_v = jnp.mean(vef, axis=2)                                # [B, N, H]
+            n_in = [h, agg_h, agg_v]
+            if self.node_attr_nf:
+                n_in.append(g.node_attr)
+            out = MLP([H, H], name="phi_h", dtype=dt,
+                      tensor_axis=self.tensor_axis)(jnp.concatenate(
+                          [a.astype(jnp.float32) for a in n_in], axis=-1))
+            h = (h + out) if self.residual else out
+            h = h * nm
 
         # ---------- psum #3: virtual feature update (node_model_virtual, :220-234)
-        if tile_partials:
-            # same numerator/denominator as the two global_node_means above,
-            # summed across tiles by the executor — phi_hv is applied there
-            # (flax ignores the unused phi_hv subtree in this mode)
-            vef_part = masked_sum(vef.astype(jnp.float32), node_mask, axis=1)  # [B, C, H]
-            count = jnp.sum(node_mask.astype(jnp.float32), axis=1)       # [B]
-            return h, x, transX_part, vef_part, count
-        agg_Hv = global_node_mean(vef.astype(jnp.float32), node_mask, self.axis_name)  # [B, C, H]
-        hv_in = jnp.concatenate([jnp.swapaxes(Hv, 1, 2), agg_Hv], axis=-1)  # [B, C, 2H]
-        out_v = jnp.swapaxes(MLP([H, H], name="phi_hv", dtype=dt)(hv_in), 1, 2)  # [B, H, C]
-        Hv = (Hv + out_v) if self.residual else out_v
+        with jax.named_scope("virtual_update"):
+            if tile_partials:
+                # same numerator/denominator as the two global_node_means above,
+                # summed across tiles by the executor — phi_hv is applied there
+                # (flax ignores the unused phi_hv subtree in this mode)
+                vef_part = masked_sum(vef.astype(jnp.float32), node_mask, axis=1)  # [B, C, H]
+                count = jnp.sum(node_mask.astype(jnp.float32), axis=1)   # [B]
+                return h, x, transX_part, vef_part, count
+            agg_Hv = global_node_mean(vef.astype(jnp.float32), node_mask, self.axis_name)  # [B, C, H]
+            hv_in = jnp.concatenate([jnp.swapaxes(Hv, 1, 2), agg_Hv], axis=-1)  # [B, C, 2H]
+            out_v = jnp.swapaxes(MLP([H, H], name="phi_hv", dtype=dt)(hv_in), 1, 2)  # [B, H, C]
+            Hv = (Hv + out_v) if self.residual else out_v
 
         return h, x, Hv, X
 
 
+@jax.named_scope("virtual_update")
 def tiled_virtual_update(gcl_params, Hv, X, transX_sum, vef_sum, count, *,
                          residual: bool = True,
                          compute_dtype: Optional[str] = None):
@@ -511,6 +524,7 @@ def tiled_virtual_update(gcl_params, Hv, X, transX_sum, vef_sum, count, *,
     return Hv, X
 
 
+@jax.named_scope("virtual_update")
 def reduce_tile_partials(transX_part, vef_part, count, valid, axis_name):
     """Cross-device reduction of one tile ROUND's virtual-node partials
     (serve/mesh_tiled.py): each device of the round holds ONE tile's
@@ -602,7 +616,8 @@ class FastEGNN(nn.Module):
         # virtual coords start at the global location mean, replicated C times (:300)
         X = jnp.repeat(g.loc_mean[:, :, None], C, axis=2)                # [B, 3, C]
 
-        h = TorchDense(H, name="embedding_in")(g.node_feat)  # f32: one small matmul
+        with jax.named_scope("embed"):
+            h = TorchDense(H, name="embedding_in")(g.node_feat)  # f32: one small matmul
         x, v = g.loc, g.vel
         gravity = jnp.asarray(self.gravity, jnp.float32) if self.gravity is not None else None
 

@@ -1,61 +1,76 @@
-"""JAX-runtime probes: compile (recompile!) watcher, device memory, transfers.
+"""JAX-runtime probes: compile (recompile!) spans and watcher, device memory,
+transfers.
 
 The #1 silent perf bug on a shape-laddered TPU stack is a recompile after
 warmup — a shape drifting past its bucket, a weak_type flip, a donated buffer
 changing layout — which shows up only as a mysteriously slow step. XLA's
 compiles are invisible to user code EXCEPT through ``jax.monitoring``: every
-backend compile records a ``/jax/core/compile/backend_compile_duration``
-event. :class:`CompileWatcher` hooks that stream, attributes each compile to
-the phase the runtime declared (``warmup``, ``epoch<N>``, ``serve``, ...) and
-counts compiles-after-warmup separately so ``scripts/obs_report.py --check``
-can fail a run on them.
+backend compile, and every retrieval from the persistent cache in its place,
+records a ``/jax/core/compile/backend_compile_duration`` event with the
+``fun_name`` of the program. One listener, registered when this module is
+imported (``distegnn_tpu.obs`` imports it), turns each into a ``jax/compile``
+span (``fun_name``) in the ring: ``obs.recent_spans()`` then answers "which
+program compiled how often" whether or not a run configured anything.
+:class:`CompileWatcher` (``obs.jax_probe``) adds, on top, the phase the
+runtime declared (``warmup``, ``epoch<N>``, ``serve``, ...), counts
+compiles-after-warmup so ``scripts/obs_report.py --check`` can fail a run on
+them, and is what sends the span to ``events.jsonl``: without a watcher the
+file holds no ``jax/compile`` record.
 
 Listener lifetime: ``jax.monitoring`` listeners cannot portably be removed,
-so ONE module-level listener is registered (idempotently) and dispatches to
-the currently-active watcher — re-configuring a run (or running many tests in
-one process) swaps the watcher, never stacks listeners.
+so the one listener dispatches to the currently-active watcher —
+re-configuring a run (or running many tests in one process) swaps the
+watcher, never stacks listeners.
 
 Also here: ``device_memory_stats()`` (``memory_stats()`` of local device 0,
 when the backend exposes it — TPU/GPU yes, CPU None) and
-:class:`TransferMeter` host->device byte accounting for loader/donation
-boundaries.
+:class:`TransferMeter` host->device byte accounting for the loaders.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Dict, Optional
+
+import jax.monitoring
 
 from distegnn_tpu.obs import metrics as _metrics
 from distegnn_tpu.obs import trace as _trace
 
-# the jax.monitoring event marking one real backend (XLA) compile
+# one per program handed to the backend: a real XLA compile or, with the
+# persistent cache on, the retrieval that replaced it
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-_listener_installed = False
-_install_lock = threading.Lock()
 _active: Optional["CompileWatcher"] = None
 
 
 def _on_duration_event(event: str, duration_secs: float, **kwargs) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    end_ns = time.perf_counter_ns()
+    attrs: Dict[str, Any] = {"fun_name": kwargs.get("fun_name")}
     w = _active
-    if w is not None and event == _COMPILE_EVENT:
-        w._record_compile(duration_secs)
+    if w is not None:
+        attrs.update(w._record_compile(duration_secs))
+    _trace.record_span("jax/compile", end_ns - int(duration_secs * 1e9),
+                       end_ns, sink=w is not None, **attrs)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
 
 
 class CompileWatcher:
-    """Counts XLA compiles and attributes them to runtime-declared phases.
+    """Attributes compiles to runtime-declared phases and counts them.
 
     Counters (global registry): ``jax/compiles`` (total),
     ``jax/compiles_after_warmup`` (the alarm), ``jax/compile_s`` (time spent
-    compiling). Each compile also lands in the event stream as a
-    ``jax/compile`` event with its phase, so the report can render a
-    recompile table.
+    compiling). While a watcher is active every ``jax/compile`` span carries
+    its ``phase`` and ``after_warmup``, so the report can render a recompile
+    table.
     """
 
-    def __init__(self, tracer: Optional[_trace.Tracer] = None,
-                 registry: Optional[_metrics.MetricsRegistry] = None):
-        self.tracer = tracer or _trace.get_tracer()
+    def __init__(self, registry: Optional[_metrics.MetricsRegistry] = None):
         self.registry = registry or _metrics.get_registry()
         self._lock = threading.Lock()
         self.phase = "warmup"
@@ -73,7 +88,7 @@ class CompileWatcher:
         with self._lock:
             self.warmup_done = True
 
-    def _record_compile(self, duration_secs: float) -> None:
+    def _record_compile(self, duration_secs: float) -> Dict[str, Any]:
         with self._lock:
             self.compiles += 1
             after = self.warmup_done
@@ -84,9 +99,7 @@ class CompileWatcher:
         self.registry.counter("jax/compile_s").add(duration_secs)
         if after:
             self.registry.counter("jax/compiles_after_warmup").add(1)
-        self.tracer.event("jax/compile", phase=phase,
-                          dur_s=round(duration_secs, 6),
-                          after_warmup=after)
+        return {"phase": phase, "after_warmup": after}
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
@@ -95,23 +108,13 @@ class CompileWatcher:
                     "phase": self.phase, "warmup_done": self.warmup_done}
 
 
-def install_compile_watcher(tracer: Optional[_trace.Tracer] = None,
-                            registry: Optional[_metrics.MetricsRegistry] = None
+def install_compile_watcher(registry: Optional[_metrics.MetricsRegistry] = None
                             ) -> CompileWatcher:
-    """Install (or re-target) THE process compile watcher. The underlying
-    jax.monitoring listener registers once per process; the active watcher —
-    the one counting — is swapped atomically."""
-    global _active, _listener_installed
-    watcher = CompileWatcher(tracer, registry)
-    with _install_lock:
-        if not _listener_installed:
-            import jax.monitoring
-
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_duration_event)
-            _listener_installed = True
-        _active = watcher
-    return watcher
+    """Install (or re-target) THE process compile watcher: the one the
+    module's listener hands every compile to."""
+    global _active
+    _active = CompileWatcher(registry)
+    return _active
 
 
 def get_compile_watcher() -> Optional[CompileWatcher]:
@@ -119,7 +122,7 @@ def get_compile_watcher() -> Optional[CompileWatcher]:
 
 
 def deactivate_compile_watcher() -> None:
-    """Stop counting (the listener stays registered but dispatches nowhere)."""
+    """Stop counting (``jax/compile`` spans go on, without phases)."""
     global _active
     _active = None
 
@@ -197,23 +200,16 @@ def tree_nbytes(tree) -> int:
 
 
 class TransferMeter:
-    """Byte counters around the host<->device boundary. The loaders/putters
-    call ``h2d(batch)`` on everything they hand to the device; fetches of
-    results call ``d2h``. Counters live in the global registry
-    (``xfer/h2d_bytes``, ``xfer/d2h_bytes``) so they appear in every
+    """Byte counter at the host->device boundary. The loaders/putters call
+    ``h2d(batch)`` on everything they hand to the device. The counter lives
+    in the global registry (``xfer/h2d_bytes``) so it appears in every
     snapshot without plumbing."""
 
     def __init__(self, registry: Optional[_metrics.MetricsRegistry] = None):
         reg = registry or _metrics.get_registry()
         self._h2d = reg.counter("xfer/h2d_bytes")
-        self._d2h = reg.counter("xfer/d2h_bytes")
 
     def h2d(self, tree) -> int:
         n = tree_nbytes(tree)
         self._h2d.add(n)
-        return n
-
-    def d2h(self, tree) -> int:
-        n = tree_nbytes(tree)
-        self._d2h.add(n)
         return n

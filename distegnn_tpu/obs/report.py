@@ -81,8 +81,10 @@ def _named(events, name):
 
 
 def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
+    # train/step: the step loop's span (dur_s = the host's whole step, with
+    # stall_s and dispatch_s); train/epoch_end: the trainer's per-epoch event
     steps = _named(events, "train/step")
-    epochs = _named(events, "train/epoch")
+    epochs = _named(events, "train/epoch_end")
     compiles = _named(events, "jax/compile")
     saves = _named(events, "ckpt/save")
     restores = _named(events, "ckpt/restore")
@@ -113,6 +115,14 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
 
     serve_exec_ms = sorted(1e3 * float(e["dur_s"])
                            for e in serve_batches if "dur_s" in e)
+
+    spans: Dict[str, Dict[str, float]] = defaultdict(
+        lambda: {"count": 0, "total_s": 0.0})
+    for e in events:
+        if e.get("kind") == "span":
+            row = spans[str(e.get("name"))]
+            row["count"] += 1
+            row["total_s"] += float(e.get("dur_s", 0.0))
 
     return {
         "n_events": len(events),
@@ -154,6 +164,9 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
             "exec_p50_ms": round(percentile(serve_exec_ms, 50), 3),
             "exec_p99_ms": round(percentile(serve_exec_ms, 99), 3),
         },
+        "spans": {k: {"count": int(v["count"]),
+                      "total_s": round(v["total_s"], 4)}
+                  for k, v in sorted(spans.items())},
         "faults": [{k: e.get(k) for k in
                     ("ts", "name", "epoch", "step", "msg", "reason",
                      "lr_scale", "path") if k in e} for e in faults],
@@ -201,6 +214,12 @@ def render_text(summary: Dict[str, Any], source: str = "",
         lines.append(f"serve: {sv['batches']} batch(es)  "
                      f"exec p50 {sv['exec_p50_ms']} ms  "
                      f"p99 {sv['exec_p99_ms']} ms")
+    if s["spans"]:
+        # every span the run wrote, set-up included (data/build_graph,
+        # data/partition, data/reorder, data/loader_init, train/make_step)
+        lines.append("spans:                          count     total")
+        for name, row in s["spans"].items():
+            lines.append(f"  {name:<28} {row['count']:>6}  {row['total_s']:>9.3f} s")
     if s["faults"]:
         lines.append("fault timeline:")
         t0 = s["faults"][0].get("ts") or 0.0

@@ -1,11 +1,24 @@
-"""Low-overhead structured tracing: spans + events -> buffered JSONL.
+"""Low-overhead structured tracing: spans + events -> ring, profiler, JSONL.
 
 One process-global :class:`Tracer` (swap it with :func:`configure`) serves
-every runtime — trainer, serve stack, loaders, checkpointing. When no sink is
-configured (the default until a run calls :func:`configure`, and always under
-``obs.enable: false``) every call is a near-zero-cost no-op: ``span()``
-returns a shared null context manager and ``event()`` returns immediately, so
-instrumentation can stay in the hot paths unconditionally.
+every runtime — trainer, serve stack, loaders, checkpointing. ``span()`` is
+the one instrumentation call and has three sinks:
+
+1. a ``jax.profiler.TraceAnnotation`` of the same name, so whenever anyone
+   records a profiler trace the span lies in the xplane's host plane, on the
+   device trace's clock;
+2. a bounded in-memory ring of :class:`SpanRecord` (:func:`recent_spans`,
+   the newest ``RING_SIZE``), on ``time.perf_counter_ns``, each with the CPU
+   time its thread spent inside it (``time.thread_time_ns``: a span that
+   blocks in a call, on a queue or on the device's backpressure, is long but
+   cheap), its own id and the id of the span that enclosed it on its thread;
+3. ``events.jsonl``, when :func:`configure` has bound a sink.
+
+Ring and annotation do not depend on the sink: ``obs.enable: false`` and
+``train(log=False)`` mean "no file, no watcher events", not "no spans".
+``event()`` has the JSONL sink alone and returns at once without one. A span
+costs four clock reads, one annotation object and one ring append
+(docs/OBSERVABILITY.md has the micro-timing).
 
 Event schema (docs/OBSERVABILITY.md): one JSON object per line,
   {"ts": <unix seconds>, "kind": "span"|"event"|"log", "name": str,
@@ -26,12 +39,19 @@ live — the same message lands in events.jsonl as a ``log`` event.
 from __future__ import annotations
 
 import atexit
+import collections
+import functools
+import itertools
 import json
 import os
 import socket
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
+
+RING_SIZE = 8192
 
 
 def _process_index() -> int:
@@ -97,29 +117,59 @@ class EventWriter:
         atexit.unregister(self.close)
 
 
-class _NullSpan:
-    """Shared no-op context manager — the disabled-tracer fast path."""
+class SpanRecord(NamedTuple):
+    """One finished span as the ring keeps it. Times are
+    ``time.perf_counter_ns``; ``cpu_ns`` is the thread's CPU time inside the
+    span (0 for a span recorded after the fact); ``parent`` is 0 for a span
+    with none open around it on its thread."""
 
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **attrs) -> "_NullSpan":
-        return self
+    name: str
+    thread: str
+    start_ns: int
+    end_ns: int
+    cpu_ns: int
+    id: int
+    parent: int
+    attrs: Dict[str, Any]
 
 
-_NULL_SPAN = _NullSpan()
+_ring: "collections.deque[SpanRecord]" = collections.deque(maxlen=RING_SIZE)
+_ids = itertools.count(1)
+_open = threading.local()       # .stack: this thread's open spans, outermost first
+
+
+def recent_spans() -> List[SpanRecord]:
+    """The newest ``RING_SIZE`` finished spans of this process, in the order
+    they ended."""
+    return list(_ring.copy())   # copy() is one C call: no append can interleave
+
+
+def clear_spans() -> None:
+    _ring.clear()
+
+
+def record_span(name: str, start_ns: int, end_ns: int, sink: bool = True,
+                **attrs) -> None:
+    """A span learned of only when it was over (a ``jax.monitoring``
+    duration): the ring and, with ``sink``, JSONL; no profiler annotation.
+    Its parent is the span open on this thread now."""
+    stack = getattr(_open, "stack", None)
+    _ring.append(SpanRecord(name, threading.current_thread().name, start_ns,
+                            end_ns, 0, next(_ids),
+                            stack[-1].id if stack else 0, attrs))
+    if sink:
+        _tracer._emit("span", name, dur_s=round((end_ns - start_ns) / 1e9, 6),
+                      **attrs)
 
 
 class _Span:
-    """Times a with-block and writes one ``span`` record at exit. Extra
-    attributes can be attached mid-flight via ``set(**attrs)``."""
+    """Times a with-block; at exit appends one :class:`SpanRecord` to the
+    ring and, with a sink, writes one ``span`` record. Extra attributes can
+    be attached mid-flight via ``set(**attrs)``; ``start_ns``/``end_ns`` stay
+    readable after the block."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "id", "parent",
+                 "start_ns", "end_ns", "_cpu_ns", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -131,14 +181,34 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
+        try:
+            stack = _open.stack
+        except AttributeError:
+            stack = _open.stack = []
+        self.id = next(_ids)
+        self.parent = stack[-1].id if stack else 0
+        stack.append(self)
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self._cpu_ns = time.thread_time_ns()
+        self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        dur = time.perf_counter() - self._t0
+        self.end_ns = time.perf_counter_ns()
+        cpu_ns = time.thread_time_ns() - self._cpu_ns
+        self._annotation.__exit__(exc_type, exc, tb)
+        _open.stack.pop()
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
-        self._tracer._emit("span", self.name, dur_s=round(dur, 6), **self.attrs)
+        _ring.append(SpanRecord(self.name, threading.current_thread().name,
+                                self.start_ns, self.end_ns, cpu_ns, self.id,
+                                self.parent, self.attrs))
+        if self._tracer.writer is not None:
+            self._tracer._emit(
+                "span", self.name,
+                dur_s=round((self.end_ns - self.start_ns) / 1e9, 6),
+                **self.attrs)
         return False
 
 
@@ -165,10 +235,9 @@ class Tracer:
         rec.update(attrs)
         w.write(rec)
 
-    def span(self, name: str, **attrs):
-        """Context manager timing a block; no-op when no sink is live."""
-        if self.writer is None:
-            return _NULL_SPAN
+    def span(self, name: str, **attrs) -> _Span:
+        """Context manager timing a block into the profiler's trace and the
+        ring, and into the sink when one is live."""
         return _Span(self, name, attrs)
 
     def event(self, name: str, **attrs) -> None:
@@ -208,9 +277,10 @@ def configure(log_dir: Optional[str] = None, enable: bool = True,
               filename: Optional[str] = None) -> Tracer:
     """(Re)bind the global tracer.
 
-    ``enable=False`` or ``log_dir=None`` installs a sinkless tracer: spans and
-    events become no-ops and NO file is created (the ``obs.enable: false``
-    kill switch); ``log()`` keeps printing either way. Default sink layout:
+    ``enable=False`` or ``log_dir=None`` installs a sinkless tracer: events
+    become no-ops, spans keep to the ring and the profiler, and NO file is
+    created (the ``obs.enable: false`` kill switch); ``log()`` keeps printing
+    either way. Default sink layout:
     process 0 writes ``<log_dir>/events.jsonl``; with ``per_host`` every
     process writes ``<log_dir>/events_p<i>.jsonl``. Every record is tagged
     ``proc``/``host`` (plus any extra ``tags``) so multi-host streams merge
@@ -261,7 +331,7 @@ def configure_from_config(config, exp_dir: str, enabled_here: bool = True,
     if enable and bool(get("jax_probe", True)):
         from distegnn_tpu.obs.jaxprobe import install_compile_watcher
 
-        install_compile_watcher(tracer)
+        install_compile_watcher()
     return tracer
 
 
@@ -270,6 +340,17 @@ def configure_from_config(config, exp_dir: str, enabled_here: bool = True,
 
 def span(name: str, **attrs):
     return _tracer.span(name, **attrs)
+
+
+def spanned(name: str):
+    """Decorator form of :func:`span`: the whole call is the span."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with _tracer.span(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return decorate
 
 
 def event(name: str, **attrs) -> None:

@@ -612,7 +612,12 @@ class EdgeOps:
     ``slot``/``inv_deg``/``oh`` come from :func:`blocked_slot_inv_deg`
     (hoisted once per forward; plain arrays, so layers stay remat-able).
     ``oh is not None`` selects the einsum lowering, otherwise the Pallas
-    kernels."""
+    kernels.
+
+    The methods carry the device scopes ``edge_gather`` and ``edge_aggregate``
+    (``jax.named_scope``), above the choice of lowering: whichever branch
+    runs, forward and transposed, its ops name the scope in the HLO's
+    ``op_name`` (docs/OBSERVABILITY.md "Device scopes")."""
 
     def __init__(self, g, slot=None, inv_deg=None, oh=None,
                  seg_impl: str = "scatter"):
@@ -628,6 +633,7 @@ class EdgeOps:
         self.ell = (seg_impl == "ell" and not self.blocked
                     and g.edges_sorted and g.max_in_degree > 0)
 
+    @jax.named_scope("edge_gather")
     def gather_rows(self, data):
         if self.blocked:
             if self.oh is not None:
@@ -646,6 +652,7 @@ class EdgeOps:
             return jax.vmap(lambda h, r: gather_rows_ell(h, r, D))(data, self.g.row)
         return jnp.take_along_axis(data, self.g.row[..., None], axis=1)
 
+    @jax.named_scope("edge_gather")
     def gather_cols(self, data):
         g = self.g
         if self.blocked and g.edge_pair is not None:
@@ -667,6 +674,7 @@ class EdgeOps:
                 h, c, p, r, m, D))(data, g.col, g.edge_pair, g.row, g.edge_mask)
         return jnp.take_along_axis(data, g.col[..., None], axis=1)
 
+    @jax.named_scope("edge_aggregate")
     def _agg(self, data, mean: bool):
         from distegnn_tpu.ops.segment import (segment_mean, segment_mean_cs,
                                               segment_sum, segment_sum_cs)
@@ -706,6 +714,7 @@ class EdgeOps:
     def agg_rows_sum(self, data):
         return self._agg(data, mean=False)
 
+    @jax.named_scope("edge_aggregate")
     def agg_rows_pair(self, a, b, a_mean: bool, agg_dtype=None):
         """Aggregate TWO edge streams in ONE pass: returns
         (agg_sum_or_mean(a), agg_mean(b)), both float32.

@@ -30,6 +30,7 @@ from distegnn_tpu.ops.graph import GraphBatch
 from distegnn_tpu.parallel.collectives import _psum
 
 
+@jax.named_scope("loss_mse")
 def masked_mse(pred: jnp.ndarray, target: jnp.ndarray, node_mask: jnp.ndarray) -> jnp.ndarray:
     """MSE over real nodes of the whole batch — nn.MSELoss on the flat node
     axis (mean over nodes*3), restricted to mask==1 rows."""
@@ -50,6 +51,7 @@ def rbf_kernel_sum(x: jnp.ndarray, y: jnp.ndarray, sigma: float,
     return jnp.sum(k)
 
 
+@jax.named_scope("loss_mmd")
 def mmd_loss(
     virtual_loc: jnp.ndarray,   # [B, 3, C]
     target: jnp.ndarray,        # [B, N, 3]

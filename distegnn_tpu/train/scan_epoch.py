@@ -2,8 +2,8 @@
 
 The reference's epoch loop dispatches one CUDA launch sequence per minibatch
 (utils/train.py:83-117), and the host-driven loop here does the same: one
-dispatch per micro-batch, which for small graphs (an n-body micro-batch is
-~1 ms of compute) leaves the device waiting on the host. The scanned epoch
+dispatch per micro-batch, which for small graphs leaves the device waiting
+on the host. The scanned epoch
 keeps the whole (uniformly padded) dataset in HBM as one stacked GraphBatch,
 runs the epoch as a ``lax.scan`` over minibatch index slices, and the host
 sees exactly one dispatch + one scalar fetch per epoch. How much that saves
@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distegnn_tpu import obs
 from distegnn_tpu.data.loader import GraphLoader, ShardedGraphLoader
 from distegnn_tpu.ops.graph import GraphBatch, pad_graphs
 from distegnn_tpu.parallel.mesh import DATA_AXIS, GRAPH_AXIS
@@ -143,10 +144,19 @@ class ScanEpochRunner:
         return jnp.asarray(order.reshape(steps, bsz).astype(np.int32))
 
     def train_epoch(self, state, epoch: int):
-        perm = self._perm(self.loader, epoch, self.num_steps, self.batch_size)
-        epoch_key = jax.random.fold_in(jax.random.PRNGKey(self.seed), epoch)
-        state, loss = self._run_train(state, self.data_train, perm, epoch_key)
-        return state, loss  # loss: device scalar; trainer fetches once
+        # spans as in run_epoch_train, with the epoch as the one "step": a
+        # host annotation cannot go inside lax.scan. The sync makes the
+        # train/epoch span end with the device's work, not with the enqueue
+        with obs.span("train/epoch", epoch=epoch):
+            with obs.span("train/step"):
+                perm = self._perm(self.loader, epoch, self.num_steps, self.batch_size)
+                epoch_key = jax.random.fold_in(jax.random.PRNGKey(self.seed), epoch)
+                with obs.span("train/dispatch"):
+                    state, loss = self._run_train(state, self.data_train, perm,
+                                                  epoch_key)
+            with obs.span("train/epoch_sync"):
+                jax.block_until_ready(loss)
+        return state, loss  # loss: device scalar, ready
 
     def eval_epoch(self, params, split: str) -> float:
         data, steps, bsz = self.eval_sets[split]
@@ -351,17 +361,22 @@ class DistributedScanRunner:
         return jnp.asarray(o.reshape(steps, draw))
 
     def train_epoch(self, state, epoch: int):
-        self.loader.set_epoch(epoch)
-        # all partition loaders share (seed, epoch) -> one common order
-        perm = self._perm_array(self.loader.loaders[0]._order(),
-                                self.num_steps, self.draw)
-        epoch_key = jax.random.fold_in(jax.random.PRNGKey(self.seed), epoch)
-        state, loss, cons = self._run_train(state, self.data_train, perm,
-                                            epoch_key)
         from distegnn_tpu.train.trainer import assert_batch_consistency
 
-        assert_batch_consistency(cons, epoch)
-        return state, loss  # loss: device scalar; trainer fetches once
+        # the same spans as ScanEpochRunner.train_epoch
+        with obs.span("train/epoch", epoch=epoch):
+            with obs.span("train/step"):
+                self.loader.set_epoch(epoch)
+                # all partition loaders share (seed, epoch) -> one common order
+                perm = self._perm_array(self.loader.loaders[0]._order(),
+                                        self.num_steps, self.draw)
+                epoch_key = jax.random.fold_in(jax.random.PRNGKey(self.seed), epoch)
+                with obs.span("train/dispatch"):
+                    state, loss, cons = self._run_train(state, self.data_train,
+                                                        perm, epoch_key)
+            with obs.span("train/epoch_sync"):
+                assert_batch_consistency(cons, epoch)
+        return state, loss  # loss: device scalar, ready
 
     def eval_epoch(self, params, split: str) -> float:
         data, steps, draw = self.eval_sets[split]

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import optax
 from flax import struct
 
+from distegnn_tpu import obs
 from distegnn_tpu.ops.graph import GraphBatch
 from distegnn_tpu.parallel.collectives import _psum
 from distegnn_tpu.train.loss import (
@@ -143,6 +144,7 @@ def make_loss_fn(model, mmd_weight: float, mmd_sigma: float, mmd_samples: int,
     return loss_fn
 
 
+@obs.spanned("train/make_step")
 def make_train_step(model, tx: optax.GradientTransformation, mmd_weight: float,
                     mmd_sigma: float, mmd_samples: int,
                     axis_name: Optional[str] = None,
@@ -160,9 +162,11 @@ def make_train_step(model, tx: optax.GradientTransformation, mmd_weight: float,
             # routed through the model's virtual-node psums); summing yields
             # the exact global gradient, identically on every device — weights
             # stay replicated.
-            grads = jax.lax.psum(grads, axes)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("grad_reduce"):
+                grads = jax.lax.psum(grads, axes)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         new_state = TrainState(params=params, opt_state=opt_state, step=state.step + 1)
         metrics = {"loss": logged, "loss_with_mmd": _psum(loss, axes)}
         if axis_name is not None:

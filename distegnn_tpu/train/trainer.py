@@ -24,6 +24,7 @@ Resilience layer (docs/ROBUSTNESS.md):
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import signal
@@ -200,60 +201,87 @@ def run_epoch_train(train_step: Callable, state, loader, seed: int, epoch: int,
     the resumed span only. ``guard``/``cadence`` hook preemption checks and
     wall-clock checkpointing between steps (docs/ROBUSTNESS.md).
 
-    ``tracer``/``step_events``: emit one ``train/step`` event per step with
-    the host-observed dispatch time and the loader-stall delta since the
-    previous step (the loaders add their collation/put time to the global
-    ``data/stall_s`` counter; reading the delta here attributes it per step
-    without a second clock in the loader's hot path)."""
+    Spans (docs/OBSERVABILITY.md): ``train/epoch`` > ``data/next`` (the wait
+    for the batch), ``train/step`` (batch in hand to ready for the next) >
+    ``train/dispatch`` (the ``train_step`` call alone), then
+    ``train/epoch_sync`` (the one fetch).
+
+    ``tracer``/``step_events``: with a sink, each ``train/step`` span carries
+    ``epoch``, ``step``, ``dispatch_s`` (the host's time in the dispatch call:
+    the enqueue, NOT the step's time on the device — the profiler's trace
+    has that) and ``stall_s``, the loader-stall delta since the previous step
+    (the loaders add their collation/put time to the global ``data/stall_s``
+    counter; reading the delta here attributes it per step without a second
+    clock in the loader's hot path)."""
     loader.set_epoch(epoch)
     try:
         steps_total = len(loader)
     except TypeError:
         steps_total = None
-    reg = obs.get_registry()
-    stall_c = reg.counter("data/stall_s")
-    step_res = reg.reservoir("train/step_ms")
+    stall_c = obs.get_registry().counter("data/stall_s")
     emit = step_events and tracer is not None and tracer.enabled
     stall_prev = stall_c.value
     total, counter, cons = None, 0.0, None
-    for step_idx, batch in enumerate(loader):
-        if step_idx < start_step:
-            stall_prev = stall_c.value
-            continue  # applied before the checkpoint this run resumed from
-        key = jax.random.PRNGKey(seed)
-        key = jax.random.fold_in(jax.random.fold_in(key, epoch), step_idx)
-        t_step = time.perf_counter()
-        state, metrics = train_step(state, batch, key)
-        dt_step = time.perf_counter() - t_step
-        step_res.record(1e3 * dt_step)
-        if emit:
-            stall_now = stall_c.value
-            tracer.event("train/step", epoch=epoch, step=step_idx,
-                         dur_s=round(dt_step, 6),
-                         stall_s=round(stall_now - stall_prev, 6))
-            stall_prev = stall_now
-        bsz = batch.loc.shape[-3] if batch.loc.ndim == 4 else batch.loc.shape[0]
-        contrib = metrics["loss"] * bsz
-        total = contrib if total is None else total + contrib
-        counter += bsz
-        if "batch_consistency" in metrics:  # device-side max, no extra sync
-            c = metrics["batch_consistency"]
-            cons = c if cons is None else jnp.maximum(cons, c)
-        if cadence is not None:
-            if steps_total is not None and step_idx + 1 == steps_total:
-                # the save lands ON the epoch boundary: record it as
-                # (epoch, 0), not (epoch-1, full) — a resume then starts the
-                # NEXT epoch instead of skip-replaying an empty remainder
-                cadence.maybe_save(state, epoch, 0)
-            else:
-                cadence.maybe_save(state, epoch - 1, step_idx + 1)
-        if guard is not None and guard.stop_agreed():
-            guard.interrupted = True
-            guard.steps_done = step_idx + 1
-            break
-    avg = float(total) / max(counter, 1.0) if total is not None else 0.0
-    assert_batch_consistency(cons, epoch)
+    with obs.span("train/epoch", epoch=epoch):
+        for step_idx, batch in _batches(loader):
+            if step_idx < start_step:
+                stall_prev = stall_c.value
+                continue  # applied before the checkpoint this run resumed from
+            with obs.span("train/step") as step_span:
+                key = jax.random.PRNGKey(seed)
+                key = jax.random.fold_in(jax.random.fold_in(key, epoch), step_idx)
+                with obs.span("train/dispatch") as dispatch:
+                    state, metrics = train_step(state, batch, key)
+                if emit:
+                    stall_now = stall_c.value
+                    step_span.set(
+                        epoch=epoch, step=step_idx,
+                        dispatch_s=round((dispatch.end_ns - dispatch.start_ns) / 1e9, 6),
+                        stall_s=round(stall_now - stall_prev, 6))
+                    stall_prev = stall_now
+                bsz = batch.loc.shape[-3] if batch.loc.ndim == 4 else batch.loc.shape[0]
+                contrib = metrics["loss"] * bsz
+                total = contrib if total is None else total + contrib
+                counter += bsz
+                if "batch_consistency" in metrics:  # device-side max, no extra sync
+                    c = metrics["batch_consistency"]
+                    cons = c if cons is None else jnp.maximum(cons, c)
+                if cadence is not None:
+                    if steps_total is not None and step_idx + 1 == steps_total:
+                        # the save lands ON the epoch boundary: record it as
+                        # (epoch, 0), not (epoch-1, full) — a resume then starts the
+                        # NEXT epoch instead of skip-replaying an empty remainder
+                        cadence.maybe_save(state, epoch, 0)
+                    else:
+                        cadence.maybe_save(state, epoch - 1, step_idx + 1)
+                stop = guard is not None and guard.stop_agreed()
+            if stop:
+                guard.interrupted = True
+                guard.steps_done = step_idx + 1
+                break
+        with obs.span("train/epoch_sync"):
+            avg = float(total) / max(counter, 1.0) if total is not None else 0.0
+            assert_batch_consistency(cons, epoch)
     return state, avg
+
+
+def _batches(loader):
+    """(step_idx, batch) of one pass over ``loader``, each wait for a batch
+    under a ``data/next`` span (closed before the batch is handed out: a span
+    must not stay open across a ``yield``). Leaving the pass early closes the
+    loader's iterator, which stops and joins a prefetch thread."""
+    it = iter(loader)
+    try:
+        for step_idx in itertools.count():
+            with obs.span("data/next"):
+                batch = next(it, None)
+            if batch is None:
+                return
+            yield step_idx, batch
+    finally:
+        close = getattr(it, "close", None)
+        if close is not None:
+            close()
 
 
 def assert_batch_consistency(cons, epoch: int) -> None:
@@ -468,7 +496,7 @@ def train(
             # so dt_epoch covers the full device time of the epoch
             log_dict["epoch_time"].append(round(dt_epoch, 4))
             tracer.event(
-                "train/epoch", epoch=epoch, dur_s=round(dt_epoch, 4),
+                "train/epoch_end", epoch=epoch, dur_s=round(dt_epoch, 4),
                 stall_s=round(stall_c.value - stall_e0, 4),
                 loss_train=(loss_train if np.isfinite(loss_train)
                             else repr(loss_train)))

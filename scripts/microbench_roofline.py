@@ -12,8 +12,9 @@ kernels? Not measured on this machine yet. The probe:
   3. gather / sorted-scatter at bench shape: achievable for OUR access
      patterns, as a fraction of the copy ceiling.
   4. an analytic byte count of the plain+fuse_agg train step (fwd+bwd
-     [E,.] streams) -> step-time floor at the measured copy bandwidth,
-     printed next to the measured step time (profile_step.py).
+     [E,.] streams) -> step-time floor at the measured copy bandwidth, to
+     set beside the measured step time (``step_device_ms`` of
+     ``benchmarks/run.py --trace 1``).
 
 Artifact: --json <path>. Run it on the chip; CPU runs are labeled and land
 nowhere.

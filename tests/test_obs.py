@@ -195,18 +195,98 @@ def test_log_is_stdout_compatible_and_mirrored(tmp_path, capsys, clean_obs):
 
 
 def test_disabled_tracer_emits_nothing(tmp_path, capsys, clean_obs):
-    """The obs.enable:false kill switch: no files, span/event no-ops, log
-    still prints."""
+    """The obs.enable:false kill switch: no files, no events, no watcher;
+    log still prints, and spans still reach the ring (they do not depend on
+    the sink)."""
     cfg = ConfigDict({**_DEFAULTS, "obs": {**_DEFAULTS["obs"], "enable": False}})
     t = trace.configure_from_config(cfg, str(tmp_path / "exp"))
     assert not t.enabled
-    with t.span("x"):
+    trace.clear_spans()
+    with t.span("x", n=1):
         t.event("y")
     t.log("still prints")
     t.flush()
     assert not (tmp_path / "exp").exists()   # not even the directory
     assert capsys.readouterr().out == "still prints\n"
     assert jaxprobe.get_compile_watcher() is None  # probe not installed
+    (rec,) = trace.recent_spans()
+    assert rec.name == "x" and rec.attrs == {"n": 1} and rec.end_ns >= rec.start_ns
+
+
+def test_spans_without_a_sink_reach_the_ring(tmp_path, monkeypatch, clean_obs):
+    """No configure() at all (the benchmark's drivers): spans are recorded,
+    nothing is written anywhere."""
+    monkeypatch.chdir(tmp_path)
+    trace.configure(log_dir=None)
+    trace.clear_spans()
+    with trace.span("outer", n=3) as outer:
+        with trace.span("inner"):
+            pass
+    inner, rec = trace.recent_spans()
+    assert (inner.name, rec.name) == ("inner", "outer")
+    assert inner.parent == rec.id == outer.id and rec.parent == 0
+    assert rec.attrs == {"n": 3} and inner.attrs == {}
+    assert rec.start_ns <= inner.start_ns <= inner.end_ns <= rec.end_ns
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_ring_is_bounded_and_ordered_across_threads():
+    """The ring keeps the newest RING_SIZE records, each thread's in the
+    order they ended; parent ids never cross threads."""
+    import threading
+
+    trace.clear_spans()
+    n = trace.RING_SIZE // 2 + 100
+    go = threading.Barrier(2)
+
+    def work(tag):
+        go.wait(timeout=10)
+        for i in range(n):
+            with trace.span("outer/" + tag, i=i):
+                with trace.span("inner/" + tag):
+                    pass
+
+    threads = [threading.Thread(target=work, args=(t,), name="ring-" + t) for t in "ab"]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+        assert not t.is_alive()
+    spans = trace.recent_spans()
+    assert len(spans) == trace.RING_SIZE          # 4n records were offered
+    by_id = {s.id: s for s in spans}
+    assert len(by_id) == len(spans)
+    for tag in "ab":
+        mine = [s for s in spans if s.thread == "ring-" + tag]
+        assert all(s.name.endswith(tag) for s in mine)
+        assert [s.end_ns for s in mine] == sorted(s.end_ns for s in mine)
+        for s in mine:
+            if s.name.startswith("inner"):
+                outer = by_id.get(s.parent)       # may have ended after the cut
+                assert outer is None or (outer.thread == s.thread
+                                         and outer.name == "outer/" + tag)
+            else:
+                assert s.parent == 0
+    assert trace.recent_spans()[-1].attrs == {"i": n - 1}   # the newest survived
+    trace.clear_spans()
+
+
+def test_spanned_decorator_and_error_attr():
+    trace.clear_spans()
+
+    @trace.spanned("deco/f")
+    def f(x):
+        """doc"""
+        if x:
+            raise KeyError(x)
+        return 5
+
+    assert f(0) == 5 and f.__doc__ == "doc"
+    with pytest.raises(KeyError):
+        f(1)
+    ok, bad = trace.recent_spans()
+    assert ok.name == bad.name == "deco/f"
+    assert "error" not in ok.attrs and bad.attrs["error"] == "KeyError"
 
 
 def test_configure_from_config_defaults_on(tmp_path, clean_obs):
@@ -254,7 +334,7 @@ def test_compile_watcher_detects_forced_recompile(tmp_path, clean_obs):
 
     t = trace.configure(log_dir=str(tmp_path))
     reg = MetricsRegistry()
-    w = jaxprobe.install_compile_watcher(t, reg)
+    w = jaxprobe.install_compile_watcher(reg)
     w.set_phase("warmup")
 
     f = jax.jit(lambda x: x * 2 + 1)
@@ -280,6 +360,59 @@ def test_compile_watcher_detects_forced_recompile(tmp_path, clean_obs):
                if c["phase"] == "warmup")
 
 
+def test_compile_spans_carry_fun_name_and_count_a_second_compile():
+    """Every backend compile is a jax/compile span in the ring, watcher or
+    not; a forced second compile of one function reads 2 under its name."""
+    import jax
+    import jax.numpy as jnp
+
+    assert jaxprobe.get_compile_watcher() is None
+    trace.clear_spans()
+
+    def twice_compiled(x):
+        return x * 3 - 1
+
+    f = jax.jit(twice_compiled)
+    with trace.span("caller") as caller:
+        f(jnp.ones((5,), jnp.float32)).block_until_ready()
+    f(jnp.ones((5,), jnp.float32)).block_until_ready()      # cached: no span
+    f(jnp.ones((6,), jnp.float32)).block_until_ready()      # second executable
+    mine = [s for s in trace.recent_spans() if s.name == "jax/compile"
+            and "twice_compiled" in str(s.attrs.get("fun_name"))]
+    assert len(mine) == 2
+    assert all(s.end_ns > s.start_ns and "phase" not in s.attrs for s in mine)
+    assert mine[0].parent == caller.id and mine[1].parent == 0
+    assert all(set(s.attrs) == {"fun_name"} for s in mine)
+
+
+def test_compile_spans_reach_the_jsonl_only_with_a_watcher(tmp_path, clean_obs):
+    """obs.jax_probe: false keeps the file free of jax/compile records; the
+    ring has them either way."""
+    import jax
+    import jax.numpy as jnp
+
+    t = trace.configure(log_dir=str(tmp_path))
+    assert jaxprobe.get_compile_watcher() is None
+    trace.clear_spans()
+
+    def quiet_then_watched(x):
+        return x * 5 + 2
+
+    f = jax.jit(quiet_then_watched)
+    f(jnp.ones((3,), jnp.float32)).block_until_ready()
+    jaxprobe.install_compile_watcher(MetricsRegistry())
+    f(jnp.ones((4,), jnp.float32)).block_until_ready()
+    t.flush()
+    ring = [s for s in trace.recent_spans() if s.name == "jax/compile"
+            and "quiet_then_watched" in str(s.attrs.get("fun_name"))]
+    assert len(ring) == 2
+    written = [e for e in read_events(str(tmp_path / "events.jsonl"))
+               if e["name"] == "jax/compile"
+               and "quiet_then_watched" in str(e.get("fun_name"))]
+    assert len(written) == 1 and written[0]["kind"] == "span"
+    assert written[0]["phase"] == "warmup" and written[0]["dur_s"] > 0
+
+
 def test_transfer_meter_and_memory_stats():
     import numpy as np
     reg = MetricsRegistry()
@@ -303,8 +436,10 @@ def _sample_events():
     for i in range(10):
         evs.append(_ev("train/step", epoch=0, step=i,
                        dur_s=0.010 + 0.001 * i, stall_s=0.002))
-    evs.append(_ev("train/epoch", epoch=0, dur_s=0.5, stall_s=0.02,
+    evs.append(_ev("train/epoch_end", epoch=0, dur_s=0.5, stall_s=0.02,
                    loss_train=1.25))
+    evs.append(_ev("train/epoch", kind="span", epoch=0, dur_s=0.49))
+    evs.append(_ev("data/reorder", kind="span", graphs=8, dur_s=0.25))
     evs.append(_ev("ckpt/save", path="e0.ckpt", epoch=0, bytes=1000,
                    dur_s=0.01))
     evs.append(_ev("serve/batch", n=64, e=256, filled=3, capacity=4,
@@ -327,9 +462,13 @@ def test_summarize_and_render():
                                 "save_s": 0.01, "restores": 0}
     assert s["serve"]["batches"] == 1
     assert s["faults"] == []
+    assert s["epochs"]["count"] == 1 and s["epochs"]["last_loss_train"] == 1.25
+    assert s["spans"] == {"data/reorder": {"count": 1, "total_s": 0.25},
+                          "train/epoch": {"count": 1, "total_s": 0.49}}
 
     text = report.render_text(s, source="x.jsonl")
     assert "steps: 10" in text and "AFTER WARMUP" in text
+    assert "data/reorder" in text.split("spans:")[1]
     assert "fault timeline: clean" in text
     assert report.check(s) == []
 
@@ -337,7 +476,7 @@ def test_summarize_and_render():
 def test_summarize_stall_falls_back_to_epochs():
     """scan-epoch runs emit no per-step events — stall comes from the
     per-epoch aggregates."""
-    evs = [_ev("train/epoch", epoch=0, dur_s=2.0, stall_s=0.5)]
+    evs = [_ev("train/epoch_end", epoch=0, dur_s=2.0, stall_s=0.5)]
     s = report.summarize(evs)
     assert s["stall"]["stall_s"] == 0.5
     assert s["stall"]["fraction"] == pytest.approx(0.25)

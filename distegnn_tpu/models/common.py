@@ -205,23 +205,9 @@ class CoordMLP(nn.Module):
         return x
 
 
-def _hoisted_linear(w, b, h, scalars, ops, hidden, scalars_first, dtype):
-    """The shared hoisted-linear core: a fused concat-Dense over
-    (h_row, h_col, scalars) — in either concat order — evaluated with the
-    matmul on the node axis (gathering commutes with linear maps)."""
-    if dtype is not None:
-        h, scalars, w, b = (a.astype(dtype) for a in (h, scalars, w, b))
-    H = hidden
-    S = w.shape[0] - 2 * H
-    if scalars_first:
-        ws, wr, wc = w[:S], w[S:S + H], w[S + H:]
-    else:
-        wr, wc, ws = w[:H], w[H:2 * H], w[2 * H:]
-    return ops.gather_rows(h @ wr) + ops.gather_cols(h @ wc) + scalars @ ws + b
-
-
 class HoistedEdgeMLP(nn.Module):
-    """phi_e with its first Dense algebraically hoisted to the node axis.
+    """phi_e with its first Dense algebraically hoisted to the node axis, and
+    the edge geometry riding the same gathers.
 
     The edge-message MLP's first layer is linear, and gathering commutes with
     a linear map, so
@@ -229,16 +215,27 @@ class HoistedEdgeMLP(nn.Module):
         concat([h_row, h_col, s]) @ W
             == gather_row(h @ W[:H]) + gather_col(h @ W[H:2H]) + s @ W[2H:]
 
-    which (a) never materializes the [E, 2H+S] concat, (b) runs the big
+    which (a) never materializes the [E, 2H+S] concat and (b) runs the big
     matmul over N rows instead of E (E/N = mean degree, ~15 at LargeFluid
-    scale), and (c) gathers compute-dtype (bf16) products instead of f32
-    features — all exactly the same math as MLP([H, H], act_last=True) on
-    the concat, in a cheaper order.
+    scale) — exactly the same math as MLP([H, H], act_last=True) on the
+    concat, in a cheaper order.
     Parameters: one fused (2H+S, H) kernel + bias with torch nn.Linear
     defaults at the FULL fan-in, so init parity matches the fused Dense.
 
-    ``ops`` is the EdgeOps dispatch — the gathers ride the blocked one-hot
-    fast path when the batch carries it.
+    The layer's other use of the same two index sets is ``coord_diff =
+    x[row] - x[col]``, whose ``radial`` is phi_e's first scalar. A gather on
+    the chip costs per row, not per byte, so the products and the coordinates
+    share ONE gather per edge end (``EdgeOps.gather_sum_diff`` over
+    ``[h @ wr | x]`` and ``[h @ wc | -x]``) and, from autodiff, one
+    scatter-add per edge end in the backward. The pack is float32 because
+    ``x`` is geometry and must not be rounded: bf16 products widen exactly,
+    the pre-activation sum is rounded to the compute dtype once, and the
+    products' cotangents accumulate in f32 where a bf16 scatter accumulated
+    in bf16. The param tree is what it was before the pack.
+
+    ``ops`` is the EdgeOps dispatch (any lowering; a blocked batch keeps
+    separate gathers). Returns ``(edge_feat, coord_diff, radial)``:
+    ``coord_diff`` un-normalized, ``radial`` its squared length [B, E, 1].
     """
 
     hidden_nf: int
@@ -247,31 +244,32 @@ class HoistedEdgeMLP(nn.Module):
     dtype: Optional[Any] = None
     # tensor-parallel hidden dim: only the two hoisted NODE-axis matmuls
     # (h @ wr, h @ wc — the dominant cost) are column-sliced; ONE node-level
-    # all-gather per product restores the full hidden dim before the cheap
-    # per-edge work, so everything per-edge (and the second Dense) stays
-    # replicated. Column slicing + tiled gather is bitwise-exact.
+    # all-gather per product restores the full hidden dim before the pack and
+    # the cheap per-edge work, so everything per-edge (and the second Dense)
+    # stays replicated. Column slicing + tiled gather is bitwise-exact.
     tensor_axis: Optional[str] = None
 
     @nn.compact
-    def __call__(self, h, scalars, ops):
+    def __call__(self, h, x, edge_attr, ops):
         H = self.hidden_nf
         fan_in = 2 * H + self.scalar_nf
         w = self.param("kernel", torch_linear_init, (fan_in, H), jnp.float32)
         b = self.param("bias", _torch_bias_init(fan_in), (H,), jnp.float32)
+        c = (lambda a: a.astype(self.dtype)) if self.dtype is not None else (lambda a: a)
+        h, w, b = c(h), c(w), c(b)
         if self.tensor_axis is not None:
             ax = self.tensor_axis
-            dt = self.dtype
-            hc_, sc_, wc_, bc_ = ((a.astype(dt) for a in (h, scalars, w, b))
-                                  if dt is not None else (h, scalars, w, b))
-            hin = tp_copy(hc_, ax)
-            hr = tp_gather(hin @ tp_slice(wc_[:H], ax), ax)
-            hcv = tp_gather(hin @ tp_slice(wc_[H:2 * H], ax), ax)
-            y = self.act(ops.gather_rows(hr) + ops.gather_cols(hcv)
-                         + sc_ @ wc_[2 * H:] + bc_)
+            hin = tp_copy(h, ax)
+            pr = tp_gather(hin @ tp_slice(w[:H], ax), ax)
+            pc = tp_gather(hin @ tp_slice(w[H:2 * H], ax), ax)
         else:
-            y = self.act(_hoisted_linear(w, b, h, scalars, ops, H,
-                                         scalars_first=False, dtype=self.dtype))
-        return self.act(TorchDense(H, dtype=self.dtype)(y))
+            pr, pc = h @ w[:H], h @ w[H:2 * H]
+        pre, coord_diff = ops.gather_sum_diff(pr, pc, x)
+        radial = jnp.sum(coord_diff**2, axis=-1, keepdims=True)     # [B, E, 1]
+        scalars = (radial if edge_attr is None
+                   else jnp.concatenate([radial, edge_attr], axis=-1))
+        y = self.act(pre + c(scalars) @ w[2 * H:] + b)
+        return self.act(TorchDense(H, dtype=self.dtype)(y)), coord_diff, radial
 
 
 class HoistedGate(nn.Module):
@@ -291,8 +289,10 @@ class HoistedGate(nn.Module):
         fan_in = S + 2 * H
         w = self.param("kernel", torch_linear_init, (fan_in, self.features), jnp.float32)
         b = self.param("bias", _torch_bias_init(fan_in), (self.features,), jnp.float32)
-        return _hoisted_linear(w, b, h, scalars, ops, H,
-                               scalars_first=True, dtype=self.dtype)
+        if self.dtype is not None:
+            h, scalars, w, b = (a.astype(self.dtype) for a in (h, scalars, w, b))
+        ws, wr, wc = w[:S], w[S:S + H], w[S + H:]
+        return ops.gather_rows(h @ wr) + ops.gather_cols(h @ wc) + scalars @ ws + b
 
 
 def resolve_dtype(d):

@@ -362,14 +362,8 @@ class EGCLVel(nn.Module):
             agg = trans_sum / cnt if self.coords_agg == "mean" else trans_sum
             agg_h_f = ef_sum / cnt
         else:
-            # --- real-edge geometry (reference coord2radial, :237-246)
-            coord_diff = ops.gather_rows(x) - ops.gather_cols(x)        # [B, E, 3]
-            radial = jnp.sum(coord_diff**2, axis=-1, keepdims=True)     # [B, E, 1]
-            if self.normalize:
-                norm = jax.lax.stop_gradient(jnp.sqrt(radial)) + self.epsilon
-                coord_diff = coord_diff / norm
-
-            # --- real edge messages phi_e (:144-150)
+            # --- real edge messages phi_e (:144-150) and the real-edge
+            # geometry (reference coord2radial, :237-246)
             if not self.hoist_edge_mlp and self.tensor_axis is not None:
                 raise ValueError(
                     "tensor parallelism requires hoist_edge_mlp=True "
@@ -378,13 +372,15 @@ class EGCLVel(nn.Module):
                     "need a per-edge gather)")
             with jax.named_scope("edge_mlp"):
                 if self.hoist_edge_mlp:
-                    scalars = (jnp.concatenate([radial, g.edge_attr], axis=-1)
-                               if self.edge_attr_nf else radial)
-                    edge_feat = HoistedEdgeMLP(H, 1 + self.edge_attr_nf,
-                                               name="phi_e", dtype=dt,
-                                               tensor_axis=self.tensor_axis)(
-                                                   h, scalars, ops)
+                    # coord_diff [B, E, 3] and radial [B, E, 1] come out of the
+                    # hoisted products' own gathers: one pass per edge end
+                    edge_feat, coord_diff, radial = HoistedEdgeMLP(
+                        H, 1 + self.edge_attr_nf, name="phi_e", dtype=dt,
+                        tensor_axis=self.tensor_axis)(
+                            h, x, g.edge_attr if self.edge_attr_nf else None, ops)
                 else:
+                    coord_diff = ops.gather_rows(x) - ops.gather_cols(x)
+                    radial = jnp.sum(coord_diff**2, axis=-1, keepdims=True)
                     e_in = [ops.gather_rows(h), ops.gather_cols(h), radial]
                     if self.edge_attr_nf:
                         e_in.append(g.edge_attr)
@@ -394,6 +390,9 @@ class EGCLVel(nn.Module):
                     gate_e = jax.nn.sigmoid(TorchDense(1, name="att", dtype=dt)(edge_feat))
                     edge_feat = edge_feat * gate_e                       # [B, E, H]
                 edge_feat = edge_feat * edge_mask[..., None].astype(edge_feat.dtype)
+            if self.normalize:
+                norm = jax.lax.stop_gradient(jnp.sqrt(radial)) + self.epsilon
+                coord_diff = coord_diff / norm
 
         # --- virtual-edge geometry (:252-253): every node sees all C virtual nodes
         with jax.named_scope("virtual_update"):

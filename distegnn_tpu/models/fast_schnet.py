@@ -72,20 +72,21 @@ class SchNetGCLVel(nn.Module):
         # REFERENCE: its coord2radial normalizes coord_diff, which FastSchNet
         # then never consumes (only radial and the SchNet sublayer's raw
         # positions are used, FastSchNet.py:169-186)
-        raw_diff = ops.gather_rows(x) - ops.gather_cols(x)
-        radial = jnp.sum(raw_diff**2, axis=-1, keepdims=True)
         vcd = X[:, None, :, :] - x[..., None]                            # [B, N, 3, C]
         virtual_radial = jnp.linalg.norm(vcd, axis=2, keepdims=True)
 
         # real edge messages phi_e (FastSchNet.py:102-108); hoisted mode never
         # gathers raw h at all — phi_e AND the SchNet gate below move node-side
-        # matmul products instead
-        e_scalars = (jnp.concatenate([radial, g.edge_attr], axis=-1)
-                     if self.edge_attr_nf else radial)
+        # matmul products instead, and raw_diff rides phi_e's gathers
+        edge_attr = g.edge_attr if self.edge_attr_nf else None
         if self.hoist_edge_mlp:
-            edge_feat = HoistedEdgeMLP(H, 1 + self.edge_attr_nf,
-                                       name="phi_e")(h, e_scalars, ops)
+            edge_feat, raw_diff, radial = HoistedEdgeMLP(
+                H, 1 + self.edge_attr_nf, name="phi_e")(h, x, edge_attr, ops)
         else:
+            raw_diff = ops.gather_rows(x) - ops.gather_cols(x)
+            radial = jnp.sum(raw_diff**2, axis=-1, keepdims=True)
+            e_scalars = (radial if edge_attr is None
+                         else jnp.concatenate([radial, edge_attr], axis=-1))
             h_row, h_col = ops.gather_rows(h), ops.gather_cols(h)
             edge_feat = MLP([H, H], act_last=True, name="phi_e")(
                 jnp.concatenate([h_row, h_col, e_scalars], axis=-1))

@@ -44,7 +44,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distegnn_tpu import runtime
+from distegnn_tpu import obs, runtime
 
 DEFAULT_BLOCK = 256       # nodes per block = one-hot matmul N dimension
 DEFAULT_EDGE_TILE = 512   # edges per grid step = one-hot matmul K dimension
@@ -595,6 +595,10 @@ def blocked_slot_inv_deg(g, impl: str = "einsum"):
     return slot, 1.0 / jnp.maximum(deg, 1.0), oh
 
 
+def _count_gather_pass():
+    obs.get_registry().counter("edge/gather_passes").add()
+
+
 class EdgeOps:
     """The one definition of the edge-op dispatch all model families share:
     row/col gathers and per-destination aggregations, lowered as
@@ -614,10 +618,21 @@ class EdgeOps:
     ``oh is not None`` selects the einsum lowering, otherwise the Pallas
     kernels.
 
+    Both directions pack what shares an index set into ONE pass, because a
+    gather or a scatter on the chip costs per ROW, not per byte (PERF.md
+    section 5): :meth:`agg_rows_pair` carries a layer's two aggregations and
+    the count in one segment sum, :meth:`gather_sum_diff` carries the hoisted
+    phi_e products and the coordinates in one gather per edge end, so a layer
+    has 2 gathers and (from autodiff) 2 transposed scatter-adds where separate
+    calls make 4 and 4. The pack is float32: coordinates never pass through
+    bf16, and bf16 products widen exactly.
+
     The methods carry the device scopes ``edge_gather`` and ``edge_aggregate``
     (``jax.named_scope``), above the choice of lowering: whichever branch
     runs, forward and transposed, its ops name the scope in the HLO's
-    ``op_name`` (docs/OBSERVABILITY.md "Device scopes")."""
+    ``op_name`` (docs/OBSERVABILITY.md "Device scopes"). Every gather a
+    method emits adds one to the ``obs`` counter ``edge/gather_passes`` as
+    it is TRACED (a count per compiled program, not per step)."""
 
     def __init__(self, g, slot=None, inv_deg=None, oh=None,
                  seg_impl: str = "scatter"):
@@ -635,6 +650,7 @@ class EdgeOps:
 
     @jax.named_scope("edge_gather")
     def gather_rows(self, data):
+        _count_gather_pass()
         if self.blocked:
             if self.oh is not None:
                 # the einsum ops are leading-dim polymorphic ('...' batch)
@@ -654,6 +670,7 @@ class EdgeOps:
 
     @jax.named_scope("edge_gather")
     def gather_cols(self, data):
+        _count_gather_pass()
         g = self.g
         if self.blocked and g.edge_pair is not None:
             if self.oh is not None:
@@ -673,6 +690,38 @@ class EdgeOps:
             return jax.vmap(lambda h, c, p, r, m: paired_gather_cols_ell(
                 h, c, p, r, m, D))(data, g.col, g.edge_pair, g.row, g.edge_mask)
         return jnp.take_along_axis(data, g.col[..., None], axis=1)
+
+    @jax.named_scope("edge_gather")
+    def gather_sum_diff(self, a, b, x):
+        """``(gather_rows(a) + gather_cols(b), gather_rows(x) - gather_cols(x))``
+        in ONE gather per edge end: the node tables ``[a | x]`` and
+        ``[b | -x]`` ride the row and the col pass together, and autodiff
+        transposes each pass into one scatter-add of the packed width.
+
+        The pack is the widest dtype among the operands (float32 for the
+        model's f32 coordinates): ``x`` is never rounded, bf16 ``a``/``b``
+        widen exactly, their sum is taken in the pack's dtype and rounded
+        back to ``a``'s dtype ONCE, and the cotangents of ``a`` and ``b``
+        accumulate in the pack's dtype. With f32 operands both results equal
+        the separate calls bit for bit (``r + (-c)`` is ``r - c``).
+
+        Blocked layouts keep their four calls: the one-hot kernels run bf16
+        operands single-pass and f32 ones six-pass, so widening the products
+        would cost more than the saved passes."""
+        if self.blocked:
+            return (self.gather_rows(a) + self.gather_cols(b),
+                    self.gather_rows(x) - self.gather_cols(x))
+        dt = jnp.result_type(a, b, x)
+        w, xp = a.shape[-1], x.astype(dt)
+        out = (self.gather_rows(jnp.concatenate([a.astype(dt), xp], -1))
+               + self.gather_cols(jnp.concatenate([b.astype(dt), -xp], -1)))
+        # the barrier makes the 3 coordinate columns a buffer of their own:
+        # without it XLA fuses this slice into its consumers in the BACKWARD
+        # and keeps the whole packed result alive as the residual (the n-body
+        # epoch compiled for a v5e: +1.32 GB of temporaries without, +0.007
+        # with; PERF.md section 6, PR 27)
+        diff = jax.lax.optimization_barrier(out[..., w:].astype(x.dtype))
+        return out[..., :w].astype(a.dtype), diff
 
     @jax.named_scope("edge_aggregate")
     def _agg(self, data, mean: bool):

@@ -200,7 +200,7 @@ def _strip_scopes(monkeypatch):
     from distegnn_tpu.train import step as step_mod
 
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
-    for name in ("gather_rows", "gather_cols", "_agg", "agg_rows_pair"):
+    for name in ("gather_rows", "gather_cols", "gather_sum_diff", "_agg", "agg_rows_pair"):
         monkeypatch.setattr(EdgeOps, name, getattr(EdgeOps, name).__wrapped__)
     for name in ("masked_mse", "mmd_loss"):
         raw = getattr(loss_mod, name).__wrapped__

@@ -18,10 +18,27 @@ is not compared):
 ``change_gap``  the parameters' change over the compared steps, same measure,
                 over the leaves whose reference gradient is not nought to
                 rounding (at least a thousandth of the median leaf's).
-``grad_diff``, ``moment_diff``, ``change_diff``
-                the same three quantities by ||p - r|| / ||r|| over all
-                leaves laid end to end: where the gap of norms cannot tell a
-                planted fault from rounding, the direction can.
+``grad_diff``, ``change_diff``
+                the first and the last of these quantities by
+                ||p - r|| / ||r|| over all leaves laid end to end: where the
+                gap of norms cannot tell a planted fault from rounding, the
+                direction can.
+``moment_diff`` the direction-aware view of Adam's first moment: per leaf
+                ||mu_p - mu_r|| / B, and of these the median leaf's, where
+                B = 0.1 * sum_k 0.9^(n-k) ||g_k|| is what the leaf's moment
+                would be in the reference had its n accumulated, clipped
+                gradients g_k not cancelled (the triangle bound of ``mu``,
+                from ``follow``'s ``update_norms``). Two reasons, both read
+                on the chip (PERF.md section 4). Against ||mu_r|| itself the
+                number fails sound runs: three gradients that nearly cancel
+                leave a moment 4 to 7 times smaller than B on about one seed
+                in twenty, while the program's error stays what it is. And
+                over all leaves laid end to end it is a number of four
+                64-element coordinate heads, which hold three quarters and
+                more of ||mu||^2 and of the difference and swing together
+                (6 to 8 times the usual on about one seed in thirty); the
+                median gives every leaf one vote, and a lower precision or
+                rows left out move every leaf.
 """
 
 from __future__ import annotations
@@ -54,16 +71,45 @@ def leaf_gap(prog: dict, ref: dict, keep=None):
     return gaps[where], where
 
 
-def whole_diff(prog: dict, ref: dict, keep=None) -> float:
-    """||p - r|| / ||r|| over all (kept) leaves laid end to end: the
-    direction-aware companion of the gap of norms. Leaving rows out of the
-    loss moves a gradient's direction far more than its norm."""
+def whole_parts(prog: dict, ref: dict, keep=None) -> tuple:
+    """(||p - r||, ||r||) over all (kept) leaves laid end to end."""
     names = [k for k in ref if keep is None or k in keep]
     d2 = sum(float(np.sum((np.asarray(prog[k], np.float64) - np.asarray(ref[k], np.float64)) ** 2))
              for k in names)
     r2 = sum(float(np.sum(np.asarray(ref[k], np.float64) ** 2)) for k in names)
-    out = float(np.sqrt(d2 / max(r2, 1e-300)))
+    return float(np.sqrt(d2)), float(np.sqrt(r2))
+
+
+def whole_diff(prog: dict, ref: dict, keep=None) -> float:
+    """||p - r|| / ||r|| over all (kept) leaves laid end to end: the
+    direction-aware companion of the gap of norms. Leaving rows out of the
+    loss moves a gradient's direction far more than its norm."""
+    d, r = whole_parts(prog, ref, keep)
+    out = d / max(r, 1e-150)
     return out if np.isfinite(out) else float("inf")
+
+
+def moment_bounds(update_norms: dict) -> dict:
+    """Per leaf, what the norm of Adam's first moment would be after these
+    updates had the leaf's gradients all pointed one way:
+    0.1 * sum_k 0.9^(n-k) ||g_k||. ``update_norms``: per leaf, the norm of
+    each update's gradient as the reference's Adam got it."""
+    out = {}
+    for k, v in update_norms.items():
+        g = np.asarray(v, np.float64)
+        out[k] = float(0.1 * np.sum(0.9 ** np.arange(len(g) - 1, -1, -1) * g))
+    return out
+
+
+def moment_diffs(prog_mu: dict, ref_mu: dict, update_norms: dict) -> dict:
+    """Per leaf ||mu_p - mu_r|| / B with B of ``moment_bounds``; a leaf that
+    never had a gradient reads 0 where the program's moment is 0 too."""
+    out = {}
+    for k, bound in moment_bounds(update_norms).items():
+        d = float(np.linalg.norm(np.asarray(prog_mu[k], np.float64) - np.asarray(ref_mu[k], np.float64)))
+        gap = d / bound if bound > 0 else (0.0 if d == 0 else float("inf"))
+        out[k] = gap if np.isfinite(gap) else float("inf")
+    return out
 
 
 def moving_leaves(ref_grad: dict) -> set:
@@ -88,7 +134,9 @@ def numbers(prog: dict, ref: dict) -> dict:
         out["grad_diff"] = (whole_diff(prog["grad"], ref["grad_first"]), "all leaves")
     if prog.get("mu") is not None:
         out["moment_gap"] = leaf_gap(prog["mu"], ref["mu"])
-        out["moment_diff"] = (whole_diff(prog["mu"], ref["mu"]), "all leaves")
+        diffs = moment_diffs(prog["mu"], ref["mu"], ref["update_norms"])
+        out["moment_diff"] = (float(np.median(list(diffs.values()))),
+                              f"median of {len(diffs)} leaves")
     keep = moving_leaves(ref["grad_first"])
     delta = lambda rec, w0: {k: np.asarray(rec[k], np.float64) - w0[k] for k in rec}
     dp, dr = delta(prog["w"], prog["w0"]), delta(ref["w"], prog["w0"])
@@ -114,4 +162,5 @@ def reference_record(inputs: dict, w0: dict, half: bool = False, mlp_mantissa=No
     from benchmarks.reference import fastegnn
 
     return fastegnn.follow(w0, inputs["model"], inputs["train"], inputs["batches"],
-                           inputs["block"], half=half, mlp_mantissa=mlp_mantissa)
+                           inputs["block"], half=half, mlp_mantissa=mlp_mantissa,
+                           edge_block=inputs.get("edge_block"))

@@ -174,7 +174,8 @@ class Driver:
             batches.append(ref_graphs.stack(graphs))
         return {"batches": batches, "model": self.dims,
                 "train": common.train_spec(self.cfg, self.clip),
-                "block": int(self.mix["reference_block"])}
+                "block": int(self.mix["reference_block"]),
+                "edge_block": self.mix.get("reference_edge_block")}
 
     def shapes(self) -> dict:
         return common.step_shapes(self, self.batch_size)

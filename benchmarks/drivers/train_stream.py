@@ -266,7 +266,8 @@ class Driver:
                                                   second_half=second)], edges=self.padded[1]))
         return {"batches": batches, "model": self.dims,
                 "train": common.train_spec(self.cfg, self.clip),
-                "block": int(self.mix["reference_block"])}
+                "block": int(self.mix["reference_block"]),
+                "edge_block": self.mix.get("reference_edge_block")}
 
     def shapes(self) -> dict:
         return common.step_shapes(self, int(self.cfg.data.batch_size))

@@ -14,6 +14,27 @@ leading axis and processed ``block`` graphs at a time, each layer
 rematerialized, so that 1.64 M edges (one LargeFluid graph) and 2.475 M
 (250 n-body graphs) fit beside nothing else on one chip.
 
+A graph that no chip holds that way is walked in edge blocks (``edge_block``,
+the number of edges of ONE graph worked at a time; the edge axis has to be a
+multiple of it, which the zero-weight padding gives). What is blocked: the
+part of a layer that lives on the edge axis, that is the two row gathers, the
+``[edge_block, 2H+1+D]`` input and ``[edge_block, H]`` hidden and output of
+phi_e, the hidden of phi_x and the three segment sums of a block, all under
+``jax.checkpoint``, so that the backward keeps a block's edge list and
+rebuilds the rest. What is carried across blocks, in float32: the three
+node-sized sums of a layer (messages ``[n, H]``, coordinate updates
+``[n, 3]``, degree ``[n, 1]``); the layer then finishes as without blocks.
+In a block the features and coordinates of an edge's ends come from one
+``[n, H+3]`` table and the three sums are one segment sum of ``H+4`` columns:
+the same numbers column by column, packed because the TPU's compiler lays a
+``[800000, 64]`` array out column-major and a scatter into it then takes 12
+times what one into 68 columns takes (164 against 14 ms for 820,224 rows, my
+chip run, PR 29; at 113,140 rows both take 7 ms).
+Everything on the node axis (the virtual-node terms, which are means over
+real nodes, and the node update) is not blocked. Without ``edge_block`` the
+edge part runs once over all edges: the same equations and the same order of
+operations as with one block, and the path both one-chip cells run.
+
 Departures from the published training script, each because the
 configuration as run states it: the MMD term draws its ``samples * C`` target
 nodes with replacement (the drawn indices are an input here, so both sides
@@ -65,22 +86,61 @@ def _mlp(w, name, x, act_last=False, mantissa=None):
     return _silu(x) if act_last else x
 
 
-def _layer(w, l, normalize, mantissa, h, x, X, Hv, vel, attr, row, col, eattr, ew):
+def _edge_sums(p, normalize, mantissa, pack, w, h, x, row, col, eattr, ew):
+    """The real edges' part of layer ``p`` over one list of edges: the sums
+    at each receiving node of the messages [n,H], of the coordinate updates
+    [n,3] and of the edge weights [n,1]. Edge (row, col) carries a message to
+    ``row`` from ``col``; ``ew`` is 1 for an edge and 0 for list padding.
+    ``pack`` (the blocked path): features and coordinates are gathered from
+    one [n,H+3] table and the three sums are one sum of H+4 columns, the same
+    numbers column by column (module docstring, "Edge blocks")."""
+    n, H = h.shape
+    mlp = functools.partial(_mlp, mantissa=mantissa)
+    if pack:
+        hx = jnp.concatenate([h, x], axis=-1)
+        at_row, at_col = hx[row], hx[col]
+        h_row, h_col, diff = at_row[:, :H], at_col[:, :H], at_row[:, H:] - at_col[:, H:]
+    else:
+        h_row, h_col, diff = h[row], h[col], x[row] - x[col]
+    radial = jnp.sum(diff * diff, axis=-1, keepdims=True)
+    if normalize:
+        diff = diff / (jax.lax.stop_gradient(jnp.sqrt(radial)) + EPS)
+    m = mlp(w, p + "phi_e", jnp.concatenate([h_row, h_col, radial, eattr], -1),
+            act_last=True) * ew[:, None]                         # [e,H]
+    dx = diff * mlp(w, p + "phi_x", m) * ew[:, None]
+    if pack:
+        sums = jax.ops.segment_sum(jnp.concatenate([m, dx, ew[:, None]], axis=-1), row, n)
+        return sums[:, :H], sums[:, H:H + 3], sums[:, H + 3:]
+    return (jax.ops.segment_sum(m, row, n), jax.ops.segment_sum(dx, row, n),
+            jax.ops.segment_sum(ew[:, None], row, n))
+
+
+def _layer(w, l, normalize, mantissa, edge_block, h, x, X, Hv, vel, attr, row, col, eattr, ew):
     """One FastEGNN layer on one graph. h [n,H], x [n,3], X [3,C] virtual
-    coordinates, Hv [H,C] virtual features. Edge (row, col) carries a message
-    to ``row`` from ``col``; ``ew`` is 1 for an edge and 0 for list padding."""
+    coordinates, Hv [H,C] virtual features. ``edge_block``: None, or the
+    number of edges worked at a time (module docstring)."""
     p = f"l{l}."
     n, H = h.shape
     C = X.shape[1]
     mlp = functools.partial(_mlp, mantissa=mantissa)
 
-    # real edges
-    diff = x[row] - x[col]
-    radial = jnp.sum(diff * diff, axis=-1, keepdims=True)
-    if normalize:
-        diff = diff / (jax.lax.stop_gradient(jnp.sqrt(radial)) + EPS)
-    m = mlp(w, p + "phi_e", jnp.concatenate([h[row], h[col], radial, eattr], -1),
-            act_last=True) * ew[:, None]                         # [e,H]
+    sums = functools.partial(_edge_sums, p, normalize, mantissa, edge_block is not None)
+    if edge_block is None:
+        m_sum, dx_sum, deg = sums(w, h, x, row, col, eattr, ew)
+    else:
+        E = row.shape[0]
+        if E % edge_block:
+            raise ValueError(f"{E} edges are not a multiple of edge_block {edge_block}")
+        blocks = jax.tree.map(lambda a: a.reshape((E // edge_block, edge_block) + a.shape[1:]),
+                              (row, col, eattr, ew))
+        one = jax.checkpoint(sums)
+        # the sum is outside the checkpoint: the backward of ``carry + part``
+        # needs neither, so no carry is kept per block
+        zero = (jnp.zeros((n, H), jnp.float32), jnp.zeros((n, 3), jnp.float32),
+                jnp.zeros((n, 1), jnp.float32))
+        (m_sum, dx_sum, deg), _ = jax.lax.scan(
+            lambda acc, blk: (jax.tree.map(jnp.add, acc, one(w, h, x, *blk)), None), zero, blocks)
+    deg = jnp.maximum(deg, 1.0)
 
     # virtual edges: every node sees the C virtual nodes
     vdiff = X[None, :, :] - x[:, :, None]                        # [n,3,C]
@@ -96,8 +156,7 @@ def _layer(w, l, normalize, mantissa, h, x, X, Hv, vel, attr, row, col, eattr, e
     mv = mlp(w, p + "phi_ev", v_in, act_last=True)              # [n,C,H]
 
     # coordinates: mean over incoming edges, mean over virtual nodes, velocity
-    deg = jnp.maximum(jax.ops.segment_sum(ew[:, None], row, n), 1.0)
-    x_new = x + jax.ops.segment_sum(diff * mlp(w, p + "phi_x", m) * ew[:, None], row, n) / deg
+    x_new = x + dx_sum / deg
     x_new = x_new + jnp.mean(-vdiff * mlp(w, p + "phi_xv", mv)[:, None, :, 0], axis=-1)
     x_new = x_new + mlp(w, p + "phi_v", h) * vel
 
@@ -105,7 +164,7 @@ def _layer(w, l, normalize, mantissa, h, x, X, Hv, vel, attr, row, col, eattr, e
     X_new = X + jnp.mean(vdiff * mlp(w, p + "phi_X", mv)[:, None, :, 0], axis=0)
 
     # node features
-    agg = jax.ops.segment_sum(m, row, n) / deg
+    agg = m_sum / deg
     n_in = jnp.concatenate([h, agg, jnp.mean(mv, axis=1), attr], axis=-1)
     h_new = h + mlp(w, p + "phi_h", n_in)
 
@@ -115,7 +174,7 @@ def _layer(w, l, normalize, mantissa, h, x, X, Hv, vel, attr, row, col, eattr, e
     return h_new, x_new, X_new, Hv_new
 
 
-def forward(w, model, g, mantissa=None):
+def forward(w, model, g, mantissa=None, edge_block=None):
     """One graph -> (predicted positions [n,3], virtual coordinates [3,C])."""
     C = model["virtual_channels"]
     h = g["feat"] @ w["embed.w"] + w["embed.b"]
@@ -123,7 +182,8 @@ def forward(w, model, g, mantissa=None):
     X = jnp.repeat(g["loc_mean"][:, None], C, axis=1)
     Hv = w["virtual_feat"]
     for l in range(model["n_layers"]):
-        lay = jax.checkpoint(functools.partial(_layer, w, l, bool(model["normalize"]), mantissa))
+        lay = jax.checkpoint(functools.partial(_layer, w, l, bool(model["normalize"]), mantissa,
+                                               edge_block))
         h, x, X, Hv = lay(h, x, X, Hv, g["vel"], g["attr"], g["row"], g["col"],
                           g["eattr"], g["ew"])
     return x, X
@@ -134,11 +194,11 @@ def _kernel_sum(a, b, sigma):
     return jnp.sum(jnp.exp(-jnp.sqrt(jnp.maximum(d2, 1e-24)) / (2.0 * sigma * sigma)))
 
 
-def _block_terms(w, model, mmd, blk, mantissa):
+def _block_terms(w, model, mmd, blk, mantissa, edge_block):
     """Sums over one block of graphs: squared error over the rows that count
     (``loss_rows``, all ones unless a fault is planted), k(V,V), k(samples,V)."""
     def one(g):
-        pred, X = forward(w, model, g, mantissa)
+        pred, X = forward(w, model, g, mantissa, edge_block)
         sse = jnp.sum((pred - g["target"]) ** 2 * g["loss_rows"][:, None])
         V = X.T
         k_vv = _kernel_sum(V, V, mmd["sigma"])
@@ -149,8 +209,8 @@ def _block_terms(w, model, mmd, blk, mantissa):
     return jnp.sum(sse), jnp.sum(k_vv), jnp.sum(k_rv)
 
 
-@functools.partial(jax.jit, static_argnames=("model_key", "mmd_key", "G", "mantissa"))
-def _block_grad(w, blk, rows, *, model_key, mmd_key, G, mantissa=None):
+@functools.partial(jax.jit, static_argnames=("model_key", "mmd_key", "G", "mantissa", "edge_block"))
+def _block_grad(w, blk, rows, *, model_key, mmd_key, G, mantissa=None, edge_block=None):
     """(mse share, mmd share), gradient of their weighted sum, for one block
     of a batch of ``G`` graphs in which ``rows`` rows count towards the MSE.
     ``mantissa``: the control, see ``_dense``."""
@@ -159,7 +219,7 @@ def _block_grad(w, blk, rows, *, model_key, mmd_key, G, mantissa=None):
     S = mmd["samples"] * C
 
     def loss(w):
-        sse, k_vv, k_rv = _block_terms(w, model, mmd, blk, mantissa)
+        sse, k_vv, k_rv = _block_terms(w, model, mmd, blk, mantissa, edge_block)
         mse = sse / (rows * 3)
         mmd_l = k_vv / G / C / C - 2.0 * k_rv / G / S / C
         return mse + mmd["weight"] * mmd_l, (mse, mmd_l)
@@ -172,9 +232,10 @@ def _hashable(d):
     return tuple(sorted(d.items()))
 
 
-def micro_step(w, model, mmd, batch, block, half=False, mantissa=None):
+def micro_step(w, model, mmd, batch, block, half=False, mantissa=None, edge_block=None):
     """Loss and gradient of one micro-batch (``G`` stacked graphs), summed
-    over blocks of ``block`` graphs. Returns (mse, mse + weight*mmd, grads).
+    over blocks of ``block`` graphs, each graph's edges walked ``edge_block``
+    at a time where that is given. Returns (mse, mse + weight*mmd, grads).
 
     ``half`` plants the fault "half of the batch left out, the mean taken
     over the rest": of several graphs the second half, of one graph the rows
@@ -197,7 +258,8 @@ def micro_step(w, model, mmd, batch, block, half=False, mantissa=None):
         for s in range(0, G, block):
             blk = {k: v[s:s + block] for k, v in batch.items()}
             a, b, g = _block_grad(w, blk, rows, model_key=_hashable(model),
-                                  mmd_key=_hashable(mmd), G=G, mantissa=mantissa)
+                                  mmd_key=_hashable(mmd), G=G, mantissa=mantissa,
+                                  edge_block=edge_block)
             mse, mm = mse + a, mm + b
             grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
     return mse, mse + mmd["weight"] * mm, grads
@@ -206,7 +268,8 @@ def micro_step(w, model, mmd, batch, block, half=False, mantissa=None):
 @functools.partial(jax.jit, static_argnames=("lr", "wd", "clip"))
 def _adam_update(w, g, mu, nu, t, *, lr, wd, clip):
     """torch.optim.Adam with L2 weight decay folded into the gradient, after
-    an optional clip of the global norm; ``t`` counts updates from 1."""
+    an optional clip of the global norm; ``t`` counts updates from 1. Also
+    returns each leaf's norm of the gradient as the moments get it."""
     if clip is not None:
         norm = jnp.sqrt(sum(jnp.sum(x * x) for x in g.values()))
         scale = jnp.where(norm < clip, 1.0, clip / norm)
@@ -216,27 +279,30 @@ def _adam_update(w, g, mu, nu, t, *, lr, wd, clip):
     nu = {k: 0.999 * nu[k] + 0.001 * g[k] * g[k] for k in g}
     c1, c2 = 1.0 - 0.9 ** t, 1.0 - 0.999 ** t
     w = {k: w[k] - lr * (mu[k] / c1) / (jnp.sqrt(nu[k] / c2) + 1e-8) for k in g}
-    return w, mu, nu
+    return w, mu, nu, {k: jnp.sqrt(jnp.sum(x * x)) for k, x in g.items()}
 
 
-def follow(w0, model, train, batches, block, half=False, mlp_mantissa=None):
+def follow(w0, model, train, batches, block, half=False, mlp_mantissa=None, edge_block=None):
     """Follow the first ``len(batches)`` micro-steps of training from ``w0``.
 
     ``train``: learning_rate, weight_decay, clip_norm (or None),
     accumulation_steps, mmd {sigma, weight, samples}. Returns host numpy:
     ``loss`` [steps] (the logged MSE), ``loss_total`` [steps], ``grad_first``
     (the first micro-batch's gradient), ``mu`` (Adam's first moment after the
-    last update), ``w`` (weights after the last micro-step)."""
+    last update), ``w`` (weights after the last micro-step), ``update_norms``
+    (per leaf, [updates]: the norm of each accumulated, clipped gradient as
+    Adam got it; ``mu`` is their decayed sum, so they bound it, and say how
+    large it is when they do not cancel). ``edge_block``: see the module docstring."""
     acc_k = int(train["accumulation_steps"])
     w = dict(w0)
     mu = {k: jnp.zeros_like(v) for k, v in w.items()}
     nu = {k: jnp.zeros_like(v) for k, v in w.items()}
     acc, t = None, 0
-    losses, totals, grad_first = [], [], None
+    losses, totals, norms, grad_first = [], [], [], None
     for i, batch in enumerate(batches):
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
         mse, total, g = micro_step(w, model, train["mmd"], batch, block, half=half,
-                                   mantissa=mlp_mantissa)
+                                   mantissa=mlp_mantissa, edge_block=edge_block)
         losses.append(mse)
         totals.append(total)
         if i == 0:
@@ -246,12 +312,15 @@ def follow(w0, model, train, batches, block, half=False, mlp_mantissa=None):
             t += 1
             mean = {k: v / acc_k for k, v in acc.items()}
             clip = train.get("clip_norm")
-            w, mu, nu = _adam_update(
+            w, mu, nu, norm = _adam_update(
                 w, mean, mu, nu, float(t), lr=float(train["learning_rate"]),
                 wd=float(train["weight_decay"]),
                 clip=None if clip is None else float(clip))
+            norms.append(norm)
             acc = None
     get = lambda tree: {k: np.asarray(v) for k, v in tree.items()}
+    norms = jax.device_get(norms)
     return {"loss": np.asarray(jnp.stack(losses)),
             "loss_total": np.asarray(jnp.stack(totals)),
-            "grad_first": get(grad_first), "mu": get(mu), "w": get(w)}
+            "grad_first": get(grad_first), "mu": get(mu), "w": get(w),
+            "update_norms": {k: np.asarray([n[k] for n in norms], np.float32) for k in w}}

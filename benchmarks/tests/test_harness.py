@@ -109,3 +109,105 @@ def test_nonfinite_loss_counts_as_failed(monkeypatch):
         monkeypatch.setattr(mod, "make_train_step", broken)
     r = _run("toy_nbody_train")
     assert r["failed"] == r["attempted"] and r["correct"] is False
+
+
+# ---- moment_diff against a scale that does not vanish (PR 29)
+
+LEAVES = 5
+
+
+def _moment_numbers(grads_ref, grads_prog):
+    """Made-up records of ``n`` updates over ``LEAVES`` leaves (gradients
+    [n, LEAVES, dim]): Adam's first moment of each side, the reference's
+    ``update_norms``. -> (the measure before PR 29, today's ``moment_diff``)."""
+    import numpy as np
+
+    from benchmarks import compare
+
+    decay = 0.1 * 0.9 ** np.arange(len(grads_ref) - 1, -1, -1)
+    mu = lambda gs: {f"leaf{k}": np.tensordot(decay, gs[:, k], 1) for k in range(LEAVES)}
+    norms = {f"leaf{k}": np.linalg.norm(grads_ref[:, k], axis=-1) for k in range(LEAVES)}
+    diffs = compare.moment_diffs(mu(grads_prog), mu(grads_ref), norms)
+    return compare.whole_diff(mu(grads_prog), mu(grads_ref)), float(np.median(list(diffs.values())))
+
+
+def _largefluid_moment_limit():
+    import os
+
+    from benchmarks.tests.conftest import ROOT
+
+    with open(os.path.join(ROOT, "benchmarks", "limits", "largefluid_train_g1.json")) as f:
+        return json.load(f)["limits"]["moment_diff"]
+
+
+@pytest.mark.parametrize("cancel", [True, False])
+def test_moment_diff_does_not_fail_a_sound_run_whose_gradients_cancel(cancel):
+    """Reference gradients g, -g, g/10 leave a moment of 0.001 |g|. A program
+    off by 1e-3 of |g| in each gradient is as sound as it is when they do not
+    cancel; measured against |mu_r| it read over the limit."""
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    g = rng.normal(size=(LEAVES, 256))
+    signs = np.array([1.0, -1.0 if cancel else 1.0, 0.1])
+    grads = signs[:, None, None] * g
+    noise = rng.normal(size=grads.shape)
+    noise *= 1e-3 * np.linalg.norm(g, axis=-1)[None, :, None] / np.linalg.norm(noise, axis=-1, keepdims=True)
+    old, new = _moment_numbers(grads, grads + noise)
+    limit = _largefluid_moment_limit()
+    assert new < limit / 2
+    assert (old > limit) if cancel else (old < limit / 2)
+
+
+@pytest.mark.parametrize("cancel", [True, False])
+def test_moment_diff_still_fails_half_of_the_rows_left_out(cancel):
+    """Gradients that are means over rows whose halves differ; the fault
+    takes the mean over the first half."""
+    import numpy as np
+
+    rng = np.random.default_rng(1)
+    rows = rng.normal(size=(3, 64, LEAVES, 256)) + np.where(np.arange(64) < 32, 1.0, -0.5)[None, :, None, None]
+    rows = rows * np.array([1.0, -1.0 if cancel else 1.0, 0.1])[:, None, None, None]
+    _, new = _moment_numbers(rows.mean(axis=1), rows[:, :32].mean(axis=1))
+    assert new > 2 * _largefluid_moment_limit()
+
+
+def test_moment_diff_is_not_moved_by_a_few_leaves():
+    """One leaf of five far off (as the coordinate heads swing) leaves the
+    median where it was; three of five move it."""
+    import numpy as np
+
+    rng = np.random.default_rng(2)
+    grads = rng.normal(size=(3, LEAVES, 256))
+    off = lambda k: np.concatenate([np.full(k, 0.5), np.full(LEAVES - k, 1e-3)])[None, :, None]
+    _, one = _moment_numbers(grads, grads * (1 + off(1)))
+    _, three = _moment_numbers(grads, grads * (1 + off(3)))
+    assert one < 0.005 < 0.1 < three
+
+
+def test_read_limits_prints_what_moment_diff_is_made_of(monkeypatch, capsys, tmp_path):
+    import importlib.util
+    import os
+
+    from benchmarks.tests.conftest import ROOT
+
+    spec = importlib.util.spec_from_file_location(
+        "read_limits", os.path.join(ROOT, "benchmarks", "tools", "read_limits.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    # with the variable set the tool places no compile cache in code
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr("sys.argv", ["read_limits.py", "--workload", "toy_fluid_train", "--seeds", "5",
+                                     "--half", "1", "--mantissa", "1", "--mantissa-bits", "2",
+                                     "--platform", "cpu", "--benchmark-file", TOY_BENCH])
+    assert mod.main() == 0
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines() if l.startswith("{")]
+    by_variant = {l["variant"]: l for l in lines if "numbers" in l}
+    assert set(by_variant) == {"sound", "fault_half_rows", "control_mantissa2"}
+    for line in by_variant.values():
+        m = line["moment"]
+        assert {"diff", "ref", "bound", "update_norms", "unfloored"} <= set(m)
+        assert len(m["update_norms"]) == 3 and m["bound"] >= m["ref"] * (1 - 1e-6)
+        assert m["unfloored"] == pytest.approx(m["diff"] / m["ref"])
+    assert by_variant["sound"]["numbers"]["moment_diff"][0] < 0.05 \
+        < by_variant["fault_half_rows"]["numbers"]["moment_diff"][0]

@@ -7,8 +7,14 @@ is long; the benchmark's own runs never call this):
 
 For each seed the cell's driver starts a fresh state from the seed, drives the
 first steps through the window's own call, and the plain reference follows
-them: one JSON line of the compared numbers. ``--control`` switches on the
-configuration's lower-precision path (``control`` in its meta file).
+them: one JSON line of the compared numbers, with ``moment`` beside them:
+Adam's first moment over all leaves laid end to end (``diff``
+||mu_p - mu_r||, ``ref`` ||mu_r||, ``update_norms`` the norms of the
+reference's accumulated, clipped gradients, ``bound`` what ||mu_r|| would be
+had they not cancelled, ``unfloored`` = diff / ref, which was ``moment_diff``
+before PR 29; with ``--leaves`` also every leaf's term of today's).
+``--control`` switches on the configuration's lower-precision path
+(``control`` in its meta file).
 ``--half N`` also reads, on the first N seeds, the planted fault "half of the
 rows left out, the mean taken over the rest" with the reference put in the
 program's place.
@@ -91,6 +97,17 @@ def main() -> int:
             out["grad"] = compare.leaf_gaps(rec["grad"], ref["grad_first"])
         return out
 
+    def moment(rec, ref):
+        if rec.get("mu") is None:
+            return None
+        diff, norm = compare.whole_parts(rec["mu"], ref["mu"])
+        whole = np.sqrt(sum(np.asarray(v, np.float64) ** 2 for v in ref["update_norms"].values()))
+        out = {"diff": diff, "ref": norm, "bound": compare.moment_bounds({"all": whole})["all"],
+               "update_norms": whole.tolist(), "unfloored": diff / max(norm, 1e-150)}
+        if args.leaves:
+            out["leaves"] = compare.moment_diffs(rec["mu"], ref["mu"], ref["update_norms"])
+        return out
+
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
         with contextlib.redirect_stdout(sys.stderr):
             t0 = time.perf_counter()
@@ -105,6 +122,7 @@ def main() -> int:
         emit({"workload": args.workload, "seed": seed,
               "variant": variant,
               "numbers": {k: [v[0], v[1]] for k, v in nums.items()},
+              "moment": moment(rec, ref),
               "loss_program": rec["loss"].tolist(), "loss_reference": ref["loss"].tolist(),
               "program_s": t1 - t0, "reference_s": t2 - t1, "build_s": build_s})
         if args.leaves:
@@ -123,7 +141,8 @@ def main() -> int:
                         "mu": bad["mu"], "w": bad["w"], "w0": rec["w0"]}
                 nums = compare.numbers(fake, ref)
             emit({"workload": args.workload, "seed": seed, "variant": name,
-                  "numbers": {k: [v[0], v[1]] for k, v in nums.items()}})
+                  "numbers": {k: [v[0], v[1]] for k, v in nums.items()},
+                  "moment": moment(fake, ref)})
             if args.leaves:
                 emit({"workload": args.workload, "seed": seed, "variant": name + "_leaves",
                       **leaves(fake, ref)})

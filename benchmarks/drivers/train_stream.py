@@ -1,10 +1,19 @@
 """Driver ``train_stream``: the host step loop of distribute mode, as
 ``parallel/launch.py:run_distributed`` assembles it (which cannot be called
-itself: it trains to the end): one-device mesh, graphs partitioned by the
-program's own splitter, ``PrefetchLoader(ShardedGraphLoader)``, the
-shard-mapped step, ``train/trainer.py:run_epoch_train`` once per pass over
-the pool. The same step object and state serve the first (compared) steps in
-set-up and then the window.
+itself: it trains to the end): a mesh of ``parallel.mesh.graph`` devices on
+the graph axis (1 where the configuration names none), every graph cut into
+that many partitions by the program's own splitter, one dataset a partition
+into ``PrefetchLoader(ShardedGraphLoader)``, the shard-mapped step,
+``train/trainer.py:run_epoch_train`` once per pass over the pool. The same
+step object and state serve the first (compared) steps in set-up and then the
+window.
+
+The comparison sees the partitions through three things it hands the plain
+reference (``reference/fastegnn.py``, "The partitioned loss"): the whole raw
+graph's edge list with the edges between partitions taken out, each
+partition's own MMD draw mapped to raw nodes and laid end to end, and the
+weight ``P * n_p / n`` on partition ``p``'s draws. With one partition nothing
+is taken out, the draw is the one draw and no weight is handed over.
 """
 
 from __future__ import annotations
@@ -62,13 +71,42 @@ class _SpanLoader:
             yield batch
 
 
+def _cached(kind: str, key: dict, make):
+    """``make()``, kept under the work directory by every parameter that
+    shapes it (``key``)."""
+    digest = hashlib.sha256(json.dumps(key, sort_keys=True).encode()).hexdigest()[:16]
+    path = os.path.join(WORK, f"{kind}_{digest}.pkl")
+    if os.path.exists(path):
+        with open(path, "rb") as f:
+            return pickle.load(f)
+    made = make()
+    os.makedirs(WORK, exist_ok=True)
+    with open(path + ".tmp", "wb") as f:
+        pickle.dump(made, f, protocol=pickle.HIGHEST_PROTOCOL)
+    os.replace(path + ".tmp", path)
+    return made
+
+
+def _split_scene(sample: dict, split: dict, index: int) -> list:
+    """One raw scene -> its partitions, as ``process_large_fluid_distribute``
+    makes them: the whole graph without edges, then ``split_graph``."""
+    from distegnn_tpu.data.fluid113k import build_fluid_graph
+    from distegnn_tpu.data.partition import split_graph
+
+    g = build_fluid_graph(sample["loc"], sample["vel"], sample["viscosity"], sample["mass"],
+                          sample["target"])
+    return split_graph(g, split["parts"], split["method"], split["inner"],
+                       outer_radius=split["outer"], seed=index)
+
+
 class Driver:
     def __init__(self, config_file: str, mix: dict, seed: int, overrides=None):
         self.mix, self.seed = mix, int(seed)
         self.meta = common.load_meta(config_file)
         self.cfg = common.load_program_config(config_file, self.meta, seed, overrides)
         self.dims = common.model_dims(self.cfg)
-        self.chips = 1
+        mesh = (self.cfg.get("parallel") or {}).get("mesh") or {}
+        self.chips = int(mesh.get("graph") or 1)      # partitions = devices on the graph axis
         self.first, self.losses = [], []
         self.count = 0
         self.epoch = 1
@@ -76,30 +114,13 @@ class Driver:
 
     # ---------------------------------------------------------------- set-up
     def _pool(self, samples):
-        """The pool's partitioned graphs, from the program's own splitter
-        (``data/partition.py``), cached under the work directory by every
-        parameter that shapes them."""
-        from distegnn_tpu.data.fluid113k import build_fluid_graph
-        from distegnn_tpu.data.partition import split_graph
-
+        """The pool's graphs, each as its list of partitions from the
+        program's own splitter (``data/partition.py``), cached."""
         d = self.cfg.data
-        key = json.dumps({"mix": self.mix, "split": d.split_mode,
-                          "inner": d.inner_radius, "outer": d.outer_radius},
-                         sort_keys=True)
-        path = os.path.join(WORK, "pool_" + hashlib.sha256(key.encode()).hexdigest()[:16] + ".pkl")
-        if os.path.exists(path):
-            with open(path, "rb") as f:
-                return pickle.load(f)
-        pool = []
-        for i, s in enumerate(samples):
-            g = build_fluid_graph(s["loc"], s["vel"], s["viscosity"], s["mass"], s["target"])
-            pool.append(split_graph(g, 1, d.split_mode, d.inner_radius,
-                                    outer_radius=d.outer_radius, seed=i)[0])
-        os.makedirs(WORK, exist_ok=True)
-        with open(path + ".tmp", "wb") as f:
-            pickle.dump(pool, f, protocol=pickle.HIGHEST_PROTOCOL)
-        os.replace(path + ".tmp", path)
-        return pool
+        split = {"parts": self.chips, "method": d.split_mode,
+                 "inner": d.inner_radius, "outer": d.outer_radius}
+        return _cached("pool", {"mix": self.mix, "split": split},
+                       lambda: [_split_scene(s, split, i) for i, s in enumerate(samples)])
 
     def setup(self, weights: dict) -> None:
         self.build()
@@ -116,27 +137,39 @@ class Driver:
         from distegnn_tpu.train import make_optimizer, needs_grad_clip
         from distegnn_tpu.utils.seed import fix_seed
 
-        cfg, d = self.cfg, self.cfg.data
-        derive_runtime_fields(cfg, world_size=1)
+        cfg, d, P = self.cfg, self.cfg.data, self.chips
+        derive_runtime_fields(cfg, world_size=P)
         fix_seed(cfg.seed % (2 ** 32))
-        mesh = make_mesh(n_graph=1, n_data=1, n_tensor=1, devices=jax.devices()[:1])
+        mesh = make_mesh(n_graph=P, n_data=1, n_tensor=1, devices=jax.devices()[:P])
 
         t0 = time.perf_counter()
         self.samples = make_samples(self.mix)
-        dataset = open_dataset(self._pool(self.samples), node_order=d.node_order)
-        self.dataset = dataset
+        pool = self._pool(self.samples)
+        # kept (local) edges of each pool graph, a partition at a time
+        kept = np.asarray([[part["edge_index"].shape[1] for part in parts] for parts in pool])
+        self.edge_imbalance = float(np.mean(kept.max(axis=1) / kept.mean(axis=1)))
+        self.kept_edges_max = int(kept.sum(axis=1).max())
+        repeats = int(self.mix.get("pass_repeats", 1))
+        self.datasets, self.built = [], {}
+        for p in range(P):
+            ds = open_dataset([parts[p] for parts in pool], node_order=d.node_order)
+            if repeats > 1:
+                # a pass lists every pool graph ``repeats`` times (reordered once)
+                ds = open_dataset([ds[i] for i in range(len(ds))] * repeats)
+            self.datasets.append(ds)
         inner = self.inner = ShardedGraphLoader(
-            [dataset], d.batch_size, shuffle=True, seed=cfg.seed,
+            self.datasets, d.batch_size, shuffle=True, seed=cfg.seed,
             node_bucket=d.node_bucket, edge_bucket=d.edge_bucket, data_parallel=1,
             edge_block=d.edge_block, split_remote=False, pairing=None)
         self.loader = _SpanLoader(PrefetchLoader(
             inner, global_batch_putter(mesh), depth=int(d.get("prefetch_depth", 2))))
         self.prep_s = time.perf_counter() - t0
-        self.nodes_per_graph = int(dataset[0]["loc"].shape[0])
-        self.edges_per_graph = int(np.mean([g["edge_index"].shape[1] for g in dataset.graphs]))
-        self.padded = (inner.loaders[0].max_nodes, inner.loaders[0].max_edges)
+        # of the whole graph: real nodes, and the edges its partitions keep
+        self.nodes_per_graph = int(sum(ds[0]["loc"].shape[0] for ds in self.datasets))
+        self.edges_per_graph = int(kept.sum(axis=1).mean())
+        self.padded = (inner.loaders[0].max_nodes, inner.loaders[0].max_edges)   # a partition's
 
-        model = get_model(cfg.model, world_size=1, dataset_name=d.dataset_name,
+        model = get_model(cfg.model, world_size=P, dataset_name=d.dataset_name,
                           axis_name=GRAPH_AXIS, tensor_axis=None)
         self.clip = 0.3 if needs_grad_clip(cfg) else None
         tx = self.tx = make_optimizer(
@@ -218,7 +251,8 @@ class Driver:
                 "failed": int(np.sum(~np.isfinite(losses))),
                 "nodes": steps * self.nodes_per_graph * int(self.cfg.data.batch_size),
                 "launches_expected": steps,
-                "counters": {"data_stall_s": stall.value - stall0}}
+                "counters": {"data_stall_s": stall.value - stall0,
+                             "partition_edge_imbalance": self.edge_imbalance}}
 
     # ------------------------------------------------------------ comparison
     def program_record(self) -> dict:
@@ -234,45 +268,74 @@ class Driver:
                 "loss_total": np.asarray([f["loss_total"] for f in first], np.float64),
                 "grad": grad, "mu": inner_mu, "w": w_last, "w0": self.w0}
 
+    def _raw_graph(self, gi: int, radius: float) -> dict:
+        """The reference's graph of pool scene ``gi`` (k-d tree radius search
+        over the whole raw cloud: 9 s at 800,000 particles), which the mix
+        alone shapes: cached like the pool."""
+        return _cached("refgraph", {"mix": self.mix, "radius": radius, "graph": gi},
+                       lambda: ref_graphs.fluid_graph(self.samples[gi], radius))
+
     def reference_inputs(self) -> dict:
         """The raw batches of the first steps, in the order the loader fed
-        them, with the MMD draw of each step's key mapped to raw nodes."""
+        them: the whole raw graph without the edges between partitions, each
+        partition's MMD draw of the step's key mapped to raw nodes."""
         import jax.numpy as jnp
 
         raw_means = np.stack([s["loc"].mean(axis=0) for s in self.samples])
         C, S = self.dims["virtual_channels"], int(self.cfg.train.mmd.samples)
-        n, N = self.nodes_per_graph, self.padded[0]
-        built, batches = {}, []
+        P, n, N = self.chips, self.nodes_per_graph, self.padded[0]
+        radius = float(self.cfg.data.inner_radius)
+        # edge lists padded to one length, so that one reference program
+        # serves the whole pool: the longest kept list, rounded up as the fed
+        # batch's edge axis is or to whole edge blocks
+        edge_block = self.mix.get("reference_edge_block")
+        unit = int(edge_block or self.cfg.data.edge_bucket)
+        edges = -(-self.kept_edges_max // unit) * unit
+        built, batches = self.built, []       # the pool's graphs serve every seed of one driver
         for f in self.first:
-            mean = np.asarray(f["loc_mean"]).reshape(3)
+            mean = np.asarray(f["loc_mean"]).reshape(-1, 3)[0]     # every partition carries the graph's
             gi = int(np.argmin(np.sum((raw_means - mean) ** 2, axis=1)))
             if gi not in built:
-                perm = common.node_perm(self.dataset[gi]["loc"], self.samples[gi]["loc"])
-                built[gi] = (ref_graphs.fluid_graph(self.samples[gi], float(self.cfg.data.inner_radius)), perm)
-            g, perm = built[gi]
+                # where the loaders put each raw node: the partitions' fed
+                # (reordered) rows, laid end to end, are a permutation of the
+                # raw cloud; partition p's rows are perms[p]
+                fed = [ds[gi]["loc"] for ds in self.datasets]
+                perm = common.node_perm(np.concatenate(fed), self.samples[gi]["loc"])
+                perms = np.split(perm, np.cumsum([len(x) for x in fed])[:-1])
+                label = np.empty(n, np.int32)
+                for p, rows in enumerate(perms):
+                    label[rows] = p
+                g = self._raw_graph(gi, radius)
+                local = label[g["row"]] == label[g["col"]]           # cut edges are dropped
+                built[gi] = (dict(g, **{k: g[k][local] for k in ("row", "col", "eattr")}), perms)
+            g, perms = built[gi]
             # the draw as the configuration runs it: the step key folded with
-            # the partition's index on the graph axis (0), split per graph,
-            # S*C uniform draws over the real nodes of the fed (reordered) graph
-            key = jax.random.split(jax.random.fold_in(f["key"], 0), 1)[0]
-            u = jax.random.uniform(key, (S * C,))
-            idx = np.minimum(np.asarray((u * n).astype(jnp.int32)), N - 1)
-            # for the planted fault "half of the rows left out": the rows the
-            # loader put into the second half of its (reordered) node axis
+            # the partition's index on the graph axis, split per graph, S*C
+            # uniform draws over the real nodes of the partition's fed rows
+            idx, weight = [], []
+            # for the planted fault "half of the rows left out": the rows each
+            # partition's loader put into the second half of its node axis
             second = np.zeros(n, np.float32)
-            second[perm[n // 2:]] = 1.0
-            # edge lists padded to the fed batch's edge axis: one reference
-            # program for the whole pool
-            batches.append(ref_graphs.stack([dict(g, mmd_idx=perm[idx].astype(np.int32),
-                                                  second_half=second)], edges=self.padded[1]))
-        return {"batches": batches, "model": self.dims,
-                "train": common.train_spec(self.cfg, self.clip),
-                "block": int(self.mix["reference_block"]),
-                "edge_block": self.mix.get("reference_edge_block")}
+            for p, rows in enumerate(perms):
+                key = jax.random.split(jax.random.fold_in(f["key"], p), 1)[0]
+                u = jax.random.uniform(key, (S * C,))
+                drawn = np.minimum(np.asarray((u * len(rows)).astype(jnp.int32)), N - 1)
+                idx.append(rows[drawn])
+                weight.append(np.full(S * C, P * len(rows) / n, np.float32))
+                second[rows[len(rows) // 2:]] = 1.0
+            extra = {"mmd_w": np.concatenate(weight)} if P > 1 else {}
+            batches.append(ref_graphs.stack(
+                [dict(g, mmd_idx=np.concatenate(idx).astype(np.int32), second_half=second, **extra)],
+                edges=edges))
+        train = common.train_spec(self.cfg, self.clip)
+        train["mmd"]["samples"] = P * S          # the P draws laid end to end
+        return {"batches": batches, "model": self.dims, "train": train,
+                "block": int(self.mix["reference_block"]), "edge_block": edge_block}
 
     def shapes(self) -> dict:
         return common.step_shapes(self, int(self.cfg.data.batch_size))
 
     def free(self) -> None:
-        for name in ("state", "state_first", "state_last", "step", "loader", "dataset",
+        for name in ("state", "state_first", "state_last", "step", "loader", "datasets", "inner",
                      "first", "losses"):
             setattr(self, name, None)

@@ -35,6 +35,25 @@ real nodes, and the node update) is not blocked. Without ``edge_block`` the
 edge part runs once over all edges: the same equations and the same order of
 operations as with one block, and the path both one-chip cells run.
 
+The partitioned loss (distribute mode on ``P`` devices, one partition of the
+graph a device) is written in the same inputs. Partition ``p`` holds ``n_p`` of
+the ``n`` nodes, draws its OWN ``samples * C`` target nodes from them and
+differentiates ``(n_p / n) * (MSE_p + weight * MMD_p)``; the gradients are
+summed. Over the whole graph that is ``sse / (3 n)``, as here, and
+``k(V,V) / C^2 - 2 / (samples * C * C) * sum_p (n_p / n) * k(draws_p, V)``: a
+WEIGHTED sum over the ``P`` sets of draws. A batch says so with ``mmd_idx``
+the ``P`` draws laid end to end, ``mmd_w`` ``[G, len(mmd_idx)]`` the weight
+``P * n_p / n`` on partition ``p``'s draws, and ``samples`` ``P`` times the
+configuration's in the ``mmd`` spec (the division by ``G * samples * C * C``
+then gives the sum above). Without ``mmd_w`` every draw has weight 1: the
+same program as before the key existed. Three facts of the program this
+relies on, each held by ``benchmarks/tests``: edges whose ends lie in
+different partitions are dropped (each part's radius graph is built from its
+own nodes, so the driver takes them out of the edge list it hands over); the
+virtual nodes' means run over the nodes of ALL partitions (so ``V`` is one
+global set, and ``k(V,V)`` counts once because the shares ``n_p / n`` sum to
+1); each partition draws from its own nodes at the share ``n_p / n``.
+
 Departures from the published training script, each because the
 configuration as run states it: the MMD term draws its ``samples * C`` target
 nodes with replacement (the drawn indices are an input here, so both sides
@@ -189,20 +208,24 @@ def forward(w, model, g, mantissa=None, edge_block=None):
     return x, X
 
 
-def _kernel_sum(a, b, sigma):
+def _kernel_sum(a, b, sigma, w=None):
+    """sum_ij w_i k(a_i, b_j); ``w`` None: every row of ``a`` at weight 1."""
     d2 = jnp.sum((a[:, None, :] - b[None, :, :]) ** 2, axis=-1)
-    return jnp.sum(jnp.exp(-jnp.sqrt(jnp.maximum(d2, 1e-24)) / (2.0 * sigma * sigma)))
+    k = jnp.exp(-jnp.sqrt(jnp.maximum(d2, 1e-24)) / (2.0 * sigma * sigma))
+    return jnp.sum(k if w is None else k * w[:, None])
 
 
 def _block_terms(w, model, mmd, blk, mantissa, edge_block):
     """Sums over one block of graphs: squared error over the rows that count
-    (``loss_rows``, all ones unless a fault is planted), k(V,V), k(samples,V)."""
+    (``loss_rows``, all ones unless a fault is planted), k(V,V), k(samples,V),
+    the last with each drawn node at its weight ``mmd_w`` where the batch
+    carries one (the partitioned loss, module docstring)."""
     def one(g):
         pred, X = forward(w, model, g, mantissa, edge_block)
         sse = jnp.sum((pred - g["target"]) ** 2 * g["loss_rows"][:, None])
         V = X.T
         k_vv = _kernel_sum(V, V, mmd["sigma"])
-        k_rv = _kernel_sum(g["target"][g["mmd_idx"]], V, mmd["sigma"])
+        k_rv = _kernel_sum(g["target"][g["mmd_idx"]], V, mmd["sigma"], g.get("mmd_w"))
         return sse, k_vv, k_rv
 
     sse, k_vv, k_rv = jax.vmap(one)(blk)

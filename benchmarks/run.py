@@ -172,6 +172,9 @@ def run(argv=None, benchmark_file=None, platform=REQUIRED_PLATFORM) -> dict:
 
     with contextlib.redirect_stdout(sys.stderr):     # the program logs to stdout
         driver = driver_mod.Driver(os.path.join(base, config["file"]), mix, args.seed)
+        if driver.chips != int(cell["chips"]):
+            raise SystemExit(f"configuration {config['name']!r} runs on {driver.chips} chip(s), "
+                             f"the cell asks for {cell['chips']}")
         w0 = weights.make_weights(args.seed, driver.dims)
         driver.setup(w0)
         setup = {"setup_s": time.perf_counter() - _T_START, "compile_s": meter.seconds,
@@ -199,6 +202,7 @@ def run(argv=None, benchmark_file=None, platform=REQUIRED_PLATFORM) -> dict:
         driver.free()
         del driver
         gc.collect()
+        jax.clear_caches()      # the program's executables hold their temporaries' arena on every chip
 
         t_ref = time.perf_counter()
         reference = compare.reference_record(inputs, record["w0"])

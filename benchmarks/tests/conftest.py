@@ -8,7 +8,13 @@ import json
 import os
 import sys
 
+import jax
 import pytest
+
+# the four-partition cell's tests need four host devices; valid until a
+# backend initializes (a plug-in imports jax before this file, so the
+# XLA_FLAGS route is too late: tests/conftest.py does the same)
+jax.config.update("jax_num_cpu_devices", 4)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))

@@ -12,6 +12,7 @@ import sys
 
 import jax
 import numpy as np
+import pytest
 import yaml
 
 from benchmarks.drivers import common
@@ -27,7 +28,7 @@ def _derived_yaml(tmp_path, name, edits):
         node = cfg
         keys = dotted.split(".")
         for k in keys[:-1]:
-            node = node[k]
+            node = node.setdefault(k, {})
         node[keys[-1]] = v
     path = str(tmp_path / (name + ".yaml"))
     with open(path, "w") as f:
@@ -96,27 +97,38 @@ def test_scan_driver_matches_main(tmp_path, monkeypatch):
     _close(mine["w"], theirs)
 
 
-def test_stream_driver_matches_main(tmp_path, monkeypatch):
+@pytest.mark.parametrize("config, mix_name, parts", [("toy_fluid", "toy_fluid_mix", 1),
+                                                     ("toy_fluid_g4", "toy_fluid_g4_mix", 4)])
+def test_stream_driver_matches_main(tmp_path, monkeypatch, config, mix_name, parts):
+    """One partition on a one-device mesh, and four on the graph axis of a
+    four-device mesh, each against ``run_distributed``'s own order."""
     import main as program_main
     import distegnn_tpu.parallel.launch as launch
 
-    mix = dict(toy_mix("toy_fluid_mix"), compare_steps=8)    # one pass over the pool
-    path = _derived_yaml(tmp_path, "toy_fluid", {
+    mix = dict(toy_mix(mix_name), compare_steps=8)    # one pass over the pool
+    repeats = int(mix.get("pass_repeats", 1))
+    path = _derived_yaml(tmp_path, config, {
         "log.log_dir": str(tmp_path / "logs"), "log.test_interval": 1, "seed": SEED,
-        "train.scan_epochs": False})
+        "train.scan_epochs": False,
+        # named for one partition too: left out, the program takes every device it finds
+        "parallel.mesh.graph": parts})
     mod = importlib.import_module("benchmarks.drivers." + mix["kind"])
     with contextlib.redirect_stdout(sys.stderr):
         d = mod.Driver(path, mix, SEED)
+        assert d.chips == parts
         d.build()
-        # the program reads partitioned shards from disk: hand it the driver's pool
-        shard = str(tmp_path / "pool_0-1.pkl")
-        with open(shard, "wb") as f:
-            pickle.dump(d._pool(d.samples), f)
+        # the program reads one shard file a partition from disk: hand it the
+        # driver's pool, a pass listing each scene as often as the driver's
+        shards = []
+        for p in range(parts):
+            shards.append(str(tmp_path / f"pool_{p}-{parts}.pkl"))
+            with open(shards[-1], "wb") as f:
+                pickle.dump([scene[p] for scene in d._pool(d.samples)] * repeats, f)
         monkeypatch.setattr(launch, "_dispatch_preprocess",
-                            lambda config, ws: [[shard], [shard], [shard]])
+                            lambda config, ws: [shards, shards, shards])
         from distegnn_tpu.models.registry import get_model
 
-        model = get_model(d.cfg.model, world_size=1, dataset_name=d.cfg.data.dataset_name)
+        model = get_model(d.cfg.model, world_size=parts, dataset_name=d.cfg.data.dataset_name)
         sample = jax.tree.map(lambda x: x[0], next(iter(d.inner)))
         w0, names = _program_init(d, sample, model)
         d.start(w0, SEED)

@@ -2,6 +2,7 @@
 skipped): the result line's keys, and ``correct`` coming out false once for
 each fault a training cell can have, planted under the timed path."""
 
+import dataclasses
 import json
 
 import jax.numpy as jnp
@@ -61,6 +62,10 @@ def _break_step(monkeypatch, fault):
             if fault == "state_unchanged":
                 _, metrics = inner(state, batch, key)
                 return state, metrics
+            if fault == "params_unchanged":
+                # the optimizer's state advances, the weights are never written back
+                new, metrics = inner(state, batch, key)
+                return dataclasses.replace(new, params=state.params), metrics
             if fault == "half_rows":
                 # the second half of the batch no longer counts (of several
                 # graphs the later ones, of one graph its later nodes); the
@@ -203,7 +208,13 @@ def test_read_limits_prints_what_moment_diff_is_made_of(monkeypatch, capsys, tmp
     assert mod.main() == 0
     lines = [json.loads(l) for l in capsys.readouterr().out.splitlines() if l.startswith("{")]
     by_variant = {l["variant"]: l for l in lines if "numbers" in l}
-    assert set(by_variant) == {"sound", "fault_half_rows", "control_mantissa2"}
+    assert set(by_variant) == {"sound", "fault_half_rows", "control_mantissa2", "fault_params_unchanged"}
+    # every record is decided under the cell's own limits, as run.py decides a run
+    assert by_variant["sound"]["correct"] is True and by_variant["sound"]["over"] == []
+    for name in ("fault_half_rows", "control_mantissa2"):
+        assert by_variant[name]["correct"] is False and by_variant[name]["over"], name
+    stuck = by_variant.pop("fault_params_unchanged")      # costs no run, carries no ``moment``
+    assert stuck["numbers"]["change_diff"][0] == 1.0 and set(stuck["over"]) <= {"change_diff"}
     for line in by_variant.values():
         m = line["moment"]
         assert {"diff", "ref", "bound", "update_norms", "unfloored"} <= set(m)

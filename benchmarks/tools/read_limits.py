@@ -7,7 +7,9 @@ is long; the benchmark's own runs never call this):
 
 For each seed the cell's driver starts a fresh state from the seed, drives the
 first steps through the window's own call, and the plain reference follows
-them: one JSON line of the compared numbers, with ``moment`` beside them:
+them: one JSON line of the compared numbers, ``correct`` and ``over`` as
+``compare.decide`` gives them under the cell's own limits file (a control or
+a fault has to read ``correct`` false), with ``moment`` beside them:
 Adam's first moment over all leaves laid end to end (``diff``
 ||mu_p - mu_r||, ``ref`` ||mu_r||, ``update_norms`` the norms of the
 reference's accumulated, clipped gradients, ``bound`` what ||mu_r|| would be
@@ -18,6 +20,13 @@ before PR 29; with ``--leaves`` also every leaf's term of today's).
 ``--half N`` also reads, on the first N seeds, the planted fault "half of the
 rows left out, the mean taken over the rest" with the reference put in the
 program's place.
+Every seed also gives ``fault_params_unchanged``: the program's own record
+with its weights left at their start, which costs no run.
+``--free-program`` drives every seed's first steps before any reference, keeps
+their records and reference inputs on the host, and frees the program: for a
+cell whose reference does not fit a chip beside the loaded program (an
+800,000-particle graph: 15.4 GB beside the step's 4.5). The last line gives
+``memory_stats`` of every local device.
 """
 
 from __future__ import annotations
@@ -45,6 +54,8 @@ def main() -> int:
                          "matmul operands rounded to 3 mantissa bits, put in the program's place'")
     ap.add_argument("--mantissa-bits", type=int, default=3)
     ap.add_argument("--leaves", action="store_true", help="emit every leaf's gap")
+    ap.add_argument("--free-program", action="store_true",
+                    help="all seeds through the program first, then the program freed, then the references")
     ap.add_argument("--out", default=None)
     ap.add_argument("--platform", default="tpu")
     ap.add_argument("--benchmark-file", default=os.path.join(ROOT, "BENCHMARK.json"))
@@ -65,6 +76,8 @@ def main() -> int:
     beside = os.path.dirname(os.path.dirname(cfg_file))
     with open(os.path.join(beside, "traffic", cell["traffic"] + ".json")) as f:
         mix = json.load(f)
+    with open(os.path.join(beside, "limits", cell["name"] + ".json")) as f:
+        limits = json.load(f)["limits"]
     overrides = common.load_meta(cfg_file)["control"] if args.control else None
     if overrides and any(k.startswith("reference.") for k in overrides):
         raise SystemExit("this configuration's control is the reference at lower precision: "
@@ -85,6 +98,15 @@ def main() -> int:
         driver.build()
         build_s = time.perf_counter() - t0
     variant = "control" if args.control else "sound"
+
+    def verdict(nums):
+        """``numbers``, and what ``run.py`` would make of them."""
+        ok, compared = compare.decide(nums, limits)
+        return {"numbers": {k: [v[0], v[1]] for k, v in nums.items()}, "correct": ok,
+                "over": [k for k, c in compared.items() if not c["value"] <= c["limit"]]}
+
+    def device_memory():
+        return [{k: int(v) for k, v in (d.memory_stats() or {}).items()} for d in jax.local_devices()]
 
     def leaves(rec, ref):
         keep = compare.moving_leaves(ref["grad_first"])
@@ -108,23 +130,42 @@ def main() -> int:
             out["leaves"] = compare.moment_diffs(rec["mu"], ref["mu"], ref["update_norms"])
         return out
 
-    for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
+    def program(seed):
+        """(record, reference inputs, seconds) of ``seed``'s first steps."""
+        t0 = time.perf_counter()
+        driver.start(weights.make_weights(seed, driver.dims), seed)
+        return driver.program_record(), driver.reference_inputs(), time.perf_counter() - t0
+
+    seeds = [int(s) for s in args.seeds.split(",")]
+    held = {}
+    if args.free_program:
+        import gc
+
         with contextlib.redirect_stdout(sys.stderr):
-            t0 = time.perf_counter()
-            w0 = weights.make_weights(seed, driver.dims)
-            driver.start(w0, seed)
-            rec = driver.program_record()
-            inputs = driver.reference_inputs()
+            held = {seed: program(seed) for seed in seeds}
+            program_memory = device_memory()
+            driver.free()
+            driver = None
+            gc.collect()
+            jax.clear_caches()
+        emit({"workload": args.workload, "variant": variant, "program_memory_stats": program_memory})
+
+    for i, seed in enumerate(seeds):
+        with contextlib.redirect_stdout(sys.stderr):
+            rec, inputs, program_s = held.pop(seed) if held else program(seed)
             t1 = time.perf_counter()
             ref = compare.reference_record(inputs, rec["w0"])
             t2 = time.perf_counter()
             nums = compare.numbers(rec, ref)
         emit({"workload": args.workload, "seed": seed,
-              "variant": variant,
-              "numbers": {k: [v[0], v[1]] for k, v in nums.items()},
+              "variant": variant, **verdict(nums),
               "moment": moment(rec, ref),
               "loss_program": rec["loss"].tolist(), "loss_reference": ref["loss"].tolist(),
-              "program_s": t1 - t0, "reference_s": t2 - t1, "build_s": build_s})
+              "program_s": program_s, "reference_s": t2 - t1, "build_s": build_s})
+        # the fault "the parameters are never written back" needs no run: the
+        # program's own record with its weights left where they started
+        emit({"workload": args.workload, "seed": seed, "variant": "fault_params_unchanged",
+              **verdict(compare.numbers(dict(rec, w=rec["w0"]), ref))})
         if args.leaves:
             emit({"workload": args.workload, "seed": seed, "variant": variant + "_leaves",
                   **leaves(rec, ref)})
@@ -140,14 +181,12 @@ def main() -> int:
                 fake = {"loss": loss, "grad": bad["grad_first"] if rec.get("grad") is not None else None,
                         "mu": bad["mu"], "w": bad["w"], "w0": rec["w0"]}
                 nums = compare.numbers(fake, ref)
-            emit({"workload": args.workload, "seed": seed, "variant": name,
-                  "numbers": {k: [v[0], v[1]] for k, v in nums.items()},
+            emit({"workload": args.workload, "seed": seed, "variant": name, **verdict(nums),
                   "moment": moment(fake, ref)})
             if args.leaves:
                 emit({"workload": args.workload, "seed": seed, "variant": name + "_leaves",
                       **leaves(fake, ref)})
-    emit({"workload": args.workload, "variant": variant, "memory_stats":
-          {k: int(v) for k, v in (jax.local_devices()[0].memory_stats() or {}).items()}})
+    emit({"workload": args.workload, "variant": variant, "memory_stats": device_memory()})
     if out:
         out.close()
     return 0

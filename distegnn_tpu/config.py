@@ -65,7 +65,8 @@ _DEFAULTS: dict = {
         "checkpoint": None,
         # TPU knobs: 'bf16' runs invariant-channel MLPs at MXU-native
         # precision (geometry stays f32 — see docs/PERFORMANCE.md); remat
-        # recomputes each layer in backward, trading FLOPs for HBM headroom
+        # recomputes each layer's MLPs in backward (what its edge passes
+        # return is kept), trading FLOPs for HBM headroom
         "compute_dtype": None,
         "remat": False,
         # lowering of the blocked edge ops (only used when data.edge_block>0):

@@ -23,12 +23,14 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from distegnn_tpu import obs
 from distegnn_tpu.models.common import (
     MLP, CoordMLP, HoistedEdgeMLP, TorchDense, _TorchDenseParams,
     _torch_bias_init, coord_head_init, gather_nodes, resolve_dtype,
     torch_linear_init,
 )
-from distegnn_tpu.ops.blocked import EdgeOps, blocked_slot_inv_deg
+from distegnn_tpu.ops.blocked import (REMAT_KEPT, EdgeOps,
+                                      blocked_slot_inv_deg)
 from distegnn_tpu.ops.edge_pipeline import (EdgeWeights, build_edge_blocks,
                                             fused_edge_layer)
 from distegnn_tpu.ops.layer_pipeline import (DEFAULT_STACK_VMEM_BUDGET,
@@ -578,9 +580,15 @@ class FastEGNN(nn.Module):
     # 'ell' (scatter-free fixed-degree gathers — exact)
     segment_impl: str = "scatter"
     # recompute each layer's activations in the backward pass instead of
-    # keeping them in HBM: layer activations are O(E*H) (hundreds of MB at
-    # LargeFluid scale), so remat trades cheap recompute FLOPs for the
-    # memory that bounds graph size / batch per chip (jax.checkpoint)
+    # keeping them in HBM: layer activations are O(E*H) (about 2,100 bytes an
+    # edge a layer), so remat trades recompute for the memory that bounds
+    # graph size / batch per chip (jax.checkpoint). What the layer's edge
+    # passes RETURN is kept (EdgeOps, REMAT_KEPT: the pre-activation sum in
+    # the compute dtype, coord_diff and the packed segment sum, 2H + 12
+    # bytes an edge at bf16) and only the MLPs run again: a gather or a
+    # scatter is the dearest op of the step, an MLP the cheapest
+    # (docs/PERFORMANCE.md "Remat memory scaling"; gauge
+    # ``model/remat_saved_bytes``)
     remat: bool = False
     fuse_agg: bool = True          # packed per-layer aggregation (EGCLVel)
     agg_dtype: Optional[str] = None  # 'bf16' packed-aggregation stream (EGCLVel)
@@ -666,7 +674,12 @@ class FastEGNN(nn.Module):
             return self._fused_stack_forward(g, h, x, v, X, Hv, gravity,
                                              fused_arrs)
 
-        layer_cls = nn.remat(EGCLVel) if self.remat else EGCLVel
+        layer_cls = EGCLVel
+        if self.remat:
+            layer_cls = nn.remat(EGCLVel, policy=(
+                jax.checkpoint_policies.save_only_these_names(*REMAT_KEPT)))
+        obs.get_registry().gauge("model/remat_saved_bytes").set(
+            self._remat_saved_bytes(g))
         # under a graph/tensor mesh fused_stack lowers to the per-layer
         # fused path: collectives cannot cross the megakernel's Pallas grid,
         # and the param tree is identical so the fallback is exact
@@ -696,6 +709,24 @@ class FastEGNN(nn.Module):
               oh=oh, fused_arrs=fused_arrs)
 
         return x, X
+
+    def _remat_saved_bytes(self, g: GraphBatch) -> int:
+        """Bytes the rematted layers of THIS trace keep of their edge passes
+        (beside their inputs), from the shapes of the named values: a layer
+        keeps ``[B, E, H]`` in the compute dtype and ``f32[B, E, 3]`` where
+        phi_e is hoisted, and the packed ``f32[B, N, 3+H+1]`` segment sum
+        where the aggregation is fused (a blocked batch makes two sums and
+        no count column). 0 without remat, and on the fused edge paths, which
+        name nothing."""
+        if not self.remat or self.edge_impl != "plain":
+            return 0
+        B, E = g.row.shape
+        H = self.hidden_nf
+        item = jnp.dtype(resolve_dtype(self.compute_dtype) or jnp.float32).itemsize
+        per_edge = (H * item + 12) if self.hoist_edge_mlp else 0
+        count_column = 0 if g.edge_block > 0 else 1
+        per_node = 4 * (3 + H + count_column) if self.fuse_agg else 0
+        return self.n_layers * B * (E * per_edge + g.max_nodes * per_node)
 
     def _fused_stack_forward(self, g: GraphBatch, h, x, v, X, Hv, gravity,
                              fused_arrs):

@@ -41,6 +41,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -599,6 +600,11 @@ def _count_gather_pass():
     obs.get_registry().counter("edge/gather_passes").add()
 
 
+# what a rematted layer keeps of its edge passes (models/fast_egnn.py): the
+# names EdgeOps puts on those results, for ``save_only_these_names``
+REMAT_KEPT = ("edge_pre", "edge_diff", "edge_agg")
+
+
 class EdgeOps:
     """The one definition of the edge-op dispatch all model families share:
     row/col gathers and per-destination aggregations, lowered as
@@ -626,6 +632,14 @@ class EdgeOps:
     has 2 gathers and (from autodiff) 2 transposed scatter-adds where separate
     calls make 4 and 4. The pack is float32: coordinates never pass through
     bf16, and bf16 products widen exactly.
+
+    What the two packed passes return is NAMED (``checkpoint_name``,
+    :data:`REMAT_KEPT`), above the choice of lowering like the scopes: a layer
+    under ``jax.checkpoint`` with ``save_only_these_names(*REMAT_KEPT)`` keeps
+    the pre-activation sum, ``coord_diff`` and the segment sum and recomputes
+    only what is computed FROM them, so no gather and no segment sum runs
+    twice (their transposes need the indices alone). Outside a checkpoint a
+    name is the identity and lowers to nothing.
 
     The methods carry the device scopes ``edge_gather`` and ``edge_aggregate``
     (``jax.named_scope``), above the choice of lowering: whichever branch
@@ -709,8 +723,10 @@ class EdgeOps:
         operands single-pass and f32 ones six-pass, so widening the products
         would cost more than the saved passes."""
         if self.blocked:
-            return (self.gather_rows(a) + self.gather_cols(b),
-                    self.gather_rows(x) - self.gather_cols(x))
+            return (checkpoint_name(self.gather_rows(a) + self.gather_cols(b),
+                                    "edge_pre"),
+                    checkpoint_name(self.gather_rows(x) - self.gather_cols(x),
+                                    "edge_diff"))
         dt = jnp.result_type(a, b, x)
         w, xp = a.shape[-1], x.astype(dt)
         out = (self.gather_rows(jnp.concatenate([a.astype(dt), xp], -1))
@@ -721,7 +737,8 @@ class EdgeOps:
         # epoch compiled for a v5e: +1.32 GB of temporaries without, +0.007
         # with; PERF.md section 6, PR 27)
         diff = jax.lax.optimization_barrier(out[..., w:].astype(x.dtype))
-        return out[..., :w].astype(a.dtype), diff
+        return (checkpoint_name(out[..., :w].astype(a.dtype), "edge_pre"),
+                checkpoint_name(diff, "edge_diff"))
 
     @jax.named_scope("edge_aggregate")
     def _agg(self, data, mean: bool):
@@ -796,8 +813,9 @@ class EdgeOps:
                 a = a.astype(jnp.bfloat16)
                 b = b.astype(jnp.bfloat16)
             out_a = self.agg_rows_sum(a) if not a_mean else self.agg_rows_mean(a)
-            return (out_a.astype(jnp.float32),
-                    self.agg_rows_mean(b).astype(jnp.float32))
+            return (checkpoint_name(out_a.astype(jnp.float32), "edge_agg"),
+                    checkpoint_name(self.agg_rows_mean(b).astype(jnp.float32),
+                                    "edge_agg"))
         g = self.g
         B, E = b.shape[0], b.shape[1]
         sa = a.shape[-1]
@@ -826,6 +844,9 @@ class EdgeOps:
                 (N, t.shape[-1]), jnp.float32).at[r].add(
                     t.astype(jnp.float32),
                     indices_are_sorted=g.edges_sorted))(packed, g.row)
+        # named before the division: neither the sum nor the count is made
+        # again by a rematted layer's backward
+        out = checkpoint_name(out, "edge_agg")
         cnt = jnp.maximum(out[..., -1:], 1.0)
         out_a = out[..., :sa] / cnt if a_mean else out[..., :sa]
         return out_a, out[..., sa:-1] / cnt

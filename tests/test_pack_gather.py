@@ -3,13 +3,21 @@ the hoisted phi_e products and the coordinates ride the same row and col
 pass. Parity against the separate gathers for every lowering, forward (bit
 for bit in f32) and gradients, the count of gathers and scatter-adds a layer
 leaves in the gradient, the counter that says the pack engages, and the
-parameter tree, which is the one the separate form had."""
+parameter tree, which is the one the separate form had.
+
+And what ``remat: true`` keeps of those passes (EdgeOps names their results,
+FastEGNN's checkpoint policy saves the names): the residuals a rematted model
+holds, remat against no remat, the names lowering to nothing outside a
+checkpoint, and the gauge ``model/remat_saved_bytes``."""
+
+import re
 
 import jax
 import jax.flatten_util
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src.ad_checkpoint import saved_residuals  # public: print_saved_residuals only
 
 from distegnn_tpu import obs
 from distegnn_tpu.models.fast_egnn import FastEGNN
@@ -42,6 +50,10 @@ def _ops(g, lowering):
     if lowering == "blocked":
         return EdgeOps(g, *blocked_slot_inv_deg(g))
     return EdgeOps(g, seg_impl=lowering)
+
+
+def _model(lowering, **kw):
+    return FastEGNN(**MODEL, segment_impl="scatter" if lowering == "blocked" else lowering, **kw)
 
 
 def _separate(self, a, b, x):
@@ -127,8 +139,7 @@ def test_fastegnn_grads_match_separate_gathers_f32(rng, lowering, monkeypatch):
     """Whole model, gradients w.r.t. every parameter (phi_e's kernel among
     them), the coordinates and the node features."""
     g = _batch(rng, lowering)
-    model = FastEGNN(**MODEL, normalize=True,
-                     segment_impl="scatter" if lowering == "blocked" else lowering)
+    model = _model(lowering, normalize=True)
     params = model.init(jax.random.PRNGKey(0), g)
     out = model.apply(params, g)
     got = _model_grads(model, params, g)
@@ -165,16 +176,19 @@ def test_fastegnn_bf16_compute_within_band_of_separate(rng, remat, monkeypatch):
     np.testing.assert_allclose(a, b, rtol=3e-2, atol=3e-2 * np.abs(b).max())
 
 
-def _edge_gather_eqns(jaxpr, found, outer=""):
-    """Primitive name of every gather / scatter-add equation traced under
-    the ``edge_gather`` scope. A nested jaxpr's name stacks are relative to
-    the equation that holds it (``take_along_axis`` is a jitted call)."""
+def _edge_eqns(jaxpr, found, outer=""):
+    """``(scope, primitive)`` of every gather / scatter-add equation traced
+    under one of the two edge scopes. A nested jaxpr's name stacks are
+    relative to the equation that holds it (``take_along_axis`` is a jitted
+    call)."""
     for eqn in jaxpr.eqns:
         stack = f"{outer}/{eqn.source_info.name_stack}"
-        if eqn.primitive.name in ("gather", "scatter-add") and "edge_gather" in stack:
-            found.append(eqn.primitive.name)
+        if eqn.primitive.name in ("gather", "scatter-add"):
+            for scope in ("edge_gather", "edge_aggregate"):
+                if scope in stack:
+                    found.append((scope, eqn.primitive.name))
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _edge_gather_eqns(sub, found, stack)
+            _edge_eqns(sub, found, stack)
     return found
 
 
@@ -182,8 +196,11 @@ def _edge_gather_eqns(jaxpr, found, outer=""):
                          ids=["f32", "bf16_remat"])
 def test_two_gathers_and_two_scatter_adds_a_layer(rng, kw):
     """The gradient's jaxpr: per layer one gather per edge end and, as its
-    transpose, one scatter-add per edge end (four and four before the pack);
-    remat repeats the forward's two."""
+    transpose, one scatter-add per edge end (four and four before the pack),
+    one segment sum and, as its transpose, one gather. Remat repeats NONE of
+    them: the rematted layer keeps what the edge passes return (it repeated
+    the forward's two gathers and the segment sum while it kept the layer's
+    inputs alone)."""
     g = _batch(rng, "scatter")
     model = FastEGNN(**MODEL, **kw)
     params = model.init(jax.random.PRNGKey(0), g)
@@ -191,10 +208,116 @@ def test_two_gathers_and_two_scatter_adds_a_layer(rng, kw):
     before = passes.value
     jaxpr = jax.make_jaxpr(jax.grad(_loss(model, g), argnums=(0, 1)))(params, g.loc, g.node_feat)
     assert passes.value - before == 2 * L
-    found = _edge_gather_eqns(jaxpr.jaxpr, [])
-    forward = 2 * L * (2 if kw.get("remat") else 1)
-    assert found.count("gather") == forward
-    assert found.count("scatter-add") == 2 * L
+    found = _edge_eqns(jaxpr.jaxpr, [])
+    assert found.count(("edge_gather", "gather")) == 2 * L
+    assert found.count(("edge_gather", "scatter-add")) == 2 * L
+    assert found.count(("edge_aggregate", "scatter-add")) == L
+    assert found.count(("edge_aggregate", "gather")) == L
+
+
+def _kept(model, params, g):
+    """The residuals of the gradient that some op computed (not an argument,
+    not a constant of the closure), as ``(shape, dtype, where from)``."""
+    return [(tuple(a.shape), a.dtype, why)
+            for a, why in saved_residuals(_loss(model, g), params, g.loc, g.node_feat)
+            if not why.startswith("from ")]
+
+
+@pytest.mark.parametrize("dtype", [None, "bf16"], ids=["f32", "bf16"])
+@pytest.mark.parametrize("lowering", LOWERINGS)
+def test_remat_keeps_what_the_edge_passes_return_and_nothing_else_of_edge_size(rng, lowering,
+                                                                               dtype):
+    g = _batch(rng, lowering)
+    (B, E), N = g.row.shape, g.max_nodes
+    assert E not in (B, N, H, 3, 3 + H + 1)
+    model = _model(lowering, remat=True, compute_dtype=dtype)
+    params = model.init(jax.random.PRNGKey(0), g)
+    kept = _kept(model, params, g)
+    cd = jnp.dtype(jnp.bfloat16 if dtype else jnp.float32)
+    f32 = jnp.dtype(jnp.float32)
+    named = sorted((s, d) for s, d, why in kept if "EdgeOps." in why)
+    edge = [((B, E, H), cd), ((B, E, 3), f32)]
+    if lowering == "blocked":
+        # two sums and no count: autodiff keeps the named sums the backward
+        # reads (the sum of translations enters x linearly, the last layer's
+        # aggregated features reach no loss) and no other
+        assert [v for v in named if E in v[0]] == sorted(edge * L)
+        assert {v for v in named if E not in v[0]} <= {((B, N, 3), f32), ((B, N, H), f32)}
+    else:
+        assert named == sorted((edge + [((B, N, 3 + H + 1), f32)]) * L)
+    # nothing else with an edge axis lives from the forward to the backward
+    assert sorted((s, d) for s, d, _ in kept if E in s) == sorted(edge * L)
+    # while without remat the MLPs' edge-sized activations do
+    kept = _kept(_model(lowering, compute_dtype=dtype), params, g)
+    assert sum(E in s for s, _, _ in kept) > 10 * L
+
+
+@pytest.mark.parametrize("dtype", [None, "bf16"], ids=["f32", "bf16"])
+@pytest.mark.parametrize("lowering", ["scatter", "cumsum", "ell"])
+def test_remat_equals_no_remat(rng, lowering, dtype):
+    """Loss and gradients: the kept values are the values the recompute
+    would make. In f32 both agree to round-off and no closer (XLA fuses a
+    checkpointed layer differently, and did with the whole layer recomputed:
+    3e-8 of the largest gradient, the loss an ulp under ``ell``);
+    bf16 within the band of
+    ``test_fastegnn_bf16_compute_within_band_of_separate`` (the blocked path:
+    tests/test_blocked.py::test_remat_same_outputs_and_grads)."""
+    g = _batch(rng, lowering)
+    plain, remat = (_model(lowering, compute_dtype=dtype, remat=r) for r in (False, True))
+    params = plain.init(jax.random.PRNGKey(0), g)
+    value_and_grad = lambda m: jax.value_and_grad(_loss(m, g), argnums=(0, 1, 2))(
+        params, g.loc, g.node_feat)
+    (ref_loss, ref), (loss, got) = value_and_grad(plain), value_and_grad(remat)
+    flat = lambda t: np.asarray(jax.flatten_util.ravel_pytree(t)[0], np.float32)
+    a, b = flat(got), flat(ref)
+    tol = 1e-6 if dtype is None else 3e-2
+    np.testing.assert_allclose(loss, ref_loss, rtol=tol)
+    np.testing.assert_allclose(a, b, rtol=tol, atol=tol * np.abs(b).max())
+
+
+@pytest.mark.parametrize("lowering", LOWERINGS)
+def test_names_lower_to_nothing_without_remat(rng, lowering, monkeypatch):
+    """``remat: false`` (the n-body configuration): the gradient's lowered
+    text is the text of the program with no name in it, but for the numbers
+    the lowering gives its private functions (``@silu_52``)."""
+    from distegnn_tpu.ops import blocked
+
+    g = _batch(rng, lowering)
+    model = _model(lowering)
+    params = model.init(jax.random.PRNGKey(0), g)
+    text = lambda: re.sub(r"@(\w+?)_\d+\b", r"@\1", jax.jit(jax.grad(
+        _loss(model, g), argnums=(0, 1, 2))).lower(params, g.loc, g.node_feat).as_text())
+    named = text()
+    calls = []
+    monkeypatch.setattr(blocked, "checkpoint_name", lambda x, name: calls.append(name) or x)
+    assert text() == named
+    assert sorted(set(calls)) == sorted(blocked.REMAT_KEPT)
+
+
+@pytest.mark.parametrize("dtype", [None, "bf16"], ids=["f32", "bf16"])
+@pytest.mark.parametrize("lowering", LOWERINGS)
+def test_remat_saved_bytes_gauge(rng, lowering, dtype):
+    """``model/remat_saved_bytes``: set as FastEGNN is traced, to the bytes
+    of the named residuals (the formula of docs/OBSERVABILITY.md on a plain
+    batch); 0 with remat off."""
+    g = _batch(rng, lowering)
+    (B, E), N = g.row.shape, g.max_nodes
+    gauge = obs.get_registry().gauge("model/remat_saved_bytes")
+    model = _model(lowering, remat=True, compute_dtype=dtype)
+    params = model.init(jax.random.PRNGKey(0), g)
+    gauge.set(-1)
+    kept = _kept(model, params, g)
+    named = sum(int(np.prod(s)) * d.itemsize for s, d, why in kept if "EdgeOps." in why)
+    per_edge = H * (2 if dtype else 4) + 12
+    if lowering == "blocked":
+        # the gauge counts what is named; of a blocked batch's two sums
+        # autodiff keeps those the backward reads
+        assert L * B * E * per_edge <= named <= gauge.value
+        assert gauge.value == L * B * (E * per_edge + N * (3 + H) * 4)
+    else:
+        assert gauge.value == named == L * B * (E * per_edge + N * (3 + H + 1) * 4)
+    jax.make_jaxpr(lambda p: _model(lowering, compute_dtype=dtype).apply(p, g))(params)
+    assert gauge.value == 0
 
 
 @pytest.mark.parametrize("lowering,passes", [("scatter", 2 * L), ("cumsum", 2 * L),
@@ -204,7 +327,7 @@ def test_gather_passes_counter(rng, lowering, passes):
     program is traced: 2 a layer packed, 4 a layer on a blocked batch,
     which keeps its separate calls."""
     g = _batch(rng, lowering)
-    model = FastEGNN(**MODEL, segment_impl="scatter" if lowering == "blocked" else lowering)
+    model = _model(lowering)
     params = model.init(jax.random.PRNGKey(0), g)
     counter = obs.get_registry().counter("edge/gather_passes")
     before = counter.value

@@ -92,12 +92,6 @@ _DEFAULTS: dict = {
         # dominant read bytes; f32 accumulation; rounds geometry columns —
         # measured opt-in, see docs/PERFORMANCE.md round-4 attack)
         "agg_dtype": None,
-        # real-edge lowering (FastEGNN): 'plain' (EdgeOps streams, any
-        # layout) or 'fused' (one Pallas pass per layer over the blocked
-        # in-window edges + compact remote tail, ops/edge_pipeline).
-        # 'fused' requires data.edge_block >= 512 (multiple of 512) and
-        # edge_attr_nf == 2; loaders then build split_remote batches.
-        "edge_impl": "plain",
     },
     "data": {
         "data_dir": "./data",
@@ -206,8 +200,8 @@ _DEFAULTS: dict = {
         "rollout": None,
         # session-affinity graph-prep cache (serve/prep.py): capacity of the
         # per-model LRU keyed on the client session_id; 0 disables. A hit
-        # skips Morton relabel + blocked re-pack + remote classify for
-        # repeat-topology requests (prep_ms ~ gather-only).
+        # skips Morton relabel + blocked re-pack for repeat-topology
+        # requests (prep_ms ~ gather-only).
         "session_cache": 64,
         # byte bound for the same cache (plan nbytes accounting, evict-to-
         # fit): tile plans for million-node scenes are MBs each, so the
@@ -480,9 +474,27 @@ _CLI_FIELDS = {
     "tensor_parallel": ("parallel.mesh.tensor", int),
     # resilience: 'auto' or an explicit checkpoint path (train.resume)
     "resume": ("train.resume", str),
-    # real-edge lowering: plain | fused | fused_stack (model.edge_impl)
-    "edge_impl": ("model.edge_impl", str),
 }
+
+# Keys this program had and has no more. A config file or a command line is
+# input from outside: one that still carries such a key is refused by name,
+# never run on the path that is left.
+_REMOVED_KEYS = ("model.edge_impl", "model.stack_vmem_budget")
+_REMOVED_CLI = ("edge_impl",)
+
+
+def removed_error(what: str) -> ValueError:
+    return ValueError(
+        f"{what} was removed: the fused Pallas edge pipelines it belonged to "
+        "never compiled on the TPU (CHANGES.md, PR 21 and PR 32), and "
+        "EdgeOps is the one real-edge path; delete it")
+
+
+def _refuse_removed_keys(cfg: ConfigDict) -> None:
+    for dotted in _REMOVED_KEYS:
+        section, key = dotted.split(".")
+        if key in (cfg.get(section) or {}):
+            raise removed_error(dotted)
 
 
 def _set_path(cfg: ConfigDict, dotted: str, value: Any) -> None:
@@ -509,6 +521,8 @@ def apply_overrides(cfg: ConfigDict, overrides: Mapping) -> None:
                 cfg.log.wandb.enable = True
                 cfg.log.wandb.offline = False
             continue
+        if name in _REMOVED_CLI:
+            raise removed_error(f"--{name}")
         if name not in _CLI_FIELDS:
             raise KeyError(f"unknown override {name!r}; valid: {sorted(_CLI_FIELDS)}")
         _set_path(cfg, _CLI_FIELDS[name][0], value)
@@ -524,10 +538,13 @@ def build_arg_parser() -> argparse.ArgumentParser:
     parser.add_argument("--multihost", action="store_true")
     for name, (_, typ) in _CLI_FIELDS.items():
         parser.add_argument(f"--{name}", type=typ, default=None)
+    for name in _REMOVED_CLI:   # parsed so that apply_overrides can say why
+        parser.add_argument(f"--{name}", default=None, help=argparse.SUPPRESS)
     return parser
 
 
 def validate_config(cfg: ConfigDict) -> None:
+    _refuse_removed_keys(cfg)
     if cfg.data.accelerate_mode not in _VALID_ACCEL_MODES:
         raise ValueError(f"data.accelerate_mode must be one of {_VALID_ACCEL_MODES}")
     if cfg.data.accelerate_mode == "distribute":
@@ -556,43 +573,6 @@ def validate_config(cfg: ConfigDict) -> None:
         raise ValueError("train.divergence_lr_decay must be in (0, 1]")
     if cfg.model.virtual_channels < 1:
         raise ValueError("model.virtual_channels must be >= 1")
-    edge_impl = cfg.model.get("edge_impl", "plain")
-    if edge_impl not in ("plain", "fused", "fused_stack"):
-        raise ValueError(
-            "model.edge_impl must be 'plain', 'fused', or 'fused_stack'")
-    if edge_impl in ("fused", "fused_stack"):
-        from distegnn_tpu.ops.edge_pipeline import OH_CHUNK
-
-        blk = int(cfg.data.edge_block)
-        if blk < OH_CHUNK or blk % OH_CHUNK:
-            raise ValueError(
-                f"model.edge_impl='{edge_impl}' requires data.edge_block >= "
-                f"{OH_CHUNK} and a multiple of {OH_CHUNK} (got {blk})")
-        if int(cfg.model.edge_attr_nf) != 2:
-            raise ValueError(f"model.edge_impl='{edge_impl}' requires "
-                             "edge_attr_nf == 2 "
-                             "(the kernel's scalar lane layout is fixed)")
-        if bool(cfg.model.normalize):
-            raise ValueError(f"model.edge_impl='{edge_impl}' does not support "
-                             "model.normalize (flagship EGCL only)")
-    if edge_impl == "fused_stack":
-        # fused's constraints PLUS a layer-grid + VMEM-residency contract:
-        # the megakernel grid is (n_layers,) and the whole blocked graph
-        # must fit the per-core VMEM budget — the residency estimate is
-        # shape-dependent, so the hard gate lives at trace time
-        # (ops/layer_pipeline raises StackVmemBudgetError naming the bound);
-        # here we validate what the config alone can know.
-        if int(cfg.model.n_layers) < 1:
-            raise ValueError(
-                "model.edge_impl='fused_stack' requires model.n_layers >= 1 "
-                "(the megakernel grid runs one step per layer)")
-        budget = int(cfg.model.get("stack_vmem_budget", 0) or 0)
-        if budget < 0:
-            raise ValueError(
-                "model.stack_vmem_budget must be >= 0 bytes (0 = the "
-                "16 MiB/core default; the fused_stack megakernel raises "
-                "StackVmemBudgetError at trace time when the VMEM-resident "
-                "graph exceeds this bound)")
     par = cfg.get("parallel")
     mesh = par.get("mesh") if par is not None else None
     if mesh is not None:

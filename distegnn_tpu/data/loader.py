@@ -21,6 +21,7 @@ import numpy as np
 import jax
 
 from distegnn_tpu import obs
+from distegnn_tpu.config import removed_error
 from distegnn_tpu.ops.graph import GraphBatch, _round_up, pad_graphs
 
 # module-level open hook: the fault-injection harness (testing/faults.py
@@ -204,9 +205,11 @@ class GraphLoader:
         pairing: Optional[bool] = None,  # None=auto (blocked: symmetry scan; plain: off)
         cache_bytes: int = 2 << 30,
         max_in_degree: Optional[int] = None,  # plain+pairing: dataset-stable ELL D
-        split_remote: bool = False,  # fused edge pipeline: carry compact remote list
-        remote_pad: Optional[int] = None,  # None=auto (dataset scan, run-stable R)
+        # False only; benchmarks/drivers/train_scan.py and train_stream.py pass it (ROADMAP D15)
+        split_remote: bool = False,
     ):
+        if split_remote:
+            raise removed_error("GraphLoader(split_remote=True)")
         self.dataset = dataset
         self.batch_size = batch_size
         self.shuffle = shuffle
@@ -215,11 +218,6 @@ class GraphLoader:
         self.edge_block, self.edge_tile = edge_block, edge_tile
         self.pairing = False
         self._prepared_cache = None
-        if split_remote and not edge_block:
-            raise ValueError("GraphLoader: split_remote requires edge_block > 0 "
-                             "(the fused pipeline's window is defined on the "
-                             "blocked layout)")
-        self.split_remote, self.remote_pad = bool(split_remote), remote_pad
         if edge_block:
             # dataset-stable blocked layout: ONE edges_per_block and ONE
             # pairing decision for every batch (single scan up front), so the
@@ -231,10 +229,6 @@ class GraphLoader:
                                  "edge_block; pass edges_per_block instead")
             n, _ = dataset.size_maxima()
             self.max_nodes = _round_up(max(max_nodes or 0, n, 1), edge_block)
-            if split_remote:
-                # fused kernel's 3-block VMEM window needs nb >= 3; small
-                # graphs pay two all-padding blocks rather than failing
-                self.max_nodes = max(self.max_nodes, 3 * edge_block)
             if edges_per_block is None or pairing is None:
                 deg, sym = scan_dataset_for_blocking(
                     dataset, self.max_nodes, edge_block)
@@ -244,17 +238,6 @@ class GraphLoader:
             self.pairing = pairing
             self.edges_per_block = edges_per_block
             self.max_edges = (self.max_nodes // edge_block) * edges_per_block
-            if self.split_remote and self.remote_pad is None:
-                # run-stable remote width: scan raw edge lists once (blockify
-                # never adds out-of-window edges — its padding slots sit
-                # inside their own block), pad to a lane multiple
-                from distegnn_tpu.ops.edge_pipeline import count_remote_edges
-
-                er = max(count_remote_edges(dataset[i]["edge_index"],
-                                            block=edge_block,
-                                            n_nodes=self.max_nodes)
-                         for i in range(len(dataset)))
-                self.remote_pad = max(_round_up(er, 128), 128)
             # cache prepared (blockified) graphs across epochs when affordable:
             # per-graph blocked edge payload ~ E * (2 idx + attrs + mask + pair)
             d0 = dataset[0].get("edge_attr")
@@ -300,9 +283,7 @@ class GraphLoader:
         if self.edge_block:
             return dict(edge_block=self.edge_block, edge_tile=self.edge_tile,
                         edges_per_block=self.edges_per_block,
-                        max_nodes=self.max_nodes, compute_pair=self.pairing,
-                        split_remote=self.split_remote,
-                        remote_pad=self.remote_pad)
+                        max_nodes=self.max_nodes, compute_pair=self.pairing)
         return dict(max_nodes=self.max_nodes, max_edges=self.max_edges,
                     compute_pair=self.pairing, max_in_degree=self.max_in_degree)
 
@@ -375,8 +356,11 @@ class ShardedGraphLoader:
         edge_block: int = 0,
         edge_tile: int = 512,
         pairing: Optional[bool] = None,  # None=auto (blocked: AND over shard scans; plain: off)
+        # False only; benchmarks/drivers/train_scan.py and train_stream.py pass it (ROADMAP D15)
         split_remote: bool = False,
     ):
+        if split_remote:
+            raise removed_error("ShardedGraphLoader(split_remote=True)")
         sizes = {len(d) for d in datasets}
         if len(sizes) != 1:
             raise ValueError(f"shards must be equal length, got {sorted(sizes)}")
@@ -391,37 +375,19 @@ class ShardedGraphLoader:
             from distegnn_tpu.ops.blocked import scan_dataset_for_blocking
 
             N = _round_up(n, edge_block)
-            if split_remote:
-                # fused kernel's 3-block VMEM window needs nb >= 3 (same
-                # clamp as GraphLoader's single-shard blocked branch)
-                N = max(N, 3 * edge_block)
             scans = [scan_dataset_for_blocking(d, N, edge_block) for d in datasets]
             epb = _round_up(max(s[0] for s in scans), edge_tile)
             if pairing is None:
                 pairing = all(s[1] for s in scans)
-            rp = None
-            if split_remote:
-                # one remote width across ALL shards (same rectangular-stack
-                # argument as epb above)
-                from distegnn_tpu.ops.edge_pipeline import count_remote_edges
-
-                er = max(count_remote_edges(d[i]["edge_index"],
-                                            block=edge_block, n_nodes=N)
-                         for d in datasets for i in range(len(d)))
-                rp = max(_round_up(er, 128), 128)
             self.loaders = [
                 GraphLoader(
                     d, batch_size * data_parallel, shuffle=shuffle, seed=seed,
                     max_nodes=N, edge_block=edge_block, edge_tile=edge_tile,
                     edges_per_block=epb, pairing=pairing,
-                    split_remote=split_remote, remote_pad=rp,
                 )
                 for d in datasets
             ]
         else:
-            if split_remote:
-                raise ValueError("ShardedGraphLoader: split_remote requires "
-                                 "edge_block > 0")
             # one static max_in_degree across ALL shards so the stacked
             # [P, B, ...] batches share a single pytree identity
             mid = None

@@ -330,8 +330,7 @@ class PrefetchLoader:
     the overlapped collate work no longer pollutes the stall counter; only
     the consumer's real wait on the queue lands on ``data/stall_s``.
     ``data/prefetch_depth`` gauge reports the configured depth. ``depth=0``
-    degrades to the old fully synchronous behavior (useful for A/B: bench.py
-    --layout io runs both).
+    degrades to the old fully synchronous behavior.
 
     Spans: each batch is built under ``data/produce`` > ``data/collate`` (the
     inner loader's ``next``), ``data/put``. The producer's wait on a full

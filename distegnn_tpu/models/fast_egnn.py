@@ -25,137 +25,15 @@ from flax import linen as nn
 
 from distegnn_tpu import obs
 from distegnn_tpu.models.common import (
-    MLP, CoordMLP, HoistedEdgeMLP, TorchDense, _TorchDenseParams,
-    _torch_bias_init, coord_head_init, gather_nodes, resolve_dtype,
-    torch_linear_init,
+    MLP, CoordMLP, HoistedEdgeMLP, TorchDense, resolve_dtype,
 )
 from distegnn_tpu.ops.blocked import (REMAT_KEPT, EdgeOps,
                                       blocked_slot_inv_deg)
-from distegnn_tpu.ops.edge_pipeline import (EdgeWeights, build_edge_blocks,
-                                            fused_edge_layer)
-from distegnn_tpu.ops.layer_pipeline import (DEFAULT_STACK_VMEM_BUDGET,
-                                             StackConfig, fused_egnn_stack)
 from distegnn_tpu.ops.graph import GraphBatch
 from distegnn_tpu.ops.segment import masked_sum
 from distegnn_tpu.parallel.collectives import (
-    global_node_mean, tp_copy, tp_gather, tp_once, tp_reduce, tp_slice,
+    global_node_mean, tp_copy, tp_reduce,
 )
-
-
-class FusedEdgeParams(nn.Module):
-    """Raw phi_e + phi_x parameters for ``edge_impl='fused'``.
-
-    Same shapes and init variances as the hoisted plain path (HoistedEdgeMLP
-    ``phi_e`` + CoordMLP ``phi_x``), declared as raw arrays because both the
-    Pallas kernel (ops/edge_pipeline.EdgeWeights) and the compact remote tail
-    consume the weights directly. Like ``hoist_edge_mlp``, flipping
-    ``edge_impl`` changes the param tree — checkpoints are not compatible
-    across the flag (tests/test_fused_model.py remaps between them)."""
-
-    hidden_nf: int
-    scalar_nf: int           # per-edge scalars: radial + edge_attr
-
-    @nn.compact
-    def __call__(self):
-        H, S = self.hidden_nf, self.scalar_nf
-        fan1 = 2 * H + S
-        w1 = self.param("w1", torch_linear_init, (fan1, H), jnp.float32)
-        b1 = self.param("b1", _torch_bias_init(fan1), (H,), jnp.float32)
-        w2 = self.param("w2", torch_linear_init, (H, H), jnp.float32)
-        b2 = self.param("b2", _torch_bias_init(H), (H,), jnp.float32)
-        w3 = self.param("w3", torch_linear_init, (H, H), jnp.float32)
-        b3 = self.param("b3", _torch_bias_init(H), (H,), jnp.float32)
-        w4 = self.param("w4", coord_head_init, (H, 1), jnp.float32)
-        return w1, b1, w2, b2, w3, b3, w4
-
-
-class _MLPParams(nn.Module):
-    """Parameter-only shadow of :class:`common.MLP` (non-TP path): declares
-    the identical ``TorchDense_{i}/Dense_0/{kernel,bias}`` subtree — same
-    names, shapes, and initializers — without the compute. Flax derives init
-    RNG from the module PATH, so a checkpoint is bitwise interchangeable
-    between this and the real MLP (the precedent is MLP's own tensor-parallel
-    branch, which does the same with _TorchDenseParams). The fused_stack
-    megakernel uses these to own the whole layer loop while keeping the
-    param tree identical to the per-layer EGCLVel modules."""
-
-    sizes: Tuple[int, ...]
-    use_bias_last: bool = True
-    kernel_init_last: Optional[object] = None
-
-    @nn.compact
-    def __call__(self, fan_in: int):
-        outs = []
-        f = fan_in
-        for i, s in enumerate(self.sizes):
-            last = i == len(self.sizes) - 1
-            outs.append(_TorchDenseParams(
-                s, use_bias=(self.use_bias_last if last else True),
-                kernel_init=(self.kernel_init_last if last else None),
-                name=f"TorchDense_{i}")(f))
-            f = s
-        return outs
-
-
-class _CoordMLPParams(nn.Module):
-    """Parameter-only shadow of :class:`common.CoordMLP` (``MLP_0`` subtree:
-    Dense(H) + biasless coord-head Dense(1) with coord_head_init)."""
-
-    hidden_nf: int
-
-    @nn.compact
-    def __call__(self, fan_in: int):
-        return _MLPParams([self.hidden_nf, 1], use_bias_last=False,
-                          kernel_init_last=coord_head_init,
-                          name="MLP_0")(fan_in)
-
-
-class _EGCLVelStackParams(nn.Module):
-    """Parameter-only shadow of one fused-path EGCLVel layer, returned in the
-    megakernel's flat weight layout (ops/layer_pipeline.stack_weight_shapes).
-
-    Declares exactly the subtree EGCLVel's ``edge_impl='fused'`` branch
-    declares — phi_e_fused raw arrays plus the phi_ev/phi_xv/phi_X/phi_v/
-    phi_h/phi_hv (+phi_g) MLP stacks — so ``edge_impl: fused_stack`` shares
-    checkpoints bitwise with ``fused``: the [L, a, b] stacking that
-    fused_egnn_stack consumes is a runtime VIEW (stack/transpose/row-bias
-    reshape), not a different tree."""
-
-    hidden_nf: int
-    virtual_channels: int
-    node_attr_nf: int
-    edge_attr_nf: int
-    has_gravity: bool
-
-    @nn.compact
-    def __call__(self):
-        H, C, A = self.hidden_nf, self.virtual_channels, self.node_attr_nf
-        w1, b1, w2, b2, w3, b3, w4 = FusedEdgeParams(
-            H, 1 + self.edge_attr_nf, name="phi_e_fused")()
-        ev = _MLPParams([H, H], name="phi_ev")(2 * H + 1 + C)
-        xv = _CoordMLPParams(H, name="phi_xv")(H)
-        Xh = _CoordMLPParams(H, name="phi_X")(H)
-        vv = _MLPParams([H, 1], name="phi_v")(H)
-        hh = _MLPParams([H, H], name="phi_h")(3 * H + A)
-        hv = _MLPParams([H, H], name="phi_hv")(2 * H)
-        row = lambda b: b[None]                  # [F] bias -> [1, F] row view
-        w = {"e_w1": w1, "e_b1": row(b1), "e_w2": w2, "e_b2": row(b2),
-             "e_w3": w3, "e_b3": row(b3), "e_w4": w4.T,
-             "ev_k0": ev[0][0], "ev_b0": row(ev[0][1]),
-             "ev_k1": ev[1][0], "ev_b1": row(ev[1][1]),
-             "xv_k0": xv[0][0], "xv_b0": row(xv[0][1]), "xv_k1": xv[1][0],
-             "X_k0": Xh[0][0], "X_b0": row(Xh[0][1]), "X_k1": Xh[1][0],
-             "v_k0": vv[0][0], "v_b0": row(vv[0][1]),
-             "v_k1": vv[1][0], "v_b1": vv[1][1].reshape(1, 1),
-             "h_k0": hh[0][0], "h_b0": row(hh[0][1]),
-             "h_k1": hh[1][0], "h_b1": row(hh[1][1]),
-             "hv_k0": hv[0][0], "hv_b0": row(hv[0][1]),
-             "hv_k1": hv[1][0], "hv_b1": row(hv[1][1])}
-        if self.has_gravity:
-            gg = _MLPParams([H, 1], name="phi_g")(H)
-            w.update({"g_k0": gg[0][0], "g_b0": row(gg[0][1]),
-                      "g_k1": gg[1][0], "g_b1": gg[1][1].reshape(1, 1)})
-        return w
 
 
 class EGCLVel(nn.Module):
@@ -202,7 +80,7 @@ class EGCLVel(nn.Module):
     # one packed aggregation pass per layer (translations + edge features +
     # count ride a single segment sum — EdgeOps.agg_rows_pair) instead of
     # two aggregations and a count. Same math; accumulation is ALWAYS f32 in
-    # the fused path, so under compute_dtype=bf16 it is slightly MORE
+    # the packed pass, so under compute_dtype=bf16 it is slightly MORE
     # precise than the legacy two-call path (whose bf16 edge_feat
     # aggregation accumulated in bf16) — not bit-identical for bf16 models;
     # fuse_agg=False restores the legacy numerics exactly.
@@ -212,11 +90,6 @@ class EGCLVel(nn.Module):
     # TRANSLATIONS — equivariance becomes approximate at bf16 noise level.
     # Opt-in (its speed: not measured on this machine), None = f32.
     agg_dtype: Optional[str] = None
-    # real-edge lowering: 'plain' = per-edge streams through EdgeOps (any
-    # layout), 'fused' = ONE Pallas pass per layer over the blocked in-window
-    # edges (ops/edge_pipeline) plus a dense remote tail — needs a blocked
-    # batch built with split_remote=True and edge_block >= 512
-    edge_impl: str = "plain"
 
     @nn.compact
     def __call__(
@@ -231,7 +104,6 @@ class EGCLVel(nn.Module):
         slot: Optional[jnp.ndarray] = None,     # [B, E] blocked-layout slots
         inv_deg: Optional[jnp.ndarray] = None,  # [B, N, 1] 1/max(in-degree, 1)
         oh: Optional[jnp.ndarray] = None,       # [B, nb, epb, block] einsum incidence
-        fused_arrs: Optional[Tuple] = None,     # batched build_edge_blocks output
         # tiled serving (serve/tiled.py): the layer runs over ONE tile of a
         # larger scene. tile_coord_mean is the precomputed SCENE-global
         # coordinate mean (replaces psum #1 — a tile-local mean would be
@@ -250,151 +122,40 @@ class EGCLVel(nn.Module):
         nm = node_mask[..., None]
         ops = EdgeOps(g, slot, inv_deg, oh, seg_impl=self.seg_impl)
 
-        # --- real-edge lowering: 'plain' materializes per-edge streams via
-        # EdgeOps; 'fused' runs one Pallas pass over the blocked in-window
-        # edges + a dense remote tail and yields aggregated [B, N, ...]
-        # results directly (no per-edge intermediate ever touches HBM)
-        if self.edge_impl not in ("plain", "fused"):
-            raise ValueError(f"unknown edge_impl {self.edge_impl!r}")
         if self.coords_agg not in ("sum", "mean"):
             raise ValueError(f"Wrong coords_agg parameter {self.coords_agg!r}")
-        fused = self.edge_impl == "fused"
-        agg = agg_h_f = None
-        if fused:
-            if self.attention or self.normalize or self.tanh:
-                raise ValueError(
-                    "edge_impl='fused' supports the flagship EGCL only: "
-                    "attention/normalize/tanh are baked out of the kernel — "
-                    "use edge_impl='plain' with those heads")
-            if self.edge_attr_nf != 2:
-                raise ValueError(
-                    f"edge_impl='fused' requires edge_attr_nf=2 (the kernel "
-                    f"scalar lanes are [radial, attr0, attr1]); got "
-                    f"{self.edge_attr_nf}")
-            if fused_arrs is None or g.remote_edge_index is None:
-                raise ValueError(
-                    "edge_impl='fused' needs a blocked batch built with "
-                    "split_remote=True plus the hoisted build_edge_blocks "
-                    "arrays (FastEGNN passes them) — check data.edge_block "
-                    "and the loader's split_remote flag")
-            w1, b1, w2, b2, w3, b3, w4 = FusedEdgeParams(
-                H, 1 + self.edge_attr_nf, name="phi_e_fused")()
-            c = (lambda a: a.astype(dt)) if dt is not None else (lambda a: a)
-            tx = self.tensor_axis
-            if tx is not None:
-                # Tensor-parallel dispatch of the SAME kernel: the hoisted
-                # node-axis products are column-sliced then gathered (phi_e's
-                # collective), and the phi_x head weights (w3/b3/w4) flow in
-                # as 1/T slices — the kernel derives every internal shape from
-                # its operands, so no kernel change. Its trans_sum output
-                # becomes a rank-local partial (closed by one node-level psum
-                # below); ef_sum/count stay replicated. Kernel inputs carrying
-                # gradients are wrapped in tp_copy (bwd psum) because the
-                # kernel's cotangents mix the partial phi_x path with the
-                # replicated phi_e path; the replicated outputs are wrapped in
-                # tp_once (bwd /T) so that psum counts their cotangent once.
-                hcp = tp_copy(c(h), tx)
-                hr = tp_gather(hcp @ tp_slice(c(w1[:H]), tx), tx)
-                hc = tp_gather(hcp @ tp_slice(c(w1[H:2 * H]), tx), tx)
-                hr, hc = tp_copy(hr, tx), tp_copy(hc, tx)
-                kw = EdgeWeights(ws=tp_copy(w1[2 * H:], tx),
-                                 b1=tp_copy(b1, tx)[None],
-                                 w2=tp_copy(w2, tx), b2=tp_copy(b2, tx)[None],
-                                 w3=tp_slice(w3, tx), b3=tp_slice(b3, tx)[None],
-                                 w4=tp_slice(w4.T, tx))
-                xk = tp_copy(x, tx)
-            else:
-                hr = c(h) @ c(w1[:H])          # hoisted node-axis products
-                hc = c(h) @ c(w1[H:2 * H])     # (HoistedEdgeMLP algebra)
-                kw = EdgeWeights(ws=w1[2 * H:], b1=b1[None], w2=w2, b2=b2[None],
-                                 w3=w3, b3=b3[None], w4=w4.T)
-                xk = x
-            dname = "bf16" if dt is jnp.bfloat16 else "f32"
-            row_t, col_l, kblk, scal = fused_arrs
-            outs = [fused_edge_layer(xk[b], hr[b], hc[b], row_t[b], col_l[b],
-                                     kblk[b], scal[b], kw, g.edge_block, dname)
-                    for b in range(h.shape[0])]
-            trans_sum = jnp.stack([o[0] for o in outs])          # [B, N, 3]
-            count = jnp.stack([o[1] for o in outs])              # [B, N]
-            ef_sum = jnp.stack([o[2] for o in outs])             # [B, N, H]
 
-            # remote tail (~5-8% of E): identical math, dense over the
-            # compact out-of-window edge list carried on the batch. Under
-            # tensor parallelism it dispatches with the SAME weight slicing
-            # as the kernel so the combined trans_sum stays one partial.
-            if tx is not None:
-                cws, cb1 = tp_copy(c(w1[2 * H:]), tx), tp_copy(c(b1), tx)
-                cw2, cb2 = tp_copy(c(w2), tx), tp_copy(c(b2), tx)
-                cw3, cb3 = tp_slice(c(w3), tx), tp_slice(c(b3), tx)
-                w4r = tp_slice(w4.T, tx).T                       # [H/T, 1]
+        # --- real edge messages phi_e (:144-150) and the real-edge
+        # geometry (reference coord2radial, :237-246)
+        if not self.hoist_edge_mlp and self.tensor_axis is not None:
+            raise ValueError(
+                "tensor parallelism requires hoist_edge_mlp=True "
+                "(phi_e's collective is the node-level gather of the "
+                "hoisted products; the concat-shaped phi_e would "
+                "need a per-edge gather)")
+        with jax.named_scope("edge_mlp"):
+            if self.hoist_edge_mlp:
+                # coord_diff [B, E, 3] and radial [B, E, 1] come out of the
+                # hoisted products' own gathers: one pass per edge end
+                edge_feat, coord_diff, radial = HoistedEdgeMLP(
+                    H, 1 + self.edge_attr_nf, name="phi_e", dtype=dt,
+                    tensor_axis=self.tensor_axis)(
+                        h, x, g.edge_attr if self.edge_attr_nf else None, ops)
             else:
-                cws, cb1, cw2, cb2, cw3, cb3, w4r = (
-                    c(w1[2 * H:]), c(b1), c(w2), c(b2), c(w3), c(b3), w4)
-            rr, rc = g.remote_edge_index[:, 0], g.remote_edge_index[:, 1]
-            rm = g.remote_edge_mask[..., None]                   # [B, R, 1]
-            with jax.named_scope("edge_gather"):
-                x_r, x_c = gather_nodes(xk, rr), gather_nodes(xk, rc)
-                hr_r, hc_c = gather_nodes(hr, rr), gather_nodes(hc, rc)
-            cd_r = (x_r - x_c) * rm
-            radial_r = jnp.sum(cd_r * cd_r, axis=-1, keepdims=True)
-            with jax.named_scope("edge_mlp"):
-                sfeat = c(jnp.concatenate(
-                    [radial_r, g.remote_edge_attr[..., :2]], axis=-1))
-                t1 = hr_r + hc_c + sfeat @ cws + cb1
-                ef_r = nn.silu(nn.silu(t1) @ cw2 + cb2)          # [B, R, H]
-            with jax.named_scope("coord_update"):
-                y2 = nn.silu(ef_r @ cw3 + cb3)
-                g_r = (y2.astype(jnp.float32) @ w4r) * rm        # [B, R, 1]
-            N_ = x.shape[1]
-            seg = jax.vmap(
-                lambda val, r: jax.ops.segment_sum(val, r, num_segments=N_))
-            with jax.named_scope("edge_aggregate"):
-                trans_sum = trans_sum + seg(cd_r * g_r, rr)
-                count = count + seg(g.remote_edge_mask, rr)
-                ef_sum = ef_sum + seg(ef_r.astype(jnp.float32) * rm, rr)
-            if tx is not None:
-                # close phi_x with its ONE node-level psum; ef_sum/count were
-                # computed redundantly on every tensor rank — tp_once makes
-                # the tp_copy-psum'd input cotangents count them exactly once
-                trans_sum = tp_reduce(trans_sum, tx)
-                ef_sum = tp_once(ef_sum, tx)
-                count = tp_once(count, tx)
-
-            cnt = jnp.maximum(count, 1.0)[..., None]
-            agg = trans_sum / cnt if self.coords_agg == "mean" else trans_sum
-            agg_h_f = ef_sum / cnt
-        else:
-            # --- real edge messages phi_e (:144-150) and the real-edge
-            # geometry (reference coord2radial, :237-246)
-            if not self.hoist_edge_mlp and self.tensor_axis is not None:
-                raise ValueError(
-                    "tensor parallelism requires hoist_edge_mlp=True "
-                    "(phi_e's collective is the node-level gather of the "
-                    "hoisted products; the concat-shaped phi_e would "
-                    "need a per-edge gather)")
-            with jax.named_scope("edge_mlp"):
-                if self.hoist_edge_mlp:
-                    # coord_diff [B, E, 3] and radial [B, E, 1] come out of the
-                    # hoisted products' own gathers: one pass per edge end
-                    edge_feat, coord_diff, radial = HoistedEdgeMLP(
-                        H, 1 + self.edge_attr_nf, name="phi_e", dtype=dt,
-                        tensor_axis=self.tensor_axis)(
-                            h, x, g.edge_attr if self.edge_attr_nf else None, ops)
-                else:
-                    coord_diff = ops.gather_rows(x) - ops.gather_cols(x)
-                    radial = jnp.sum(coord_diff**2, axis=-1, keepdims=True)
-                    e_in = [ops.gather_rows(h), ops.gather_cols(h), radial]
-                    if self.edge_attr_nf:
-                        e_in.append(g.edge_attr)
-                    edge_feat = MLP([H, H], act_last=True, name="phi_e", dtype=dt)(
-                        jnp.concatenate(e_in, axis=-1))
-                if self.attention:
-                    gate_e = jax.nn.sigmoid(TorchDense(1, name="att", dtype=dt)(edge_feat))
-                    edge_feat = edge_feat * gate_e                       # [B, E, H]
-                edge_feat = edge_feat * edge_mask[..., None].astype(edge_feat.dtype)
-            if self.normalize:
-                norm = jax.lax.stop_gradient(jnp.sqrt(radial)) + self.epsilon
-                coord_diff = coord_diff / norm
+                coord_diff = ops.gather_rows(x) - ops.gather_cols(x)
+                radial = jnp.sum(coord_diff**2, axis=-1, keepdims=True)
+                e_in = [ops.gather_rows(h), ops.gather_cols(h), radial]
+                if self.edge_attr_nf:
+                    e_in.append(g.edge_attr)
+                edge_feat = MLP([H, H], act_last=True, name="phi_e", dtype=dt)(
+                    jnp.concatenate(e_in, axis=-1))
+            if self.attention:
+                gate_e = jax.nn.sigmoid(TorchDense(1, name="att", dtype=dt)(edge_feat))
+                edge_feat = edge_feat * gate_e                       # [B, E, H]
+            edge_feat = edge_feat * edge_mask[..., None].astype(edge_feat.dtype)
+        if self.normalize:
+            norm = jax.lax.stop_gradient(jnp.sqrt(radial)) + self.epsilon
+            coord_diff = coord_diff / norm
 
         # --- virtual-edge geometry (:252-253): every node sees all C virtual nodes
         with jax.named_scope("virtual_update"):
@@ -426,31 +187,29 @@ class EGCLVel(nn.Module):
                 vef = vef * gate
             vef = vef * node_mask[:, :, None, None].astype(vef.dtype)    # zero padded nodes
 
-        # --- real coordinate update (coord_model_vel, :166-188); the fused
-        # path already holds the aggregated translations in `agg`
-        if not fused:
-            # tensor-parallel phi_x returns a rank-local PARTIAL scalar; it
-            # rides coord_diff and the row aggregation (all linear) to the
-            # node axis, where ONE psum of [B, N, 3] closes the MLP —
-            # per-edge traffic never crosses the tensor axis. coord_diff is
-            # tp_copy-wrapped so its cotangent (partial per rank) is summed.
-            with jax.named_scope("coord_update"):
-                cdm = (tp_copy(coord_diff, self.tensor_axis)
-                       if self.tensor_axis is not None else coord_diff)
-                trans = cdm * CoordMLP(H, tanh=self.tanh, name="phi_x", dtype=dt,
-                                       tensor_axis=self.tensor_axis)(edge_feat)  # [B, E, 3]
-            if self.fuse_agg:
-                # both per-layer aggregations (+ the count) in ONE pass (blocked
-                # layouts keep two calls inside but honor the agg_dtype knob)
-                agg, agg_h_f = ops.agg_rows_pair(
-                    trans, edge_feat, a_mean=(self.coords_agg == "mean"),
-                    agg_dtype=self.agg_dtype)
-            else:
-                agg = (ops.agg_rows_sum(trans) if self.coords_agg == "sum"
-                       else ops.agg_rows_mean(trans))                    # [B, N, 3]
-                agg_h_f = None
-            if self.tensor_axis is not None:
-                agg = tp_reduce(agg, self.tensor_axis)
+        # --- real coordinate update (coord_model_vel, :166-188).
+        # tensor-parallel phi_x returns a rank-local PARTIAL scalar; it
+        # rides coord_diff and the row aggregation (all linear) to the
+        # node axis, where ONE psum of [B, N, 3] closes the MLP —
+        # per-edge traffic never crosses the tensor axis. coord_diff is
+        # tp_copy-wrapped so its cotangent (partial per rank) is summed.
+        with jax.named_scope("coord_update"):
+            cdm = (tp_copy(coord_diff, self.tensor_axis)
+                   if self.tensor_axis is not None else coord_diff)
+            trans = cdm * CoordMLP(H, tanh=self.tanh, name="phi_x", dtype=dt,
+                                   tensor_axis=self.tensor_axis)(edge_feat)  # [B, E, 3]
+        if self.fuse_agg:
+            # both per-layer aggregations (+ the count) in ONE pass (blocked
+            # layouts keep two calls inside but honor the agg_dtype knob)
+            agg, agg_h_f = ops.agg_rows_pair(
+                trans, edge_feat, a_mean=(self.coords_agg == "mean"),
+                agg_dtype=self.agg_dtype)
+        else:
+            agg = (ops.agg_rows_sum(trans) if self.coords_agg == "sum"
+                   else ops.agg_rows_mean(trans))                    # [B, N, 3]
+            agg_h_f = None
+        if self.tensor_axis is not None:
+            agg = tp_reduce(agg, self.tensor_axis)
         with jax.named_scope("coord_update"):
             x = x + agg
 
@@ -592,24 +351,6 @@ class FastEGNN(nn.Module):
     remat: bool = False
     fuse_agg: bool = True          # packed per-layer aggregation (EGCLVel)
     agg_dtype: Optional[str] = None  # 'bf16' packed-aggregation stream (EGCLVel)
-    # real-edge lowering (EGCLVel): 'plain', 'fused' (single Pallas pass
-    # per layer over the blocked in-window edges, ops/edge_pipeline), or
-    # 'fused_stack' (ONE Pallas megakernel running all n_layers with the
-    # blocked edge stream VMEM-resident, ops/layer_pipeline — same
-    # constraints as 'fused' plus the whole graph must fit the VMEM budget;
-    # raises layer_pipeline.StackVmemBudgetError otherwise). 'fused' and
-    # 'fused_stack' require a blocked batch (edge_block >= 512, multiple of
-    # 512, N >= 3 blocks) built with split_remote=True, and
-    # edge_attr_nf == 2. 'fused' <-> 'fused_stack' share the param tree
-    # bitwise (checkpoints interchangeable); 'plain' does not. Under a
-    # graph/tensor mesh 'fused_stack' falls back to the per-layer fused
-    # path (identical math and tree): the layer-boundary collectives cannot
-    # cross a Pallas grid — the megakernel is the single-chip lowering that
-    # serving replicas and single-host training use.
-    edge_impl: str = "plain"
-    # optional VMEM budget override (bytes) for the fused_stack residency
-    # guard; 0 = layer_pipeline.DEFAULT_STACK_VMEM_BUDGET (16 MiB/core)
-    stack_vmem_budget: int = 0
 
     @nn.compact
     def __call__(self, g: GraphBatch) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -632,59 +373,12 @@ class FastEGNN(nn.Module):
         # shared by all layers
         slot, inv_deg, oh = blocked_slot_inv_deg(g, self.blocked_impl)
 
-        # fused edge pipeline: the kernel's blocked HBM layout of the edge
-        # stream is layer-invariant too — build it once per forward
-        fused_arrs = None
-        if self.edge_impl in ("fused", "fused_stack"):
-            if g.edge_block <= 0:
-                raise ValueError(
-                    f"edge_impl='{self.edge_impl}' requires a blocked batch "
-                    "(data.edge_block >= 512, a multiple of 512)")
-            fused_arrs = jax.vmap(
-                lambda r, c, ea, em: build_edge_blocks(
-                    r, c, ea, em, block=g.edge_block, n_nodes=g.max_nodes)
-            )(g.row, g.col, g.edge_attr, g.edge_mask)
-
-        if self.edge_impl == "fused_stack":
-            # megakernel constraints, hoisted to the model because the
-            # megakernel bypasses EGCLVel entirely (mirrors its fused checks)
-            if self.attention or self.normalize or self.tanh:
-                raise ValueError(
-                    "edge_impl='fused_stack' supports the flagship EGCL "
-                    "only: attention/normalize/tanh are baked out of the "
-                    "megakernel — use edge_impl='plain' with those heads")
-            if self.edge_attr_nf != 2:
-                raise ValueError(
-                    f"edge_impl='fused_stack' requires edge_attr_nf=2 (the "
-                    f"kernel scalar lanes are [radial, attr0, attr1]); got "
-                    f"{self.edge_attr_nf}")
-            if self.n_layers < 1:
-                raise ValueError(
-                    f"edge_impl='fused_stack' needs n_layers >= 1 (the "
-                    f"megakernel grid is (n_layers,)); got {self.n_layers}")
-            if g.remote_edge_index is None:
-                raise ValueError(
-                    "edge_impl='fused_stack' needs a blocked batch built "
-                    "with split_remote=True (the megakernel folds the "
-                    "compact remote tail in per layer) — check "
-                    "data.edge_block and the loader's split_remote flag")
-
-        if (self.edge_impl == "fused_stack" and self.axis_name is None
-                and self.tensor_axis is None):
-            return self._fused_stack_forward(g, h, x, v, X, Hv, gravity,
-                                             fused_arrs)
-
         layer_cls = EGCLVel
         if self.remat:
             layer_cls = nn.remat(EGCLVel, policy=(
                 jax.checkpoint_policies.save_only_these_names(*REMAT_KEPT)))
         obs.get_registry().gauge("model/remat_saved_bytes").set(
             self._remat_saved_bytes(g))
-        # under a graph/tensor mesh fused_stack lowers to the per-layer
-        # fused path: collectives cannot cross the megakernel's Pallas grid,
-        # and the param tree is identical so the fallback is exact
-        layer_impl = ("fused" if self.edge_impl == "fused_stack"
-                      else self.edge_impl)
         for i in range(self.n_layers):
             h, x, Hv, X = layer_cls(
                 hidden_nf=H,
@@ -703,10 +397,9 @@ class FastEGNN(nn.Module):
                 seg_impl=self.segment_impl,
                 fuse_agg=self.fuse_agg,
                 agg_dtype=self.agg_dtype,
-                edge_impl=layer_impl,
                 name=f"gcl_{i}",
             )(h, x, v, X, Hv, g, gravity=gravity, slot=slot, inv_deg=inv_deg,
-              oh=oh, fused_arrs=fused_arrs)
+              oh=oh)
 
         return x, X
 
@@ -716,9 +409,8 @@ class FastEGNN(nn.Module):
         keeps ``[B, E, H]`` in the compute dtype and ``f32[B, E, 3]`` where
         phi_e is hoisted, and the packed ``f32[B, N, 3+H+1]`` segment sum
         where the aggregation is fused (a blocked batch makes two sums and
-        no count column). 0 without remat, and on the fused edge paths, which
-        name nothing."""
-        if not self.remat or self.edge_impl != "plain":
+        no count column). 0 without remat."""
+        if not self.remat:
             return 0
         B, E = g.row.shape
         H = self.hidden_nf
@@ -727,41 +419,3 @@ class FastEGNN(nn.Module):
         count_column = 0 if g.edge_block > 0 else 1
         per_node = 4 * (3 + H + count_column) if self.fuse_agg else 0
         return self.n_layers * B * (E * per_edge + g.max_nodes * per_node)
-
-    def _fused_stack_forward(self, g: GraphBatch, h, x, v, X, Hv, gravity,
-                             fused_arrs):
-        """Dispatch the whole layer loop as ONE megakernel per graph.
-
-        Params are declared through the _EGCLVelStackParams shadows (same
-        ``gcl_{i}/...`` subtree as the per-layer path, bitwise-identical
-        init) and stacked along a leading layer axis at runtime; the
-        blocked edge stream is read from HBM once for all n_layers."""
-        H, C, B = self.hidden_nf, self.virtual_channels, g.batch_size
-        dt = resolve_dtype(self.compute_dtype)
-        cfg = StackConfig(
-            n_layers=self.n_layers, block=g.edge_block, hidden=H, channels=C,
-            node_attr_nf=self.node_attr_nf,
-            has_gravity=self.gravity is not None, residual=self.residual,
-            coords_mean=True,  # FastEGNN always aggregates with 'mean'
-            dtype_name="bf16" if dt is jnp.bfloat16 else "f32",
-            vmem_budget=self.stack_vmem_budget or DEFAULT_STACK_VMEM_BUDGET)
-        wlayers = [
-            _EGCLVelStackParams(H, C, self.node_attr_nf, self.edge_attr_nf,
-                                self.gravity is not None, name=f"gcl_{i}")()
-            for i in range(self.n_layers)]
-        wstack = {k: jnp.stack([wl[k] for wl in wlayers])
-                  for k in wlayers[0]}
-        row_t, col_l, kblk, scal = fused_arrs
-        xs, Xs = [], []
-        for b in range(B):
-            edge_arrs = (row_t[b], col_l[b], kblk[b], scal[b])
-            remote_arrs = (g.remote_edge_index[b, 0],
-                           g.remote_edge_index[b, 1],
-                           g.remote_edge_attr[b], g.remote_edge_mask[b])
-            _, x_b, X_b, _ = fused_egnn_stack(
-                cfg, h[b], x[b], v[b], X[b], Hv[b], g.node_mask[b],
-                g.node_attr[b] if self.node_attr_nf else None, gravity,
-                edge_arrs, remote_arrs, wstack)
-            xs.append(x_b)
-            Xs.append(X_b)
-        return jnp.stack(xs), jnp.stack(Xs)

@@ -62,9 +62,6 @@ def get_model(model_config, world_size: int = 1, dataset_name: Optional[str] = N
             segment_impl=model_config.get("segment_impl", "scatter"),
             fuse_agg=bool(model_config.get("fuse_agg", True)),
             agg_dtype=model_config.get("agg_dtype"),
-            edge_impl=model_config.get("edge_impl", "plain"),
-            stack_vmem_budget=int(
-                model_config.get("stack_vmem_budget", 0) or 0),
         )
     if name == "FastRF":
         FastRF = _import_model("fast_rf", "FastRF")

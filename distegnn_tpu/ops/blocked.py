@@ -144,10 +144,10 @@ def repack_blocked(edge_index: np.ndarray, loc: Optional[np.ndarray] = None,
     one vectorized NumPy pass (sort-by-(block, row), no per-block loop).
 
     When ``loc`` is given the node ids are first relabeled along the Z-order
-    curve (ops/order.py) so spatially-near nodes share blocks — the layout
-    the fused kernel's locality analysis assumes. Returns a :class:`RepackPlan`
-    whose ``src``/``dst`` index maps let position-dependent payloads
-    (edge_attr) be re-laid later without redoing the sort.
+    curve (ops/order.py) so spatially-near nodes share blocks. Returns a
+    :class:`RepackPlan` whose ``src``/``dst`` index maps let
+    position-dependent payloads (edge_attr) be re-laid later without redoing
+    the sort.
     """
     ei = np.asarray(edge_index).astype(np.int64, copy=False)
     perm = None
@@ -224,7 +224,7 @@ def prepare_blocked_graph(g: dict, n_nodes_padded: int, epb: int, block: int,
         g["edge_index"] = g["edge_index"][:, keep]
         if g.get("edge_attr") is not None:
             g["edge_attr"] = g["edge_attr"][keep]
-        for k in ("_edge_pair", "_edge_mask", "_blockified", "_remote_sel"):
+        for k in ("_edge_pair", "_edge_mask", "_blockified"):
             g.pop(k, None)
     if np.any(np.diff(g["edge_index"][0]) < 0):
         order = np.argsort(g["edge_index"][0], kind="stable")

@@ -64,15 +64,6 @@ class GraphBatch:
     # col-aggregations ride the MXU kernels (blocked layout, ops/blocked.py)
     # or the scatter-free cumsum path (plain sorted layout, ops/segment.py)
     edge_pair: Optional[jnp.ndarray] = None
-    # Compact out-of-window edge list for the fused edge pipeline
-    # (ops/edge_pipeline.split_remote_edges): [B, 2, R] int32 / [B, R, D] /
-    # [B, R] 0-1. Padding points at node 0 with mask 0. Present only when the
-    # batch was built with pad_graphs(split_remote=True); models with
-    # edge_impl='fused' route these ~5-8% of edges through the plain EdgeOps
-    # path and sum them with the in-window kernel accumulators.
-    remote_edge_index: Optional[jnp.ndarray] = None
-    remote_edge_attr: Optional[jnp.ndarray] = None
-    remote_edge_mask: Optional[jnp.ndarray] = None
     edges_sorted: bool = struct.field(pytree_node=False, default=False)
     edge_block: int = struct.field(pytree_node=False, default=0)
     edge_tile: int = struct.field(pytree_node=False, default=0)
@@ -130,8 +121,6 @@ def pad_graphs(
     edge_tile: int = 512,
     compute_pair: Optional[bool] = None,
     max_in_degree: Optional[int] = None,
-    split_remote: bool = False,
-    remote_pad: Optional[int] = None,
 ) -> "GraphBatch":
     """Pack a list of per-graph numpy dicts into one padded GraphBatch.
 
@@ -157,22 +146,11 @@ def pad_graphs(
     historical layouts: on for blocked batches, off for plain ones (the plain
     pairing only pays off with ``segment_impl='cumsum'``; loaders switch it on
     dataset-stably so every batch shares one pytree structure).
-
-    ``split_remote`` (blocked layouts only) — additionally extract the edges
-    whose sender falls OUTSIDE the fused kernel's 3-block VMEM window into the
-    compact ``remote_edge_*`` arrays (ops/edge_pipeline.split_remote_edges),
-    padded to ``remote_pad`` slots (auto: batch max rounded to 128; loaders
-    pass a dataset-stable value so every batch shares one pytree structure).
-    Required by models running ``edge_impl='fused'``.
     """
     bsz = len(graphs)
     n_max = max(g["loc"].shape[0] for g in graphs)
     if compute_pair is None:
         compute_pair = edge_block > 0
-    if split_remote and not edge_block:
-        raise ValueError("pad_graphs: split_remote requires edge_block > 0 "
-                         "(the remote/in-window partition is defined by the "
-                         "blocked layout)")
     if edge_block:
         from distegnn_tpu.ops.blocked import (max_block_degree,
                                               prepare_blocked_graph)
@@ -200,41 +178,7 @@ def pad_graphs(
         edge_pair = (np.stack(pairs).astype(np.int32)
                      if all(p is not None for p in pairs) else None)
         E = (N // edge_block) * edges_per_block
-        if split_remote:
-            from distegnn_tpu.ops.edge_pipeline import (pad_remote_list,
-                                                        split_remote_edges)
-
-            # classify on each graph's REAL blockified edges (padding slots
-            # carry row == col inside their own block — always in-window —
-            # so filtering by mask only removes never-remote slots)
-            outs = []
-            for g in graphs:
-                sel = g.get("_remote_sel")
-                if (sel is not None
-                        and g.get("_blockified") == (N, edges_per_block,
-                                                     edge_block)):
-                    # session-cached selection: the classify+sort was done at
-                    # prep time; a gather of the current arrays suffices
-                    outs.append(pad_remote_list(
-                        g["edge_index"][:, sel], g["edge_attr"][sel],
-                        n_pad=remote_pad))
-                    continue
-                keep = g["_edge_mask"] > 0
-                outs.append(split_remote_edges(
-                    g["edge_index"][:, keep], g["edge_attr"][keep],
-                    block=edge_block, n_nodes=N, n_pad=remote_pad))
-            R = max(o[0].shape[1] for o in outs)
-            rei = np.zeros((bsz, 2, R), np.int32)
-            rea = np.zeros((bsz, R, outs[0][1].shape[1]), dtype)
-            rem = np.zeros((bsz, R), dtype)
-            for b, (ei_r, ea_r, m_r) in enumerate(outs):
-                r = ei_r.shape[1]
-                rei[b, :, :r], rea[b, :r], rem[b, :r] = ei_r, ea_r, m_r
-            remote = (rei, rea, rem)
-        else:
-            remote = None
     else:
-        remote = None
         e_max = max(g["edge_index"].shape[1] for g in graphs)
         E = max_edges if max_edges is not None else _round_up(max(e_max, 1), edge_bucket)
         N = max_nodes if max_nodes is not None else _round_up(max(n_max, 1), node_bucket)
@@ -331,9 +275,6 @@ def pad_graphs(
         edge_attr=edge_attr, edge_mask=edge_mask, edges_sorted=edges_sorted,
         edge_block=edge_block, edge_tile=edge_tile if edge_block else 0,
         edge_pair=edge_pair, max_in_degree=max_in_degree,
-        remote_edge_index=remote[0] if remote else None,
-        remote_edge_attr=remote[1] if remote else None,
-        remote_edge_mask=remote[2] if remote else None,
     )
 
 

@@ -32,14 +32,9 @@ from distegnn_tpu.data.partition import node_work
 from distegnn_tpu.ops.order import morton_perm
 
 
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
-
-
-def shape_rung(size: int, floor: int, growth: float = 2.0,
-               multiple: int = 1) -> int:
-    """Smallest ``floor * growth^k`` (rounded up to ``multiple``) admitting
-    ``size`` — the scene-independent quantizer for every free tile axis.
+def shape_rung(size: int, floor: int, growth: float = 2.0) -> int:
+    """Smallest ``floor * growth^k`` admitting ``size`` — the
+    scene-independent quantizer for every free tile axis.
     Mirrors BucketLadder._rung without a cap: tiles never reject, they are
     the path requests land on AFTER the ladder cap rejected them."""
     size = max(int(size), 1)
@@ -47,8 +42,7 @@ def shape_rung(size: int, floor: int, growth: float = 2.0,
     k = max(0, math.ceil(math.log(size / floor, growth)))
     while floor * growth ** k < size:   # float-log fixup on exact powers
         k += 1
-    r = int(math.ceil(floor * growth ** k))
-    return _round_up(r, max(int(multiple), 1))
+    return int(math.ceil(floor * growth ** k))
 
 
 class TileSpec(NamedTuple):
@@ -80,11 +74,7 @@ class TilePlan(NamedTuple):
     tiles: Tuple[TileSpec, ...]
     tile_nodes: int            # own-node slots per tile (halo local base)
     halo_pad: int              # rung-quantized halo slots (common to tiles)
-    edge_pad: int              # rung-quantized edge slots (plain layout)
-    edge_block: int            # 0 = plain layout
-    edge_tile: int             # blocked layouts: epb rounding quantum
-    edges_per_block: int       # blocked layouts: pinned epb (0 when plain)
-    remote_pad: int            # blocked layouts: pinned remote width
+    edge_pad: int              # rung-quantized edge slots
     halo_total: int            # sum of per-tile halo counts
     work_imbalance: float      # max/mean per-tile work under the node_work model
 
@@ -95,11 +85,7 @@ class TilePlan(NamedTuple):
     @property
     def padded_nodes(self) -> int:
         """Per-tile padded node count — THE compiled node axis."""
-        n = self.tile_nodes + self.halo_pad
-        if self.edge_block:
-            # fused kernel wants a block multiple and a full 3-block window
-            n = max(_round_up(n, self.edge_block), 3 * self.edge_block)
-        return n
+        return self.tile_nodes + self.halo_pad
 
     @property
     def halo_fraction(self) -> float:
@@ -110,8 +96,7 @@ class TilePlan(NamedTuple):
     @property
     def shape_key(self) -> tuple:
         """The compile-cache key axes: equal keys => one shared executable."""
-        return (self.padded_nodes, self.edge_pad, self.edge_block,
-                self.edges_per_block, self.remote_pad)
+        return (self.padded_nodes, self.edge_pad)
 
 
 class RoundSchedule(NamedTuple):
@@ -180,7 +165,6 @@ def plan_tiles(edge_index: np.ndarray, loc: np.ndarray,
                edge_attr: Optional[np.ndarray] = None, *,
                tile_nodes: int = 65536, halo_floor: int = 1024,
                edge_floor: int = 8192, growth: float = 2.0,
-               edge_block: int = 0, edge_tile: int = 512,
                bits: int = 16, work_node_cost: float = 1.0,
                work_edge_cost: float = 1.0) -> TilePlan:
     """Compute a work-balanced Morton tile plan for one scene.
@@ -188,10 +172,7 @@ def plan_tiles(edge_index: np.ndarray, loc: np.ndarray,
     ``edge_index`` [2, E] (row=receiver, col=sender) and ``loc`` [n, 3] are
     the scene's ORIGINAL node ids; the plan carries the Morton relabel
     (``perm``/``inv_perm``) and every tile's edges in tile-local ids, so the
-    executor only gathers. ``edge_block > 0`` plans for the blocked/fused
-    layout and pins ``edges_per_block`` and the remote width across tiles —
-    pad_graphs must not re-derive them per tile or every tile would compile
-    its own program.
+    executor only gathers.
     """
     loc = np.asarray(loc)
     edge_index = np.asarray(edge_index)
@@ -258,24 +239,7 @@ def plan_tiles(edge_index: np.ndarray, loc: np.ndarray,
     tw = np.asarray(tile_work, np.float64)
     imbalance = float(tw.max() / max(tw.mean(), 1e-30))
 
-    epb = rpad = 0
-    if edge_block:
-        from distegnn_tpu.ops.blocked import max_block_degree
-        from distegnn_tpu.ops.edge_pipeline import count_remote_edges
-
-        padded = max(_round_up(tile_nodes + halo_pad, edge_block),
-                     3 * edge_block)
-        deg = max(max_block_degree(t.edge_index[0], padded, edge_block)
-                  for t in tiles)
-        epb = shape_rung(max(deg, 1), edge_tile, growth, multiple=edge_tile)
-        rmax = max(count_remote_edges(t.edge_index, block=edge_block,
-                                      n_nodes=padded) for t in tiles)
-        rpad = shape_rung(max(rmax, 1), 128, growth, multiple=128)
-        edge_pad = 0    # blocked layouts size edges via epb, not edge_pad
-
     return TilePlan(n_nodes=n, n_edges=e_total, perm=perm, inv_perm=inv_perm,
                     tiles=tuple(tiles), tile_nodes=tile_nodes,
                     halo_pad=halo_pad, edge_pad=edge_pad,
-                    edge_block=int(edge_block), edge_tile=int(edge_tile),
-                    edges_per_block=int(epb), remote_pad=int(rpad),
                     halo_total=halo_total, work_imbalance=imbalance)

@@ -158,29 +158,6 @@ def tp_slice(x, axis_name: Optional[str] = None):
     return _slice(x)
 
 
-def tp_once(x, axis_name: Optional[str] = None):
-    """Identity forward; divides the cotangent by T. Zero communication.
-
-    For values computed redundantly (bitwise-identically) on every tensor rank
-    from replicated inputs — e.g. the fused kernel's ef_sum/count outputs,
-    which come from the replicated phi_e weights while the same kernel call's
-    trans_sum output is a per-rank partial. Inputs feeding such a kernel are
-    wrapped in tp_copy (bwd psum), which would count the replicated outputs'
-    cotangent T times; tp_once pre-divides so the psum counts it exactly once.
-    Exact (not just approximate) when T is a power of two.
-    """
-    if axis_name is None:
-        return x
-    t = jax.lax.psum(1, axis_name)
-
-    @jax.custom_vjp
-    def _once(v):
-        return v
-
-    _once.defvjp(lambda v: (v, None), lambda _, g: (jax.tree.map(lambda a: a / t, g),))
-    return _once(x)
-
-
 def tp_slice_rows(x, axis_name: Optional[str] = None):
     """Row-block analogue of tp_slice: 1/T slice of axis 0 (row-parallel W2)."""
     if axis_name is None:

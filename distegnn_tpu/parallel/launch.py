@@ -243,8 +243,6 @@ def run_distributed(config):
             datasets, d.batch_size, shuffle=(split_idx == 0), seed=config.seed,
             node_bucket=d.node_bucket, edge_bucket=d.edge_bucket,
             data_parallel=dp, edge_block=d.edge_block,
-            split_remote=(config.model.get("edge_impl")
-                          in ("fused", "fused_stack")),
             # cumsum aggregation wants the reverse-edge pairing attached to
             # plain batches (scatter-free col-gather backward, ops/segment.py)
             pairing=(True if (not d.edge_block and

@@ -1,7 +1,7 @@
 """What this process runs on: the device, whether Pallas kernels compile or
 interpret, and where XLA's persistent compile cache lives.
 
-Every entry point (main.py, scripts/serve_gateway.py and bench.py under
+Every entry point (main.py and scripts/serve_gateway.py under
 ``__main__``, the serving worker child, chip_smoke.py) calls
 :func:`configure_compile_cache` first and reports :func:`device_summary`, so a run that JAX quietly dropped to the
 CPU says so in its first line instead of in its timings.

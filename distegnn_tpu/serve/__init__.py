@@ -62,17 +62,10 @@ def engine_from_config(cfg, model, params, metrics=None):
         node_multiple=s.node_multiple, edge_multiple=s.edge_multiple,
         max_nodes=s.max_nodes, max_edges=s.max_edges)
     metrics = metrics or ServeMetrics()
-    layout = None
-    if cfg.get("model") and cfg.model.get("edge_impl") in ("fused",
-                                                             "fused_stack"):
-        # fused/fused_stack models only consume blocked split_remote batches
-        layout = dict(edge_block=int(cfg.data.edge_block),
-                      split_remote=True)
     engine = InferenceEngine(
         model, params, ladder=ladder, max_batch=s.max_batch,
         cache_size=s.cache_size, donate=s.donate, metrics=metrics,
         rollout_opts=(s.rollout.to_dict() if s.get("rollout") else None),
-        layout_opts=layout,
         session_cache=int(s.get("session_cache", 0) or 0),
         session_cache_bytes=int(s.get("session_cache_bytes", 0) or 0),
         tiled=(s.tiled.to_dict() if s.get("tiled")

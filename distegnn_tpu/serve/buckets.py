@@ -129,8 +129,8 @@ class BucketLadder:
 
     # ---- padding ---------------------------------------------------------
     def pad_batch(self, graphs: Sequence[dict], bucket: Bucket,
-                  batch_pad: int, *, edge_block: int = 0, edge_tile: int = 512,
-                  split_remote: bool = False) -> Tuple[GraphBatch, int]:
+                  batch_pad: int, *, edge_block: int = 0,
+                  edge_tile: int = 512) -> Tuple[GraphBatch, int]:
         """Pack ``graphs`` (all admitted by ``bucket``) into one GraphBatch
         of EXACTLY (batch_pad, bucket.n, bucket.e).
 
@@ -138,12 +138,11 @@ class BucketLadder:
         are valid graphs (no NaN hazards from empty-graph means) and their
         outputs are simply discarded; returns (batch, n_real).
 
-        ``edge_block > 0`` emits the BLOCKED layout instead (the fused edge
-        pipeline's input; ``split_remote`` adds the compact out-of-window
-        list). Node count snaps up from bucket.n to a block multiple;
-        edges_per_block and the remote width auto-derive per batch — a
-        serving layer has no dataset to scan, so the ENGINE keys its compile
-        cache on the resulting batch shapes rather than on the rung alone.
+        ``edge_block > 0`` emits the BLOCKED layout instead. Node count snaps
+        up from bucket.n to a block multiple; edges_per_block auto-derives
+        per batch — a serving layer has no dataset to scan, so the ENGINE
+        keys its compile cache on the resulting batch shapes rather than on
+        the rung alone.
         """
         n_real = len(graphs)
         if n_real == 0:
@@ -153,11 +152,9 @@ class BucketLadder:
         filled = list(graphs) + [graphs[0]] * (batch_pad - n_real)
         if edge_block:
             nb = (bucket.n + edge_block - 1) // edge_block
-            if split_remote:
-                nb = max(nb, 3)  # fused kernel's VMEM window spans 3 blocks
             batch = pad_graphs(filled, max_nodes=nb * edge_block,
                                edge_block=edge_block, edge_tile=edge_tile,
-                               compute_pair=False, split_remote=split_remote)
+                               compute_pair=False)
         else:
             batch = pad_graphs(filled, max_nodes=bucket.n, max_edges=bucket.e,
                                node_bucket=1, edge_bucket=1)

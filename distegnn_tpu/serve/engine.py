@@ -80,9 +80,8 @@ class InferenceEngine:
       rollout_opts: kwargs forwarded to make_rollout_fn (radius, max_degree,
         max_per_cell, edge_block, ...) — required for ``rollout``.
       layout_opts: kwargs forwarded to ``ladder.pad_batch`` (edge_block,
-        edge_tile, split_remote) — a model with ``edge_impl='fused'`` needs
-        ``{'edge_block': 512, 'split_remote': True}`` so every served batch
-        carries the blocked layout + remote tail.
+        edge_tile): ``{'edge_block': 256}`` serves every batch in the
+        blocked layout.
       session_cache: capacity of the session-affinity prep cache
         (serve/prep.py) exposed as ``engine.prep_cache``; 0 (default)
         disables it.
@@ -139,13 +138,6 @@ class InferenceEngine:
         if donate == "auto":
             donate = jax.default_backend() == "tpu"
         self._donate = bool(donate)
-        # the executable's identity includes the model's edge path and, for
-        # fused_stack, the stack depth: one multi-layer kernel per (rung, L).
-        # A blue/green swap to a different depth must not reuse the old one.
-        _impl = str(getattr(model, "edge_impl", "plain") or "plain")
-        self._stack_key: Tuple = (
-            _impl, int(getattr(model, "n_layers", 0) or 0)
-            if _impl == "fused_stack" else 0)
         self._cache: "OrderedDict[Tuple, Callable]" = OrderedDict()
         # one lock for the cache; device execution itself is serialized by
         # the runtime, and the batcher calls from a single dispatch thread
@@ -204,14 +196,11 @@ class InferenceEngine:
         batch, n_real = self.ladder.pad_batch(graphs, bucket, self.max_batch,
                                               **self._layout_opts)
         # key on the RESULTING shapes, not the rung: blocked layouts derive
-        # edges_per_block / remote width per batch, and two rungs that pad to
-        # the same shapes may share one executable (plain layout keys reduce
-        # to the old (bucket.n, bucket.e, max_batch) triple)
-        rpad = (batch.remote_edge_mask.shape[-1]
-                if batch.remote_edge_mask is not None else 0)
+        # edges_per_block per batch, and two rungs that pad to the same
+        # shapes may share one executable (plain layout keys reduce to the
+        # (bucket.n, bucket.e, max_batch) triple)
         fn = self._compiled(("predict", batch.max_nodes, batch.max_edges,
-                             batch.edge_block, rpad, self.max_batch)
-                            + self._stack_key,
+                             batch.edge_block, self.max_batch),
                             lambda: self._build_predict(bucket))
         with obs.span("serve/execute", n=batch.max_nodes, e=batch.max_edges,
                       filled=n_real, capacity=self.max_batch,
@@ -322,11 +311,8 @@ class InferenceEngine:
         for b in buckets or [self.ladder.bucket_of_graph(g)]:
             batch, _ = self.ladder.pad_batch([g], b, self.max_batch,
                                              **self._layout_opts)
-            rpad = (batch.remote_edge_mask.shape[-1]
-                    if batch.remote_edge_mask is not None else 0)
             fn = self._compiled(("predict", batch.max_nodes, batch.max_edges,
-                                 batch.edge_block, rpad, self.max_batch)
-                                + self._stack_key,
+                                 batch.edge_block, self.max_batch),
                                 lambda: self._build_predict(b))
             out = np.asarray(fn(params, batch))
             if out.shape != (self.max_batch, batch.max_nodes, 3):
@@ -396,8 +382,7 @@ class InferenceEngine:
             ro = make_rollout_fn(self.model, **opts)
             return jax.jit(functools.partial(ro, steps=int(steps)))
 
-        fn = self._compiled(("rollout", n_pad, int(steps)) + self._stack_key,
-                            build)
+        fn = self._compiled(("rollout", n_pad, int(steps)), build)
         traj, over = fn(self.params, jnp.asarray(loc_p), jnp.asarray(vel_p),
                         jnp.asarray(mask))
         if bool(np.asarray(over).any()):
@@ -455,8 +440,7 @@ class InferenceEngine:
             ro = make_batched_rollout_fn(self.model, **opts)
             return jax.jit(functools.partial(ro, steps=steps))
 
-        fn = self._compiled(("rollout_batch", n_pad, steps, B)
-                            + self._stack_key, build)
+        fn = self._compiled(("rollout_batch", n_pad, steps, B), build)
         with obs.span("serve/execute", n=n_pad, e=0, filled=len(scenes),
                       capacity=B, workload="rollout", steps=steps,
                       **_rid_attrs(request_ids)):
@@ -517,8 +501,7 @@ class InferenceEngine:
                 ro = make_rollout_fn(self.model, **opts)
                 return jax.jit(functools.partial(ro, steps=_c))
 
-            fn = self._compiled(("rollout", n_pad, c) + self._stack_key,
-                                build)
+            fn = self._compiled(("rollout", n_pad, c), build)
             with obs.span("serve/execute", n=n_pad, e=0, filled=1,
                           capacity=1, workload="rollout_stream", steps=c,
                           **_rid_attrs([request_id])):

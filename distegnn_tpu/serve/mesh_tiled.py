@@ -95,7 +95,7 @@ def _round_executable(ex, plan: TilePlan, devices) -> Callable:
         return h2, x2, tx, vf, ct
 
     key = ("tile_layer",) + plan.shape_key + (
-        ex.edge_impl, int(model.hidden_nf), int(model.virtual_channels), D)
+        int(model.hidden_nf), int(model.virtual_channels), D)
     return ex.engine._compiled(
         key, lambda: jax.pmap(
             mapped, axis_name=ROUND_AXIS,

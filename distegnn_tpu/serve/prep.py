@@ -3,14 +3,14 @@
 Interactive clients (MD front-ends, trajectory viewers) stream many requests
 for the SAME scene: positions move every frame, but the edge topology — and
 therefore everything expensive about graph prep (Morton relabel, blocked
-re-pack, remote-edge classification, bucket assignment) — is identical or
+re-pack, bucket assignment) — is identical or
 changes rarely. The serve path previously redid that work per request.
 
 `SessionPrepCache` is a per-model LRU keyed on the client-supplied
 ``session_id``. Each entry holds a `PrepPlan`: the topology-only layout
-artifacts (`ops.blocked.RepackPlan`, the remote selection indices, the
-ladder bucket). A hit re-applies the plan to the fresh per-request arrays
-with fancy-index gathers only — no sort, no classify, no bucket math — and
+artifacts (`ops.blocked.RepackPlan`, the ladder bucket). A hit re-applies
+the plan to the fresh per-request arrays with fancy-index gathers only — no
+sort, no bucket math — and
 the produced dict carries the ``_blockified`` stamp so
 `prepare_blocked_graph` inside `pad_graphs` is a no-op.
 
@@ -92,7 +92,6 @@ class PrepPlan(NamedTuple):
     fingerprint: tuple
     bucket: Bucket                   # from the RAW (n, e) — the submit rung
     repack: Optional[RepackPlan]     # blocked layouts; None for plain
-    remote_sel: Optional[np.ndarray]  # row-sorted remote slot indices
     sort: Optional[np.ndarray]       # plain layouts: row-sort of raw edges
     edge_index: Optional[np.ndarray]  # plain layouts: the sorted edge list
 
@@ -128,7 +127,6 @@ class SessionPrepCache:
         opts = dict(layout_opts or {})
         self.edge_block = int(opts.get("edge_block", 0))
         self.edge_tile = int(opts.get("edge_tile", 512))
-        self.split_remote = bool(opts.get("split_remote", False))
         self._plans: "OrderedDict[str, object]" = OrderedDict()
         self._sizes: dict = {}
         self._bytes = 0
@@ -180,17 +178,14 @@ class SessionPrepCache:
             # sorted-scatter lowering; nothing else is topology-derived
             sort = np.argsort(ei[0], kind="stable")
             return PrepPlan(fingerprint=fp, bucket=bucket, repack=None,
-                            remote_sel=None, sort=sort,
+                            sort=sort,
                             edge_index=np.ascontiguousarray(ei[:, sort]))
         # blocked layout: mirror pad_batch's node snap exactly, then relabel
         # along the Morton curve and derive epb from the RELABELED rows (the
         # perm moves edges between blocks, so degree must be measured after)
         from distegnn_tpu.ops.order import morton_perm
 
-        nb = -(-bucket.n // self.edge_block)
-        if self.split_remote:
-            nb = max(nb, 3)  # fused kernel's VMEM window spans 3 blocks
-        N = nb * self.edge_block
+        N = _round_up(bucket.n, self.edge_block)
         perm = morton_perm(np.asarray(graph["loc"]), bits=self.bits)
         inv = np.empty_like(perm)
         inv[perm] = np.arange(n, dtype=perm.dtype)
@@ -199,14 +194,8 @@ class SessionPrepCache:
         epb = _round_up(max(deg, 1), self.edge_tile)
         plan = repack_blocked(ei2, None, n_nodes_padded=N, epb=epb,
                               block=self.edge_block)._replace(perm=perm)
-        remote_sel = None
-        if self.split_remote:
-            from distegnn_tpu.ops.edge_pipeline import remote_selection
-
-            remote_sel = remote_selection(plan.edge_index,
-                                          block=self.edge_block, n_nodes=N)
         return PrepPlan(fingerprint=fp, bucket=bucket, repack=plan,
-                        remote_sel=remote_sel, sort=None, edge_index=None)
+                        sort=None, edge_index=None)
 
     # ---- plan application ------------------------------------------------
     def _apply(self, graph: dict, plan: PrepPlan) -> dict:
@@ -234,8 +223,6 @@ class SessionPrepCache:
         g["_edge_mask"] = p.edge_mask
         g["_edge_pair"] = None       # serve batches run compute_pair=False
         g["_blockified"] = p.stamp
-        if plan.remote_sel is not None:
-            g["_remote_sel"] = plan.remote_sel
         return g
 
     # ---- the entry point -------------------------------------------------

@@ -88,15 +88,6 @@ class TiledExecutor:
         # session-cached) plan serves at any setting.
         self.devices = c["devices"] if c["devices"] == "auto" \
             else int(c["devices"])
-        layout = dict(getattr(engine, "_layout_opts", {}) or {})
-        model = engine.model
-        impl = str(getattr(model, "edge_impl", "plain") or "plain")
-        # fused_stack lowers to the per-layer fused path (identical params);
-        # the megakernel's whole-loop grid cannot host a per-tile scan
-        self.edge_impl = "fused" if impl in ("fused", "fused_stack") else "plain"
-        self.edge_block = (int(layout.get("edge_block", 512) or 512)
-                           if self.edge_impl == "fused" else 0)
-        self.edge_tile = int(layout.get("edge_tile", 512) or 512)
         g = self.engine.metrics.registry.gauge
         self._g_tiles = g("serve/tiled_tiles")
         self._g_halo = g("serve/tiled_halo_fraction")
@@ -117,20 +108,18 @@ class TiledExecutor:
 
     # ---- planning --------------------------------------------------------
     def plan(self, graph: dict) -> TilePlan:
-        """Morton tile plan for one scene (ops/tiling.plan_tiles with this
-        engine's layout). Cacheable per session (serve/prep.py)."""
+        """Morton tile plan for one scene (ops/tiling.plan_tiles).
+        Cacheable per session (serve/prep.py)."""
         return plan_tiles(
             np.asarray(graph["edge_index"]), np.asarray(graph["loc"]),
             np.asarray(graph["edge_attr"]) if graph.get("edge_attr") is not None else None,
             tile_nodes=self.tile_nodes, halo_floor=self.halo_floor,
-            edge_floor=self.edge_floor, growth=self.growth,
-            edge_block=self.edge_block, edge_tile=self.edge_tile)
+            edge_floor=self.edge_floor, growth=self.growth)
 
     def _plan_ok(self, plan: TilePlan, n: int) -> bool:
-        """A cached plan is reusable only if it was built for this layout
-        and scene size (a blue/green swap can change the edge impl)."""
-        return (plan.n_nodes == n and plan.edge_block == self.edge_block
-                and plan.tile_nodes == self.tile_nodes)
+        """A cached plan is reusable only if it was built for this scene
+        size and tile size."""
+        return plan.n_nodes == n and plan.tile_nodes == self.tile_nodes
 
     # ---- tile batch construction ----------------------------------------
     def _tile_batch(self, plan: TilePlan, spec, loc, vel, feat, node_attr,
@@ -160,16 +149,9 @@ class TiledExecutor:
             if h:
                 d_attr[plan.tile_nodes:plan.tile_nodes + h] = node_attr[halo]
             d["node_attr"] = d_attr
-        if plan.edge_block:
-            batch = pad_graphs([d], max_nodes=plan.padded_nodes,
-                               edge_block=plan.edge_block,
-                               edges_per_block=plan.edges_per_block,
-                               edge_tile=plan.edge_tile, compute_pair=False,
-                               split_remote=True, remote_pad=plan.remote_pad)
-        else:
-            batch = pad_graphs([d], max_nodes=plan.padded_nodes,
-                               max_edges=plan.edge_pad, node_bucket=1,
-                               edge_bucket=1)
+        batch = pad_graphs([d], max_nodes=plan.padded_nodes,
+                           max_edges=plan.edge_pad, node_bucket=1,
+                           edge_bucket=1)
         own = np.zeros((1, batch.node_mask.shape[1]), np.float32)
         own[0, :n_own] = 1.0
         return batch.replace(node_mask=own)
@@ -194,12 +176,8 @@ class TiledExecutor:
         (``_layer_fn`` jits it) and the device-parallel round executable
         (serve/mesh_tiled.py pmaps it over a round of D tiles)."""
         from distegnn_tpu.models.fast_egnn import EGCLVel
-        from distegnn_tpu.ops.blocked import blocked_slot_inv_deg
-        from distegnn_tpu.ops.edge_pipeline import build_edge_blocks
 
         model = self.engine.model
-        impl = self.edge_impl
-        blocked_impl = str(getattr(model, "blocked_impl", "einsum"))
         gravity = (jnp.asarray(model.gravity, jnp.float32)
                    if getattr(model, "gravity", None) is not None else None)
         layer = EGCLVel(
@@ -217,23 +195,13 @@ class TiledExecutor:
             hoist_edge_mlp=bool(getattr(model, "hoist_edge_mlp", True)),
             seg_impl=str(getattr(model, "segment_impl", "scatter")),
             fuse_agg=bool(getattr(model, "fuse_agg", True)),
-            agg_dtype=getattr(model, "agg_dtype", None),
-            edge_impl=impl)
+            agg_dtype=getattr(model, "agg_dtype", None))
 
         def fn(gcl_params, h, x, batch, X, Hv, cm):
-            slot, inv_deg, oh = blocked_slot_inv_deg(batch, blocked_impl)
-            fused_arrs = None
-            if impl == "fused":
-                fused_arrs = jax.vmap(
-                    lambda r, c, ea, em: build_edge_blocks(
-                        r, c, ea, em, block=batch.edge_block,
-                        n_nodes=batch.max_nodes)
-                )(batch.row, batch.col, batch.edge_attr, batch.edge_mask)
+            # a tile's batch is in the plain layout: no blocked slots
             return layer.apply(
                 {"params": gcl_params}, h, x, batch.vel, X, Hv, batch,
-                gravity=gravity, slot=slot, inv_deg=inv_deg, oh=oh,
-                fused_arrs=fused_arrs, tile_coord_mean=cm,
-                tile_partials=True)
+                gravity=gravity, tile_coord_mean=cm, tile_partials=True)
 
         return fn
 
@@ -244,8 +212,7 @@ class TiledExecutor:
         program (the round executable extends this key with D)."""
         model = self.engine.model
         key = ("tile_layer",) + plan.shape_key + (
-            self.edge_impl, int(model.hidden_nf),
-            int(model.virtual_channels))
+            int(model.hidden_nf), int(model.virtual_channels))
         return self.engine._compiled(
             key, lambda: jax.jit(self._layer_callable(plan)))
 

@@ -16,12 +16,6 @@ torch.Adam with L2 weight_decay folded into the gradient, optional
 grad-clip-by-global-norm(0.3), loss/accumulation_steps with a step every k
 micro-batches (optax.MultiSteps), optional cosine schedule over
 epochs*len(loader)/accumulation_steps.
-
-``model.edge_impl`` (plain vs fused Pallas edge pipeline) needs no branch
-here: the flag lives on the model object and its extra batch fields
-(``remote_edge_*``, built by loaders with ``split_remote=True``) ride the
-GraphBatch pytree through jit/shard_map untouched. The step stays one
-compiled program per (layout, model) pair either way.
 """
 
 from __future__ import annotations

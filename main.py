@@ -135,8 +135,6 @@ def main(argv=None):
         ds, config.data.batch_size, shuffle=shuffle, seed=config.seed,
         node_bucket=config.data.node_bucket, edge_bucket=config.data.edge_bucket,
         edge_block=config.data.edge_block,
-        split_remote=(config.model.get("edge_impl")
-                      in ("fused", "fused_stack")),
         # cumsum aggregation wants the reverse-edge pairing for scatter-free
         # col-gather backwards (plain layout; ops/segment.py)
         pairing=(True if (not config.data.edge_block and

@@ -7,20 +7,6 @@ Variants:
   segsum_flag        jax.ops.segment_sum(indices_are_sorted=True)
   gather             the read side (x[ids]) for comparison
   matmul_f32 / bf16  the edge-MLP matmul [E,128]x[128,64]
-  fused_edge_layer   the whole per-layer edge pipeline in ONE Pallas pass
-                     (ops/edge_pipeline.py) — geometry + phi_e + coord gate +
-                     all three aggregations; compare against the SUM of the
-                     unfused primitives above to see the traffic it removes.
-                     Off-TPU it runs interpret mode at a toy shape (the full
-                     shape would take hours interpreted).
-  fused_egnn_stack   the cross-layer megakernel (ops/layer_pipeline.py): ALL
-                     L layers in one Pallas grid with the graph VMEM-resident.
-                     Runs at the VMEM-capped shape (the stack must fit the 16
-                     MiB budget), and prints the analytic HBM-bytes-per-step
-                     model for plain / fused / fused_stack at both the capped
-                     and flagship shapes — the traffic ratio is the claim the
-                     megakernel makes, so the numbers and their assumptions
-                     are emitted next to the timing.
 """
 
 from __future__ import annotations
@@ -80,135 +66,7 @@ def main():
     print(f"gather             {timed(f_gather, x, ids_s):8.2f} ms")
     print(f"matmul_f32         {timed(f_mm, a, w):8.2f} ms")
     print(f"matmul_bf16        {timed(f_mm_bf16, a, w):8.2f} ms")
-    fused_edge_bench(rng)
-    fused_stack_bench(rng)
     tiled_exec_bench(rng)
-
-
-def fused_edge_bench(rng):
-    import jax
-    import jax.numpy as jnp
-
-    from distegnn_tpu.ops.edge_pipeline import (EdgeWeights, build_edge_blocks,
-                                                fused_edge_layer)
-
-    block = 512
-    on_tpu = jax.default_backend() == "tpu"
-    n_pad = (-(-N // block) * block) if on_tpu else 3 * block
-    nb = n_pad // block
-    per_block = -(-E // nb)  # ceil: worst block's share of the edges
-    epb = (-(-per_block // block) * block) if on_tpu else 3 * block
-    # blocked layout built directly: block b owns epb row-local edge slots,
-    # cols within one block of the row (always inside the 3-block window)
-    rows, cols = [], []
-    for b in range(nb):
-        r = np.sort(rng.integers(b * block, (b + 1) * block, size=epb))
-        c = np.clip(r + rng.integers(-block, block, size=epb), 0, n_pad - 1)
-        rows.append(r)
-        cols.append(c)
-    row = jnp.asarray(np.concatenate(rows).astype(np.int32))
-    col = jnp.asarray(np.concatenate(cols).astype(np.int32))
-    e_tot = int(row.shape[0])
-    attr = jnp.asarray(rng.normal(size=(e_tot, 2)).astype(np.float32))
-    mask = jnp.ones((e_tot,), jnp.float32)
-    row_t, col_l, kblk, scal = jax.jit(
-        lambda r, c, a, m: build_edge_blocks(r, c, a, m, block=block,
-                                             n_nodes=n_pad))(row, col, attr, mask)
-    xc = jnp.asarray(rng.normal(size=(n_pad, 3)).astype(np.float32))
-    hr = jnp.asarray(rng.normal(size=(n_pad, H)).astype(np.float32))
-    hc = jnp.asarray(rng.normal(size=(n_pad, H)).astype(np.float32))
-    wts = EdgeWeights(
-        ws=jnp.asarray(rng.normal(size=(3, H)).astype(np.float32)),
-        b1=jnp.zeros((1, H)), w2=jnp.asarray(rng.normal(size=(H, H)).astype(np.float32)),
-        b2=jnp.zeros((1, H)), w3=jnp.asarray(rng.normal(size=(H, H)).astype(np.float32)),
-        b3=jnp.zeros((1, H)), w4=jnp.asarray(rng.normal(size=(1, H)).astype(np.float32)))
-    def run(*args):
-        # scalar touching all three accumulators so none is DCE'd and the
-        # timed() sync fetch stays 1 element
-        t, cnt, ef = fused_edge_layer(*args, wts, block, "bf16")
-        return t[0, 0] + cnt[0] + ef[0, 0]
-
-    f = jax.jit(run)
-    ms = timed(f, xc, hr, hc, row_t, col_l, kblk, scal)
-    tag = "" if on_tpu else " (interpret, toy shape)"
-    print(f"fused_edge_layer   {ms:8.2f} ms  [N={n_pad}, E={e_tot}]{tag}")
-
-
-def fused_stack_bench(rng):
-    import jax
-    import jax.numpy as jnp
-
-    from distegnn_tpu.ops.edge_pipeline import build_edge_blocks
-    from distegnn_tpu.ops.layer_pipeline import (StackConfig,
-                                                 fused_egnn_stack,
-                                                 hbm_bytes_per_step,
-                                                 stack_weight_shapes)
-
-    block, L, C = 512, 4, 3
-    # VMEM-capped shape on EVERY backend: the whole stack must be resident,
-    # and the flagship shape exceeds the 16 MiB budget by design.
-    n_pad = 3 * block
-    nb = n_pad // block
-    epb = 3 * block
-    rows, cols = [], []
-    for b in range(nb):
-        r = np.sort(rng.integers(b * block, (b + 1) * block, size=epb))
-        c = np.clip(r + rng.integers(-block, block, size=epb), 0, n_pad - 1)
-        rows.append(r)
-        cols.append(c)
-    row = jnp.asarray(np.concatenate(rows).astype(np.int32))
-    col = jnp.asarray(np.concatenate(cols).astype(np.int32))
-    e_tot = int(row.shape[0])
-    attr = jnp.asarray(rng.normal(size=(e_tot, 2)).astype(np.float32))
-    mask = jnp.ones((e_tot,), jnp.float32)
-    edge_arrs = jax.jit(
-        lambda r, c, a, m: build_edge_blocks(r, c, a, m, block=block,
-                                             n_nodes=n_pad))(row, col, attr,
-                                                             mask)
-    R = 128  # masked-off remote tail: the pad path, zero live remote edges
-    remote_arrs = (jnp.zeros((R,), jnp.int32), jnp.zeros((R,), jnp.int32),
-                   jnp.zeros((R, 2), jnp.float32), jnp.zeros((R,), jnp.float32))
-    cfg = StackConfig(n_layers=L, block=block, hidden=H, channels=C,
-                      dtype_name="bf16")
-    wstack = {k: jnp.asarray(
-        rng.normal(size=(L,) + s).astype(np.float32) * 0.05)
-        for k, s in stack_weight_shapes(cfg).items()}
-    h0 = jnp.asarray(rng.normal(size=(n_pad, H)).astype(np.float32))
-    x0 = jnp.asarray(rng.normal(size=(n_pad, 3)).astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(n_pad, 3)).astype(np.float32) * 0.01)
-    X0 = jnp.asarray(rng.normal(size=(3, C)).astype(np.float32))
-    Hv0 = jnp.asarray(rng.normal(size=(H, C)).astype(np.float32))
-    nmask = jnp.ones((n_pad,), jnp.float32)
-
-    def run(*args):
-        h, x, X, Hv = fused_egnn_stack(cfg, *args, None, None, edge_arrs,
-                                       remote_arrs, wstack)
-        return h[0, 0] + x[0, 0] + X[0, 0] + Hv[0, 0]
-
-    f = jax.jit(run)
-    on_tpu = jax.default_backend() == "tpu"
-    ms = timed(f, h0, x0, v, X0, Hv0, nmask)
-    tag = "" if on_tpu else " (interpret, VMEM-capped shape)"
-    print(f"fused_egnn_stack   {ms:8.2f} ms  [N={n_pad}, E={e_tot}, L={L}]{tag}")
-
-    # Analytic HBM-bytes-per-step model (ops/layer_pipeline.hbm_bytes_per_step)
-    # — CPU-evidence-only until a hardware profile confirms it. Assumptions:
-    # bf16 compute streams, f32 state/checkpoints, remote tail at the padded
-    # width, every array read/written exactly as many times as the lowering's
-    # dataflow implies (no cache modeling).
-    print("hbm_bytes_per_step model (analytic; CPU evidence only):")
-    for label, (n, e, rp) in (
-            (f"capped  N={n_pad} E={e_tot}", (n_pad, e_tot, R)),
-            ("flagship N=113152 E=1639424", (113_152, 1_639_424, 8192))):
-        per = {impl: hbm_bytes_per_step(
-            impl, n_nodes=n, n_edges=e, hidden=H, channels=C, n_layers=L,
-            remote_pad=rp, node_attr_nf=2, dtype_name="bf16")["total"]
-            for impl in ("plain", "fused", "fused_stack")}
-        ratio = per["fused"] / per["fused_stack"]
-        print(f"  {label}: plain {per['plain'] / 1e9:7.3f} GB | "
-              f"fused {per['fused'] / 1e9:7.3f} GB | "
-              f"fused_stack {per['fused_stack'] / 1e9:7.3f} GB | "
-              f"fused/fused_stack = {ratio:.2f}x")
 
 
 def tiled_exec_bench(rng):

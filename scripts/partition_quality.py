@@ -35,7 +35,7 @@ TARGET_EDGES_PER_NODE = 15.0
 
 
 def fluid_cloud(n: int, seed: int = 0) -> np.ndarray:
-    """Uniform cloud at Fluid113K edge density (bench.py's workload)."""
+    """Uniform cloud at Fluid113K edge density."""
     rng = np.random.default_rng(seed)
     vol = n * (4.0 / 3.0) * np.pi * RADIUS**3 / TARGET_EDGES_PER_NODE
     side = max(vol ** (1.0 / 3.0), 2.0 * RADIUS)

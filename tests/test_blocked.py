@@ -107,18 +107,26 @@ def _nbody_like_graphs(rng, n_graphs=2, n=300):
     return graphs
 
 
-@pytest.mark.parametrize("blocked_impl", ["pallas", "einsum"])
-@pytest.mark.parametrize("compute_dtype", [None, "bf16"])
-def test_fastegnn_blocked_parity(compute_dtype, blocked_impl):
+@pytest.mark.parametrize("compute_dtype,blocked_impl,empty_blocks", [
+    (None, "pallas", 0), (None, "einsum", 0),
+    ("bf16", "pallas", 0), ("bf16", "einsum", 0),
+    (None, "pallas", 2), (None, "einsum", 2)])
+def test_fastegnn_blocked_parity(compute_dtype, blocked_impl, empty_blocks):
     """Same graphs, blocked vs plain layout -> same FastEGNN output + grads
-    (both blocked lowerings: Pallas kernels and the einsum contraction)."""
+    (both blocked lowerings: Pallas kernels and the einsum contraction).
+    ``empty_blocks``: ``max_nodes`` leaves that many trailing node blocks
+    with no real node and no real edge (what a loader's dataset-wide
+    ``max_nodes`` does to a small batch)."""
     from distegnn_tpu.models.fast_egnn import FastEGNN
 
     rng = np.random.default_rng(5)
     graphs = _nbody_like_graphs(rng)
     plain = pad_graphs([dict(g) for g in graphs])
-    blocked = pad_graphs([dict(g) for g in graphs], edge_block=BLOCK, edge_tile=TILE)
+    blocked = pad_graphs([dict(g) for g in graphs], edge_block=BLOCK, edge_tile=TILE,
+                         max_nodes=(2 + empty_blocks) * BLOCK)
     assert blocked.edge_block == BLOCK
+    assert blocked.max_nodes == (2 + empty_blocks) * BLOCK
+    assert not np.asarray(blocked.edge_mask).reshape(2, 2 + empty_blocks, -1)[:, 2:].any()
 
     model = FastEGNN(node_feat_nf=1, edge_attr_nf=2, hidden_nf=16,
                      virtual_channels=2, n_layers=2, compute_dtype=compute_dtype,

@@ -110,3 +110,79 @@ def test_configdict_attribute_access():
     c.a.b = 2
     assert c["a"]["b"] == 2
     assert c.to_dict() == {"a": {"b": 2}}
+
+
+def _removed_yaml(tmp_path, key, value):
+    import yaml
+
+    with open(CFG) as f:
+        raw = yaml.safe_load(f)
+    raw["model"][key] = value
+    path = tmp_path / "old.yaml"
+    path.write_text(yaml.safe_dump(raw))
+    return load_config(str(path))
+
+
+def _removed_cli(tmp_path, flag, value):
+    args = build_arg_parser().parse_args(["--config_path", CFG, flag, value])
+    return load_config(args.config_path, overrides={
+        k: v for k, v in vars(args).items() if k != "config_path"})
+
+
+def _removed_loader(tmp_path, cls_name, _):
+    import distegnn_tpu.data.loader as loader
+
+    # refused before the dataset is looked at
+    return getattr(loader, cls_name)([], 1, split_remote=True)
+
+
+@pytest.mark.parametrize("how,key,value", [
+    (_removed_yaml, "edge_impl", "fused"),
+    (_removed_yaml, "edge_impl", "fused_stack"),
+    (_removed_yaml, "edge_impl", "plain"),
+    (_removed_yaml, "stack_vmem_budget", 1),
+    (_removed_cli, "--edge_impl", "fused"),
+    (_removed_loader, "GraphLoader", None),
+    (_removed_loader, "ShardedGraphLoader", None),
+], ids=["yaml-edge_impl-fused", "yaml-edge_impl-fused_stack",
+        "yaml-edge_impl-plain", "yaml-stack_vmem_budget", "cli-edge_impl",
+        "GraphLoader-split_remote", "ShardedGraphLoader-split_remote"])
+def test_removed_key_is_refused_by_name(tmp_path, how, key, value):
+    """Input that still carries a key of the fused edge pipelines (deleted,
+    PR 32) is refused with the key's name and the reason — never run on the
+    path that is left, whatever value it carries."""
+    name = key.lstrip("-") if how is not _removed_loader else "split_remote"
+    with pytest.raises(ValueError, match=rf"{name}.* was removed: .*PR 32"):
+        how(tmp_path, key, value)
+
+
+@pytest.mark.parametrize("name,parts", [("largefluid_distegnn", 1),
+                                        ("nbody_fastegnn", None),
+                                        ("largefluid800k_distegnn", 4)])
+def test_benchmark_configs_build_their_model(name, parts):
+    """The seam the benchmark's drivers call (tier-1 does not run
+    benchmarks/tests): each of its yamls loads, validates, and gives a model
+    through the keywords train_stream.py (``parts`` partitions on the graph
+    axis) or train_scan.py (``parts`` None) passes to ``get_model``."""
+    from distegnn_tpu.config import validate_config
+    from distegnn_tpu.models.fast_egnn import FastEGNN
+    from distegnn_tpu.models.registry import get_model
+    from distegnn_tpu.parallel.mesh import GRAPH_AXIS
+
+    cfg = load_config(f"benchmarks/configs/{name}.yaml")
+    derive_runtime_fields(cfg, world_size=parts or 1)
+    validate_config(cfg)
+    if parts is None:
+        model = get_model(cfg.model, world_size=1,
+                          dataset_name=cfg.data.dataset_name)
+        assert model.axis_name is None
+    else:
+        mesh = (cfg.get("parallel") or {}).get("mesh") or {}
+        assert int(mesh.get("graph") or 1) == parts
+        model = get_model(cfg.model, world_size=parts,
+                          dataset_name=cfg.data.dataset_name,
+                          axis_name=GRAPH_AXIS, tensor_axis=None)
+        assert model.axis_name == GRAPH_AXIS
+    assert isinstance(model, FastEGNN)
+    assert (model.hidden_nf, model.n_layers, model.virtual_channels) == (64, 4, 3)
+    assert model.segment_impl == "scatter" and cfg.data.edge_block == 0

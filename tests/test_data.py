@@ -116,3 +116,28 @@ def test_sharded_loader_stacks_partitions(nbody_dir):
     batch = next(iter(sl))
     assert batch.loc.shape[0] == 2  # leading partition axis
     np.testing.assert_array_equal(batch.loc[0], batch.loc[1])
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["train_scan", "train_stream"])
+def test_loader_call_forms_of_the_benchmark_drivers(nbody_dir, sharded):
+    """benchmarks/drivers/train_scan.py:62-65 and train_stream.py:160-163
+    construct the loaders with exactly these keywords (tier-1 does not run
+    benchmarks/tests): the forms construct and yield a batch."""
+    paths = process_nbody_cutoff(nbody_dir, "nbody_10", max_samples=6, radius=-1,
+                                 frame_0=1, frame_T=3, cutoff_rate=0.0, tag="charged10_0_0_1")
+    ds = GraphDataset(paths[0])
+    if sharded:
+        loader = ShardedGraphLoader(
+            [ds, ds], 2, shuffle=True, seed=3,
+            node_bucket=8, edge_bucket=128, data_parallel=1,
+            edge_block=0, split_remote=False, pairing=None)
+        lead = (2, 2)       # [P, B]
+    else:
+        loader = GraphLoader(
+            ds, 2, shuffle=True, seed=3,
+            node_bucket=8, edge_bucket=128,
+            edge_block=0, split_remote=False, pairing=None)
+        lead = (2,)
+    batch = next(iter(loader))
+    assert batch.loc.shape == lead + (16, 3)
+    assert batch.edge_block == 0 and batch.edge_pair is None

@@ -613,12 +613,28 @@ def test_priority_classes_and_header_override(live):
     assert gw._priority_of(h, "rollout") == "bulk"     # bad value: default
 
 
+def _wait_drained(gw, timeout_s: float = 30.0) -> None:
+    """The module's gateway is shared: a handler thread of an earlier test
+    releases its slot just AFTER its client has the response, so the
+    counters reach zero a moment later than the test before returns."""
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        with gw._inflight_lock:
+            if gw._inflight == 0 and gw._inflight_bulk == 0:
+                return
+        time.sleep(0.01)
+    raise AssertionError(
+        f"gateway did not drain in {timeout_s} s: inflight={gw._inflight} "
+        f"bulk={gw._inflight_bulk}")
+
+
 def test_bulk_capped_below_interactive(live):
     """The bulk share of max_inflight is bounded; interactive still admits
     when every bulk slot is taken."""
     gw = live.gw
     cap = gw.bulk_max_inflight
     assert cap < gw.max_inflight
+    _wait_drained(gw)
     taken = 0
     try:
         for _ in range(cap):

@@ -164,3 +164,21 @@ def test_fastegnn_fuse_agg_bf16_compute(batch, rng):
     np.testing.assert_allclose(np.asarray(out_f[1], np.float32),
                                np.asarray(out_u[1], np.float32),
                                rtol=3e-2, atol=3e-2)
+
+
+def test_fastegnn_agg_dtype_bf16_within_bf16_band_of_f32(batch, rng):
+    """``agg_dtype: bf16`` on the model (the op alone:
+    test_agg_rows_pair_bf16_stream): the packed aggregation streams bf16 and
+    accumulates f32, so the prediction moves by bf16 rounding and no more."""
+    from distegnn_tpu.models.fast_egnn import FastEGNN
+
+    g = batch
+    kw = dict(node_feat_nf=2, edge_attr_nf=2, hidden_nf=16, virtual_channels=3,
+              n_layers=2)
+    m32, m16 = FastEGNN(**kw), FastEGNN(**kw, agg_dtype="bf16")
+    params = m32.init(jax.random.PRNGKey(0), g)
+    out32, out16 = m32.apply(params, g), m16.apply(params, g)
+    assert out16[0].dtype == jnp.float32
+    assert not np.array_equal(out16[0], out32[0])       # the knob is read
+    for a, b in zip(out16, out32):
+        np.testing.assert_allclose(a, b, rtol=3e-2, atol=3e-2)

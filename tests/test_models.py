@@ -235,3 +235,41 @@ def test_registry_serves_all_families(rng):
         params = model.init(jax.random.PRNGKey(0), gb)
         out, _ = model.apply(params, gb)
         assert np.all(np.isfinite(np.asarray(out))), name
+
+
+_TREE_KW = dict(node_feat_nf=1, edge_attr_nf=2, hidden_nf=16,
+                virtual_channels=2, n_layers=2)
+
+
+def _param_tree(model, gb):
+    """{path: (shape, dtype)} of the params ``model.init`` would make."""
+    tree = jax.eval_shape(model.init, jax.random.PRNGKey(0), gb)
+    return {jax.tree_util.keystr(k): (a.shape, str(a.dtype))
+            for k, a in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+@pytest.mark.parametrize("model_kw,edge_block", [
+    (dict(segment_impl=s), b) for s in ("scatter", "cumsum", "ell")
+    for b in (0, 256)
+] + [(kw, 0) for kw in (dict(blocked_impl="pallas"), dict(remat=True),
+                        dict(compute_dtype="bf16"), dict(fuse_agg=False),
+                        dict(agg_dtype="bf16"))],
+    ids=lambda v: "-".join(f"{k}={x}" for k, x in v.items()) if isinstance(v, dict) else f"block{v}")
+def test_fastegnn_param_tree_is_one_tree_whatever_the_lowering(rng, model_kw, edge_block):
+    """A checkpoint trains under one lowering and serves under any: names,
+    shapes and dtypes of FastEGNN's params do not depend on segment_impl,
+    blocked_impl, the batch layout, remat, the compute or aggregation dtype
+    or fuse_agg (``hoist_edge_mlp`` alone changes the tree, and says so)."""
+    from distegnn_tpu.models.fast_egnn import FastEGNN
+
+    g = _random_graph(rng, n=40, e=120, feat_nf=1, edge_nf=2)
+    want = _param_tree(FastEGNN(**_TREE_KW),
+                       pad_graphs([dict(g)], node_bucket=1, edge_bucket=1))
+    assert sum(int(np.prod(s)) for s, _ in want.values()) == 9474
+    if edge_block:
+        gb = pad_graphs([dict(g)], edge_block=edge_block)
+    else:   # cumsum and ell read the pairing and the static in-degree
+        gb = pad_graphs([dict(g)], node_bucket=1, edge_bucket=1,
+                        compute_pair=True)
+    got = _param_tree(FastEGNN(**_TREE_KW, **model_kw), gb)
+    assert got == want

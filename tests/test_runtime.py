@@ -121,73 +121,33 @@ def test_cumsum_kernels_lower_for_tpu(monkeypatch, what):
     assert "tpu_custom_call" in lowered.as_text()
 
 
-def test_fused_edge_layer_lowers_for_tpu(monkeypatch):
-    """ops/edge_pipeline.py is NOT in the smoke (Mosaic refuses its sublane
-    gather on the chip, ROADMAP S2), but the five JAX-level lowering repairs
-    of PR 21 are kept from rotting: forward and backward kernels, bf16 (the
-    flagship compute dtype), pass the Pallas TPU lowering."""
-    import numpy as np
-
-    from distegnn_tpu.ops.edge_pipeline import (EdgeWeights,
-                                                build_edge_blocks,
-                                                fused_edge_layer)
+@pytest.mark.parametrize("what", ["segment_sum", "gather", "paired_col_gather"])
+def test_blocked_kernels_lower_for_tpu(monkeypatch, what):
+    """ops/blocked.py's Pallas kernels (``blocked_impl: pallas``: the one-hot
+    segment sum, its adjoint gather, and the paired col gather whose backward
+    is the segment sum) pass the Pallas TPU lowering, forward and backward."""
+    from distegnn_tpu.ops import blocked
 
     monkeypatch.setattr(runtime, "use_interpret", lambda: False)
-    T, H, nb = 512, 64, 3
-    n, E = nb * T, nb * T
-    rng = np.random.default_rng(0)
-    row = np.sort(rng.integers(0, T, size=(nb, T)), axis=1) \
-        + np.arange(nb)[:, None] * T
-    col = rng.integers(0, n, size=E)
-    arrs = build_edge_blocks(
-        jnp.asarray(row.reshape(-1)), jnp.asarray(col),
-        jnp.asarray(rng.normal(size=(E, 2)).astype(np.float32)),
-        jnp.ones((E,), jnp.float32), block=T, n_nodes=n)
+    # nb=3 blocks of 256 nodes, 2 edge tiles a block; F=40 is this test's own
+    B, block, tile, nb, F = 1, 256, 512, 3, 40
+    N, E = nb * block, nb * 2 * tile
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
-    w = EdgeWeights(ws=f32(3, H), b1=f32(1, H), w2=f32(H, H), b2=f32(1, H),
-                    w3=f32(H, H), b3=f32(1, H), w4=f32(1, H))
-
-    def loss(x, hr, hc, w):
-        t, _, e = fused_edge_layer(x, hr, hc, *arrs, w, T, "bf16")
-        return jnp.sum(t) + jnp.sum(e)
-
-    # value AND grad: under grad alone the forward kernel is dead code
-    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).trace(
-        f32(n, 3), f32(n, H), f32(n, H), w).lower(lowering_platforms=("tpu",))
-    assert lowered.as_text().count("tpu_custom_call") >= 2   # fwd + bwd
-
-
-# ------------------------------------------------------------------- bench
-
-def _load_bench():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.join(REPO, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_peaks_table_refuses_unknown_device():
-    bench = _load_bench()
-    assert bench.device_peaks("TPU v5 lite")["hbm_gbps"] == 819.0
-    with pytest.raises(SystemExit, match="no published peaks"):
-        bench.device_peaks("cpu")
-
-
-def test_bench_race_exits_nonzero_when_a_leg_fails(monkeypatch, capsys):
-    """A race whose leg dies must not end in exit 0, and must never print a
-    0.0 under a metric's name."""
-    bench = _load_bench()
-    monkeypatch.setattr(bench, "RACE_ORDER",
-                        ((["--layout", "no-such-layout"], None),))
-    monkeypatch.setattr(sys, "argv", ["bench.py"])
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code not in (0, None)
-    out = capsys.readouterr()
-    assert "leg failed" in out.err
-    for line in out.out.splitlines():
-        if line.lstrip().startswith("{"):
-            assert json.loads(line).get("value") != 0.0
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    fn, args = {
+        "segment_sum": (
+            lambda d, s: blocked.blocked_segment_sum(d, s, N, block, tile),
+            (f32(B, E, F), i32(B, E))),
+        "gather": (
+            lambda h, s: blocked.blocked_gather(h, s, block, tile),
+            (f32(B, N, F), i32(B, E))),
+        "paired_col_gather": (
+            lambda h, c, p, s: blocked.paired_col_gather(h, c, p, s, block, tile),
+            (f32(B, N, F), i32(B, E), i32(B, E), i32(B, E))),
+    }[what]
+    # value AND grad: each kernel's VJP is the other kernel
+    lowered = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(fn(*a)))).trace(*args).lower(
+            lowering_platforms=("tpu",))
+    want = 1 if what == "paired_col_gather" else 2   # its forward is a take
+    assert lowered.as_text().count("tpu_custom_call") >= want

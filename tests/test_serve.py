@@ -338,8 +338,6 @@ def test_serve_bench_cli_one_json_line(capsys):
     assert rec["snapshot"]["cache_misses"] >= 1
 
 
-# ------------------------------------------------- fused_stack executables
-
 def test_serve_bench_rollout_leg_traces_on_cpu(capsys):
     """The rollout BENCH line can never silently vanish: a tiny CPU trace of
     `serve_bench.py --workload rollout` must emit exactly ONE JSON line with
@@ -364,57 +362,30 @@ def test_serve_bench_rollout_leg_traces_on_cpu(capsys):
     assert rec["snapshot"]["requests_completed"] == 2
 
 
-def test_fused_stack_one_executable_per_rung_and_obs_check(tmp_path):
-    """Cross-layer megakernel serving gate: a warmed rung under
-    ``edge_impl='fused_stack'`` serves every subsequent predict from exactly
-    ONE multi-layer executable — the cache key carries (edge_impl, L), no
-    per-layer entries exist, zero compiles land after warmup — and the obs
-    stream passes ``obs_report --check``."""
-    import os
-    import subprocess
-    import sys
+def test_blocked_layout_engine_one_executable_per_rung_and_matches_plain():
+    """``layout_opts={'edge_block': ...}`` serves every batch in the blocked
+    layout: a warmed rung is ONE executable (keyed on the resulting shapes,
+    edge_block included), later predicts hit it, and the prediction is the
+    plain-layout engine's."""
+    model = FastEGNN(node_feat_nf=1, edge_attr_nf=2, hidden_nf=16,
+                     virtual_channels=2, n_layers=2)
+    g = synthetic_graph(40, seed=1)
+    layout = dict(edge_block=32)
+    eng = InferenceEngine(model, None, max_batch=2, layout_opts=layout)
+    b0 = eng.ladder.bucket_of_graph(g)
+    init_batch, _ = eng.ladder.pad_batch([g], b0, 2, **layout)
+    eng.params = model.init(jax.random.PRNGKey(0), init_batch)
 
-    from distegnn_tpu.models.fast_egnn import FastEGNN as _FE
-    from distegnn_tpu.obs import jaxprobe, trace
+    warmed = eng.warmup([(40, g["edge_index"].shape[1])])
+    assert len(warmed) == 1
+    for _ in range(3):
+        out = eng.predict(g)
+    st = eng.cache_stats()
+    assert st["live"] == 1 and st["misses"] == 1 and st["hits"] == 3
+    (key,) = list(eng._cache)
+    assert key == ("predict", 64, 1024, 32, 2)   # N, E, edge_block, max_batch
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    log_dir = str(tmp_path / "obs")
-    trace.configure(log_dir=log_dir)
-    watcher = jaxprobe.install_compile_watcher()
-    try:
-        model = _FE(node_feat_nf=1, edge_attr_nf=2, hidden_nf=16,
-                    virtual_channels=2, n_layers=2, edge_impl="fused_stack")
-        g = synthetic_graph(40, seed=1)
-        layout = dict(edge_block=512, split_remote=True)
-        eng = InferenceEngine(model, None, max_batch=2, layout_opts=layout)
-        b0 = eng.ladder.bucket_of_graph(g)
-        init_batch, _ = eng.ladder.pad_batch([g], b0, 2, **layout)
-        eng.params = model.init(jax.random.PRNGKey(0), init_batch)
-
-        warmed = eng.warmup([(40, g["edge_index"].shape[1])])
-        assert len(warmed) == 1
-        st = eng.cache_stats()
-        assert st["live"] == 1 and st["misses"] == 1  # ONE executable, not L
-        (key,) = list(eng._cache)
-        assert key[-2:] == ("fused_stack", 2)  # the (rung, L) cache unit
-
-        watcher.mark_warmup_done()
-        for _ in range(3):
-            out = eng.predict(g)
-            assert out.shape == (40, 3) and np.isfinite(out).all()
-        st = eng.cache_stats()
-        assert st["live"] == 1 and st["misses"] == 1 and st["hits"] == 3
-        assert watcher.snapshot()["compiles_after_warmup"] == 0
-        trace.get_tracer().flush()
-    finally:
-        trace.configure(log_dir=None)
-        jaxprobe.deactivate_compile_watcher()
-
-    events = os.path.join(log_dir, "events.jsonl")
-    r = subprocess.run(
-        [sys.executable, os.path.join(repo, "scripts", "obs_report.py"),
-         events, "--check"],
-        capture_output=True, text=True, cwd=repo,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"}, timeout=120)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "obs_report --check: OK" in r.stderr
+    plain = InferenceEngine(model, eng.params, max_batch=2).predict(g)
+    assert out.shape == (40, 3)
+    np.testing.assert_allclose(out, plain, rtol=0, atol=1e-5 * max(
+        1.0, float(np.abs(plain).max())))

@@ -86,12 +86,9 @@ def test_lru_eviction_counts_and_drops_oldest():
 
 # ------------------------------------------------------------ blocked plans
 
-@pytest.mark.parametrize("split_remote", [False, True])
-def test_blocked_hit_bitwise_identical_and_stamped(split_remote):
-    block = 512 if split_remote else 256
-    cache = SessionPrepCache(
-        4, ladder=_ladder(),
-        layout_opts={"edge_block": block, "split_remote": split_remote})
+def test_blocked_hit_bitwise_identical_and_stamped():
+    cache = SessionPrepCache(4, ladder=_ladder(),
+                             layout_opts={"edge_block": 256})
     g = synthetic_graph(90, seed=4)
     miss = cache.prepare("s", g)
     hit = cache.prepare("s", g)
@@ -101,10 +98,26 @@ def test_blocked_hit_bitwise_identical_and_stamped(split_remote):
     assert out["_blockified"] is not None        # pad_graphs prep is a no-op
     assert out["_edge_pair"] is None
     assert miss.perm is not None and sorted(miss.perm) == list(range(90))
-    if split_remote:
-        assert out["_remote_sel"] is not None
     # the perm is undone by indexing: permuted loc at inverse matches raw
     np.testing.assert_array_equal(out["loc"], np.asarray(g["loc"])[miss.perm])
+
+
+def test_blocked_plan_stamp_is_the_one_pad_batch_derives():
+    """The plan mirrors ``pad_batch``'s node snap (bucket.n up to a block
+    multiple, no floor on the block count): the prepared dict goes through
+    ``pad_batch`` with its stamp intact, i.e. without a second blockify."""
+    ladder = _ladder()
+    cache = SessionPrepCache(4, ladder=ladder, layout_opts={"edge_block": 256})
+    g = synthetic_graph(90, seed=4)            # one block: bucket.n = 128
+    res = cache.prepare("s", g)
+    N, epb, block = res.graph["_blockified"]
+    assert (N, block) == (256, 256)
+    batch, _ = ladder.pad_batch([res.graph], res.bucket, 1, edge_block=256)
+    assert (batch.max_nodes, batch.edges_per_block) == (N, epb)
+    np.testing.assert_array_equal(np.asarray(batch.edge_index[0]),
+                                  res.graph["edge_index"])
+    np.testing.assert_array_equal(np.asarray(batch.edge_mask[0]),
+                                  res.graph["_edge_mask"])
 
 
 def test_blocked_plan_aggregation_parity():

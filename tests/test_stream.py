@@ -149,10 +149,9 @@ def test_streamed_epoch_bitwise_parity(tmp_path):
 
 @pytest.mark.io
 @pytest.mark.slow
-def test_streamed_epoch_parity_blocked_split_remote(tmp_path):
-    """The expensive lane: blocked layout + split_remote (the fused
-    pipeline's batch shape) over a streamed dataset — the loader's dataset
-    scans (edges-per-block, remote width) and blockify must see identical
+def test_streamed_epoch_parity_blocked(tmp_path):
+    """The expensive lane: blocked layout over a streamed dataset — the
+    loader's dataset scan (edges-per-block) and blockify must see identical
     graphs through the LRU."""
     graphs = _make_graphs(8, n_lo=40, n_hi=80, seed=1)
     write_shards(graphs, str(tmp_path), shard_size=2)

@@ -2,7 +2,7 @@
 
 - parity: a 2x2x2 (data x graph x tensor) mesh computes the SAME forward
   loss / gradients / optimizer step as the degenerate 2x2x1 mesh, on both
-  edge layouts (plain hoisted MLP and fused edge pipeline);
+  edge layouts (the plain batch and the blocked one);
 - cross-mesh checkpoints: params are saved FULL (never tensor-sliced), so a
   checkpoint written under mesh A restores under mesh B — with a typed error
   when the restoring tensor degree cannot divide the saved hidden width;
@@ -64,7 +64,7 @@ def test_mesh_3d_shape_and_product_check():
 # ---------------------------------------------------------------- parity
 
 @needs_8
-@pytest.mark.parametrize("leg", ["plain", "fused", "fused_stack"])
+@pytest.mark.parametrize("leg", ["plain", "blocked"])
 def test_tensor_parity_2x2x2_vs_2x2x1(leg):
     """fwd/grad/train-step within 1e-6 x max(1, scale) of the T=1 baseline —
     the dryrun parity harness, one edge layout per case."""

@@ -1,6 +1,6 @@
 """Tiled giant-scene serving (ops/tiling.py + serve/tiled.py + the engine /
 queue / gateway dispatch): exact parity of the tiled forward against the
-monolithic engine for plain AND fused edge impls, the byte-bounded session
+monolithic engine, the byte-bounded session
 prep cache, the BucketLadder rung boundary contract, and — slow lane — a
 million-node scene served end-to-end over HTTP through ONE compiled tile
 executable (CompileWatcher-certified, no recompile after warmup, no 413)."""
@@ -29,9 +29,9 @@ from distegnn_tpu.serve.transport import Gateway
 pytestmark = pytest.mark.serve
 
 
-def _model(impl="plain", n_layers=2):
+def _model(n_layers=2):
     return FastEGNN(node_feat_nf=1, edge_attr_nf=2, hidden_nf=16,
-                    virtual_channels=2, n_layers=n_layers, edge_impl=impl)
+                    virtual_channels=2, n_layers=n_layers)
 
 
 def _norm_err(pred, ref):
@@ -58,51 +58,37 @@ def test_plan_tiles_covers_every_node_and_edge_once():
     # the single-executable invariant: ONE padded shape serves every tile
     assert all(s.n_halo <= plan.halo_pad for s in plan.tiles)
     assert all(s.edge_index.shape[1] <= plan.edge_pad for s in plan.tiles)
-    assert plan.padded_nodes == plan.tile_nodes + plan.halo_pad  # plain layout
+    assert plan.padded_nodes == plan.tile_nodes + plan.halo_pad
     assert isinstance(plan.shape_key, tuple)
 
 
 # ----------------------------------------------------- tiled forward parity
 
-def test_tiled_parity_plain():
+@pytest.mark.parametrize("layout_opts", [None, {"edge_block": 32}],
+                         ids=["plain_engine", "blocked_engine"])
+def test_tiled_parity_plain(layout_opts):
     """Tiled executor == monolithic forward (1e-5 scale-normalized), halo
-    edges and virtual-node aggregation included — plain edge impl."""
-    model = _model("plain")
+    edges and virtual-node aggregation included. An engine that serves its
+    ladder batches blocked (``layout_opts``) still tiles giant scenes in the
+    plain layout: the tile plan has no block fields."""
+    model = _model()
     g = synthetic_graph(400, radius=0.2, seed=5)
     tight = pad_graphs([g], node_bucket=1, edge_bucket=1)
     params = model.init(jax.random.PRNGKey(0), tight)
     ref = np.asarray(model.apply(params, tight)[0])[0]
 
-    eng = InferenceEngine(model, params)
+    eng = InferenceEngine(model, params, layout_opts=layout_opts)
     tx = TiledExecutor(eng, {"tile_nodes": 128, "halo_floor": 16,
                              "edge_floor": 256})
-    out = tx.predict(dict(g))
+    plan = tx.plan(dict(g))
+    assert plan.shape_key == (plan.tile_nodes + plan.halo_pad, plan.edge_pad)
+    out = tx.predict(dict(g), plan=plan)
     assert out["tiles"] >= 2          # actually exercised halo exchange
     assert _norm_err(out["prediction"], ref) <= 1e-5
 
 
-def test_tiled_parity_fused():
-    """Same parity through the halo-aware fused edge pipeline (blocked
-    layout, split_remote) — the reuse-fused_edge_layer leg of the tentpole."""
-    model = _model("fused")
-    g = synthetic_graph(900, radius=0.2, seed=5)
-    batch = pad_graphs([dict(g)], max_nodes=1536, edge_block=512,
-                       edge_tile=512, split_remote=True, compute_pair=False)
-    params = model.init(jax.random.PRNGKey(0), batch)
-    ref = np.asarray(model.apply(params, batch)[0])[0, :900]
-
-    eng = InferenceEngine(model, params,
-                          layout_opts={"edge_block": 512,
-                                       "split_remote": True})
-    tx = TiledExecutor(eng, {"tile_nodes": 256, "halo_floor": 64,
-                             "edge_floor": 512})
-    out = tx.predict(dict(g))
-    assert out["tiles"] >= 2
-    assert _norm_err(out["prediction"], ref) <= 1e-5
-
-
 def test_tiled_overflow_is_typed_413_material():
-    model = _model("plain")
+    model = _model()
     g = synthetic_graph(50, seed=0)
     params = model.init(jax.random.PRNGKey(0),
                         pad_graphs([g], node_bucket=1, edge_bucket=1))
@@ -233,7 +219,7 @@ def _payload(g, **extra):
 def tiled_gateway():
     """Small ladder (cap 64) + tiled executor: a 300-node scene is above the
     cap and must dispatch to the tiled path instead of 413."""
-    model = _model("plain")
+    model = _model()
     g = synthetic_graph(300, radius=0.2, seed=7)
     tight = pad_graphs([g], node_bucket=1, edge_bucket=1)
     params = model.init(jax.random.PRNGKey(0), tight)
@@ -337,7 +323,7 @@ def test_million_node_scene_serves_with_one_executable(tmp_path):
     g = _lattice_scene(side)
     assert g["loc"].shape[0] == 1_000_000
 
-    model = _model("plain")
+    model = _model()
     tiny = synthetic_graph(20, seed=0)
     params = model.init(jax.random.PRNGKey(0),
                         pad_graphs([tiny], node_bucket=1, edge_bucket=1))

@@ -1,7 +1,7 @@
 """Device-parallel tiled serving (serve/mesh_tiled.py + ops/tiling.py round
 scheduling): LPT round planning, mesh-vs-sequential exactness on 8 virtual
-CPU devices (plain AND fused edge impls, ragged rounds included), the
-round-boundary disconnect contract, tile-plan portability across a devices
+CPU devices (ragged rounds included), the round-boundary disconnect
+contract, tile-plan portability across a devices
 reconfig, the one-executable-per-(shape_key, D) invariant, and — slow lane —
 a million-node scene through rounds of 8 with zero recompiles after warmup.
 
@@ -91,27 +91,14 @@ def test_resolve_devices_auto_clamp_and_degenerate():
 
 # --------------------------------------------- mesh-vs-sequential exactness
 
-def _seq_and_executor(impl="plain"):
-    if impl == "fused":
-        model = _model("fused")
-        g = synthetic_graph(900, radius=0.2, seed=5)
-        batch = pad_graphs([dict(g)], max_nodes=1536, edge_block=512,
-                           edge_tile=512, split_remote=True,
-                           compute_pair=False)
-        params = model.init(jax.random.PRNGKey(0), batch)
-        eng = InferenceEngine(model, params,
-                              layout_opts={"edge_block": 512,
-                                           "split_remote": True})
-        tx = TiledExecutor(eng, {"tile_nodes": 256, "halo_floor": 64,
-                                 "edge_floor": 512})
-    else:
-        model = _model("plain")
-        g = synthetic_graph(400, radius=0.2, seed=5)
-        tight = pad_graphs([g], node_bucket=1, edge_bucket=1)
-        params = model.init(jax.random.PRNGKey(0), tight)
-        eng = InferenceEngine(model, params)
-        tx = TiledExecutor(eng, {"tile_nodes": 128, "halo_floor": 16,
-                                 "edge_floor": 256})
+def _seq_and_executor():
+    model = _model()
+    g = synthetic_graph(400, radius=0.2, seed=5)
+    tight = pad_graphs([g], node_bucket=1, edge_bucket=1)
+    params = model.init(jax.random.PRNGKey(0), tight)
+    eng = InferenceEngine(model, params)
+    tx = TiledExecutor(eng, {"tile_nodes": 128, "halo_floor": 16,
+                             "edge_floor": 256})
     seq = tx.predict(dict(g))
     assert seq["tiles"] >= 2 and seq["devices"] == 1
     assert seq["rounds"] == seq["tiles"]    # sequential: one tile per round
@@ -121,7 +108,7 @@ def _seq_and_executor(impl="plain"):
 def test_mesh_parity_plain_even_rounds():
     """D divides the tile count: every round is full; parity is exact and
     the round count drops D-fold vs sequential on the SAME plan."""
-    g, tx, eng, seq = _seq_and_executor("plain")
+    g, tx, eng, seq = _seq_and_executor()
     T = seq["tiles"]
     D = 4
     assert T % D == 0
@@ -140,7 +127,7 @@ def test_mesh_parity_plain_even_rounds():
 def test_mesh_parity_plain_ragged_round():
     """Tile count NOT divisible by D: the last round carries zero-masked
     filler slots whose partials must contribute exactly nothing."""
-    g, tx, eng, seq = _seq_and_executor("plain")
+    g, tx, eng, seq = _seq_and_executor()
     T = seq["tiles"]
     D = 3
     assert T % D != 0
@@ -150,23 +137,10 @@ def test_mesh_parity_plain_ragged_round():
     assert _norm_err(out["prediction"], seq["prediction"]) <= 1e-6
 
 
-def test_mesh_parity_fused_ragged_round():
-    """Same exactness through the halo-aware fused edge pipeline (blocked
-    layout, split_remote) under pmap, ragged last round included."""
-    g, tx, eng, seq = _seq_and_executor("fused")
-    T = seq["tiles"]
-    D = 3
-    assert T % D != 0
-    tx.devices = D
-    out = tx.predict(dict(g))
-    assert out["devices"] == D and out["rounds"] == -(-T // D)
-    assert _norm_err(out["prediction"], seq["prediction"]) <= 1e-6
-
-
 def test_mesh_one_executable_per_shape_and_devices():
     """A mesh-only engine compiles exactly ONE tile-layer executable, keyed
     by the sequential rung key extended with D."""
-    model = _model("plain")
+    model = _model()
     g = synthetic_graph(400, radius=0.2, seed=5)
     tight = pad_graphs([g], node_bucket=1, edge_bucket=1)
     params = model.init(jax.random.PRNGKey(0), tight)
@@ -185,7 +159,7 @@ def test_mesh_one_executable_per_shape_and_devices():
 # ------------------------------------------- round-boundary cancel contract
 
 def test_mesh_disconnect_cancels_at_round_boundary():
-    g, tx, eng, seq = _seq_and_executor("plain")
+    g, tx, eng, seq = _seq_and_executor()
     tx.devices = 4
     seen = []
 
@@ -208,7 +182,7 @@ def test_tile_plan_portable_across_devices_reconfig():
     """A plan session-cached at devices: 1 is reused BITWISE (cache hit, no
     rebuild) after the executor is reconfigured to devices: 4 — shape_key
     carries no device count — and nbytes_of still charges the plan."""
-    model = _model("plain")
+    model = _model()
     g = synthetic_graph(400, radius=0.2, seed=5)
     tight = pad_graphs([g], node_bucket=1, edge_bucket=1)
     params = model.init(jax.random.PRNGKey(0), tight)
@@ -244,7 +218,7 @@ def test_tile_plan_portable_across_devices_reconfig():
 def mesh_gateway():
     """Tiled gateway with serve.tiled.devices: 4 — the 300-node scene above
     the cap serves through device-parallel rounds."""
-    model = _model("plain")
+    model = _model()
     g = synthetic_graph(300, radius=0.2, seed=7)
     tight = pad_graphs([g], node_bucket=1, edge_bucket=1)
     params = model.init(jax.random.PRNGKey(0), tight)
@@ -308,7 +282,7 @@ def test_million_node_mesh_rounds_one_executable(tmp_path):
 
     side = 100                          # 1_000_000 nodes
     g = _lattice_scene(side)
-    model = _model("plain")
+    model = _model()
     tiny = synthetic_graph(20, seed=0)
     params = model.init(jax.random.PRNGKey(0),
                         pad_graphs([tiny], node_bucket=1, edge_bucket=1))

@@ -600,6 +600,10 @@ def _count_gather_pass():
     obs.get_registry().counter("edge/gather_passes").add()
 
 
+def _count_sorted_row_pass():
+    obs.get_registry().counter("edge/sorted_row_passes").add()
+
+
 # what a rematted layer keeps of its edge passes (models/fast_egnn.py): the
 # names EdgeOps puts on those results, for ``save_only_these_names``
 REMAT_KEPT = ("edge_pre", "edge_diff", "edge_agg")
@@ -671,6 +675,10 @@ class EdgeOps:
                 return einsum_gather(data, self.oh)
             return blocked_gather(data, self.slot, self.g.edge_block,
                                   self.g.edge_tile)
+        if self.g.edges_sorted:
+            # every branch below transposes into a SORTED segment sum by a
+            # rule of its own (cumsum, ell, gather_rows_sorted)
+            _count_sorted_row_pass()
         if self.cumsum:
             from distegnn_tpu.ops.segment import gather_rows_cs
 
@@ -680,6 +688,10 @@ class EdgeOps:
 
             D = self.g.max_in_degree
             return jax.vmap(lambda h, r: gather_rows_ell(h, r, D))(data, self.g.row)
+        if self.g.edges_sorted:
+            from distegnn_tpu.ops.segment import gather_rows_sorted
+
+            return gather_rows_sorted(data, self.g.row)
         return jnp.take_along_axis(data, self.g.row[..., None], axis=1)
 
     @jax.named_scope("edge_gather")

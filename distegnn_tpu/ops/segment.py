@@ -57,6 +57,43 @@ def segment_mean(data, segment_ids, num_segments, mask=None, indices_are_sorted=
     return total / count.reshape((num_segments,) + (1,) * (data.ndim - 1))
 
 
+def sorted_row_sum(data, rows_sorted, num_segments):
+    """``sum_e data[e] -> rows_sorted[e]`` for ASCENDING ids: the scatter-add
+    that says so (``indices_are_sorted``), so the TPU compiler neither sorts
+    the ids nor permutes the rows first, as it does for an unhinted
+    scatter-add. Same additions, f32 stays f32; rows whose id is out of range
+    are dropped, padding rows land on the slot they name."""
+    return segment_sum(data, rows_sorted, num_segments, indices_are_sorted=True)
+
+
+@jax.custom_vjp
+def gather_rows_sorted(h, rows_sorted):
+    """BATCHED ``h[b, rows_sorted[b]]`` (``[B, N, F]``, ``[B, E]`` ->
+    ``[B, E, F]``) for ASCENDING ids (GraphBatch.edges_sorted) whose BACKWARD
+    is :func:`sorted_row_sum` by construction, not whatever autodiff makes of
+    an unhinted gather (on the TPU: a sort of the ids, a permutation of the
+    cotangent's rows and then the sorted scatter-add; PERF.md section 6, PR
+    33). The forward is the unhinted ``jnp.take_along_axis`` over the whole
+    batch, values bit for bit, and not a ``vmap`` of a per-graph gather: with
+    one graph a batch ``take_along_axis`` drops the batch axis from the gather,
+    which the TPU compiler then runs 2.6 to 5.7 times faster than the same
+    gather with a batching dimension (same section). Padding rows point at
+    slot N-1 and their cotangent lands there, as with the plain transpose."""
+    return jnp.take_along_axis(h, rows_sorted[..., None], axis=1)
+
+
+def _grs_fwd(h, rows_sorted):
+    return gather_rows_sorted(h, rows_sorted), (rows_sorted, h.shape[1])
+
+
+def _grs_bwd(res, g):
+    rows_sorted, n = res
+    return jax.vmap(lambda t, r: sorted_row_sum(t, r, n))(g, rows_sorted), None
+
+
+gather_rows_sorted.defvjp(_grs_fwd, _grs_bwd)
+
+
 def segment_max(data, segment_ids, num_segments, mask=None, initial=-1e30):
     """Per-segment max; empty segments yield ``initial``. Masked rows are
     replaced by ``initial`` before the scatter so they never win."""

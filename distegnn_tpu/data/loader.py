@@ -320,12 +320,19 @@ class GraphLoader:
         # thread runs under stall_attribution (prefetch producer), in which
         # case the same work overlaps compute and lands on data/produce_s
         stall = _stall_counter()
+        reg = obs.get_registry()
+        real_c, padded_c = reg.counter("data/real_edges"), reg.counter("data/padded_edges")
         for b in range(len(self)):
             t0 = time.perf_counter()
             idx = order[b * self.batch_size:(b + 1) * self.batch_size]
             batch = pad_graphs(
                 [self._graph(int(i)) for i in idx], **self.pad_kwargs(),
             )
+            # edge slots of the batch the step will compute on, and how many
+            # of them hold an edge (the rest is padding to the dataset's
+            # largest graph, or a blocked layout's interior padding)
+            real_c.add(int(np.count_nonzero(batch.edge_mask)))
+            padded_c.add(batch.edge_mask.size)
             stall.add(time.perf_counter() - t0)
             yield batch
 

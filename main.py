@@ -14,13 +14,19 @@ from __future__ import annotations
 
 import os
 import sys
+from typing import Any, Callable, NamedTuple
 
 import jax
 import numpy as np
 
 from distegnn_tpu import obs, runtime
 from distegnn_tpu.config import build_arg_parser, derive_runtime_fields, load_config
-from distegnn_tpu.data import GraphDataset, GraphLoader, process_nbody_cutoff
+from distegnn_tpu.data import (
+    GraphDataset,
+    GraphLoader,
+    PrefetchLoader,
+    process_nbody_cutoff,
+)
 from distegnn_tpu.models.registry import get_model
 from distegnn_tpu.train import (
     TrainState,
@@ -32,6 +38,7 @@ from distegnn_tpu.train import (
     train,
 )
 from distegnn_tpu.train.checkpoint import adopt_resume_seed, resolve_resume
+from distegnn_tpu.train.scan_epoch import ScanEpochRunner, dataset_nbytes, scan_enabled
 from distegnn_tpu.utils.seed import fix_seed
 
 # exit code of a preempted-but-resumable run (BSD EX_TEMPFAIL): a wrapper
@@ -99,57 +106,52 @@ def process_dataset_edge_cutoff(data_cfg, seed: int = 0):
     raise NotImplementedError(f"{name} has no cutoff-mode processor")
 
 
-def main(argv=None):
-    parser = build_arg_parser()
-    args = parser.parse_args(argv)
-    if getattr(args, "multihost", False):
-        init_multihost()
-    overrides = {k: v for k, v in vars(args).items() if k != "config_path"}
-    config = load_config(args.config_path, overrides=overrides)
+class CutoffRun(NamedTuple):
+    """What ``build_cutoff`` assembles and ``train()`` takes."""
 
-    if config.data.accelerate_mode == "distribute":
-        try:
-            from distegnn_tpu.parallel.launch import run_distributed
-        except ImportError as e:
-            raise NotImplementedError("distribute mode not built yet (SURVEY.md §7.2 stage 6)") from e
+    datasets: tuple          # GraphDataset of train, valid, test
+    loaders: tuple           # their bare GraphLoaders (model.init's sample, the scan runner)
+    feeds: tuple             # what train() iterates: the train loader behind PrefetchLoader, the other two bare
+    model: Any
+    tx: Any
+    state: TrainState        # fresh from model.init; a resume replaces it
+    step_factory: Callable
+    train_step: Callable
+    eval_step: Callable
+    scan_runner: Any         # ScanEpochRunner, or None: the host loop over feeds[0]
 
-        best = run_distributed(config)
-        _point_at_events()
-        return best
 
-    # cutoff_edges mode is single-device by contract (reference main.py:173
-    # asserts world_size == 1); an explicit conflicting --world_size is an error
-    ws = config.data.get("world_size")
-    if ws not in (None, 1):
-        raise ValueError(f"accelerate_mode=cutoff_edges is single-device; got --world_size {ws}")
-    derive_runtime_fields(config, world_size=1)
-    adopt_resume_seed(config)
-    fix_seed(config.seed)
-
-    # Data
-    files = process_dataset_edge_cutoff(config.data, seed=config.seed)
-    ds_train, ds_valid, ds_test = (
-        GraphDataset(f, node_order=config.data.node_order) for f in files)
-    obs.log(f"Data ready: {len(ds_train)}/{len(ds_valid)}/{len(ds_test)} graphs")
-    mk = lambda ds, shuffle: GraphLoader(
-        ds, config.data.batch_size, shuffle=shuffle, seed=config.seed,
-        node_bucket=config.data.node_bucket, edge_bucket=config.data.edge_bucket,
-        edge_block=config.data.edge_block,
+def build_cutoff(config, files) -> CutoffRun:
+    """Everything ``accelerate_mode: cutoff_edges`` trains with, from the
+    config and the three processed split files: one assembly for ``main`` and
+    for a benchmark driver that measures this path."""
+    d = config.data
+    datasets = tuple(GraphDataset(f, node_order=d.node_order) for f in files)
+    obs.log("Data ready: " + "/".join(str(len(ds)) for ds in datasets) + " graphs")
+    loaders = tuple(GraphLoader(
+        ds, d.batch_size, shuffle=(i == 0), seed=config.seed,
+        node_bucket=d.node_bucket, edge_bucket=d.edge_bucket,
+        edge_block=d.edge_block,
         # cumsum aggregation wants the reverse-edge pairing for scatter-free
         # col-gather backwards (plain layout; ops/segment.py)
-        pairing=(True if (not config.data.edge_block and
+        pairing=(True if (not d.edge_block and
                           config.model.get("segment_impl") in ("cumsum", "ell")) else None),
-    )
-    loader_train, loader_valid, loader_test = mk(ds_train, True), mk(ds_valid, False), mk(ds_test, False)
+    ) for i, ds in enumerate(datasets))
+    # collate + put of training batch k+1 overlap step k on a background
+    # thread, as in distribute mode (parallel/launch.py); depth 0 =
+    # synchronous. The put is the placement on the one device that the jitted
+    # step would do itself. The evaluation loaders stay bare
+    feeds = (PrefetchLoader(loaders[0], jax.device_put, depth=int(d.get("prefetch_depth", 2))),
+             *loaders[1:])
 
     # Model
-    model = get_model(config.model, world_size=1, dataset_name=config.data.dataset_name)
-    sample = next(iter(loader_train))
+    model = get_model(config.model, world_size=1, dataset_name=d.dataset_name)
+    sample = next(iter(loaders[0]))
     params = model.init(jax.random.PRNGKey(config.seed), sample)
     obs.log(f"Model: {config.model.model_name}, {count_parameters(params)} parameters")
 
     # Optimizer (+ reference clip rule and cosine schedule option)
-    total_steps = config.train.epochs * len(loader_train) // config.train.accumulation_steps
+    total_steps = config.train.epochs * len(loaders[0]) // config.train.accumulation_steps
 
     def build_tx(lr_scale: float = 1.0):
         return make_optimizer(
@@ -177,6 +179,54 @@ def main(argv=None):
                                        mmd_sigma=config.train.mmd.sigma,
                                        mmd_samples=config.train.mmd.samples))
 
+    train_step = step_factory(1.0)
+    eval_step = jax.jit(make_eval_step(model))
+
+    # scan_epochs: fold the epoch loop into one on-device lax.scan program
+    # (train/scan_epoch.py) when the dataset fits in HBM — kills the
+    # per-minibatch dispatch latency that dominates small-graph training
+    scan_runner = None
+    total = sum(dataset_nbytes(l) for l in loaders)
+    if scan_enabled(config.train.scan_epochs, total):
+        scan_runner = ScanEpochRunner(
+            train_step, eval_step, loaders[0], config.seed,
+            loader_valid=loaders[1], loader_test=loaders[2])
+        obs.log(f"scan_epochs: on ({total / 2**30:.2f} GiB device-resident)")
+    return CutoffRun(datasets, loaders, feeds, model, tx, state, step_factory,
+                     train_step, eval_step, scan_runner)
+
+
+def main(argv=None):
+    parser = build_arg_parser()
+    args = parser.parse_args(argv)
+    if getattr(args, "multihost", False):
+        init_multihost()
+    overrides = {k: v for k, v in vars(args).items() if k != "config_path"}
+    config = load_config(args.config_path, overrides=overrides)
+
+    if config.data.accelerate_mode == "distribute":
+        try:
+            from distegnn_tpu.parallel.launch import run_distributed
+        except ImportError as e:
+            raise NotImplementedError("distribute mode not built yet (SURVEY.md §7.2 stage 6)") from e
+
+        best = run_distributed(config)
+        _point_at_events()
+        return best
+
+    # cutoff_edges mode is single-device by contract (reference main.py:173
+    # asserts world_size == 1); an explicit conflicting --world_size is an error
+    ws = config.data.get("world_size")
+    if ws not in (None, 1):
+        raise ValueError(f"accelerate_mode=cutoff_edges is single-device; got --world_size {ws}")
+    derive_runtime_fields(config, world_size=1)
+    adopt_resume_seed(config)
+    fix_seed(config.seed)
+
+    files = process_dataset_edge_cutoff(config.data, seed=config.seed)
+    run = build_cutoff(config, files)
+    state = run.state
+
     start_epoch, start_step_in_epoch = 0, 0
     resumed = resolve_resume(config, state)
     if resumed is not None:
@@ -188,30 +238,10 @@ def main(argv=None):
         state, start_epoch, _ = restore_checkpoint(config.model.checkpoint, state)
         obs.log(f"Checkpoint loaded from {config.model.checkpoint} (epoch {start_epoch})")
 
-    train_step = step_factory(1.0)
-    eval_step = jax.jit(make_eval_step(model))
-
-    # scan_epochs: fold the epoch loop into one on-device lax.scan program
-    # (train/scan_epoch.py) when the dataset fits in HBM — kills the
-    # per-minibatch dispatch latency that dominates small-graph training
-    scan_runner = None
-    from distegnn_tpu.train.scan_epoch import (
-        ScanEpochRunner,
-        dataset_nbytes,
-        scan_enabled,
-    )
-
-    total = sum(dataset_nbytes(l) for l in (loader_train, loader_valid, loader_test))
-    if scan_enabled(config.train.scan_epochs, total):
-        scan_runner = ScanEpochRunner(
-            train_step, eval_step, loader_train, config.seed,
-            loader_valid=loader_valid, loader_test=loader_test)
-        obs.log(f"scan_epochs: on ({total / 2**30:.2f} GiB device-resident)")
-
     state, best_state, best, log_dict = train(
-        state, train_step, eval_step, loader_train, loader_valid, loader_test,
-        config, start_epoch=start_epoch, scan_runner=scan_runner,
-        start_step_in_epoch=start_step_in_epoch, step_factory=step_factory,
+        state, run.train_step, run.eval_step, *run.feeds,
+        config, start_epoch=start_epoch, scan_runner=run.scan_runner,
+        start_step_in_epoch=start_step_in_epoch, step_factory=run.step_factory,
     )
     if best.get("preempted"):
         obs.log(f"Preempted (resumable). Best so far: {best}")

@@ -604,6 +604,10 @@ def _count_sorted_row_pass():
     obs.get_registry().counter("edge/sorted_row_passes").add()
 
 
+def _count_row_sum_kernel():
+    obs.get_registry().counter("edge/row_sum_kernel").add()
+
+
 # what a rematted layer keeps of its edge passes (models/fast_egnn.py): the
 # names EdgeOps puts on those results, for ``save_only_these_names``
 REMAT_KEPT = ("edge_pre", "edge_diff", "edge_agg")
@@ -848,14 +852,16 @@ class EdgeOps:
             D = g.max_in_degree
             out = jax.vmap(lambda t, r: sorted_segment_sum_ell(
                 t, r, N, D).astype(jnp.float32))(packed, g.row)
-        else:
+        elif g.edges_sorted:
+            from distegnn_tpu.ops.segment import sorted_row_sum
+
             # f32 accumulator regardless of stream dtype (a bf16 scatter-add
-            # accumulator saturates); XLA fuses the convert into the scatter
-            # operand so the HBM read stays at stream width
+            # accumulator saturates); the stream is read at its own width
+            out = sorted_row_sum(packed, g.row, N, jnp.float32)
+        else:
             out = jax.vmap(lambda t, r: jnp.zeros(
                 (N, t.shape[-1]), jnp.float32).at[r].add(
-                    t.astype(jnp.float32),
-                    indices_are_sorted=g.edges_sorted))(packed, g.row)
+                    t.astype(jnp.float32)))(packed, g.row)
         # named before the division: neither the sum nor the count is made
         # again by a rematted layer's backward
         out = checkpoint_name(out, "edge_agg")

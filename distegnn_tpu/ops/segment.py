@@ -57,13 +57,73 @@ def segment_mean(data, segment_ids, num_segments, mask=None, indices_are_sorted=
     return total / count.reshape((num_segments,) + (1,) * (data.ndim - 1))
 
 
-def sorted_row_sum(data, rows_sorted, num_segments):
-    """``sum_e data[e] -> rows_sorted[e]`` for ASCENDING ids: the scatter-add
-    that says so (``indices_are_sorted``), so the TPU compiler neither sorts
-    the ids nor permutes the rows first, as it does for an unhinted
-    scatter-add. Same additions, f32 stays f32; rows whose id is out of range
-    are dropped, padding rows land on the slot they name."""
-    return segment_sum(data, rows_sorted, num_segments, indices_are_sorted=True)
+# Below this many rows (B x E) the kernel's launch and visit list are not
+# worth it: serving's small rungs keep the scatter-add.
+_MIN_KERNEL_ROWS = 32768
+
+
+def _row_sum_kernel_engages(rows: int) -> bool:
+    """The Pallas kernel runs on a TPU for ``rows`` >= :data:`_MIN_KERNEL_ROWS`
+    (the ``ops/cumsum.py`` idiom); elsewhere, the CPU included, the hinted
+    scatter-add. Tests patch this to run the kernel interpreted."""
+    return jax.default_backend() == "tpu" and rows >= _MIN_KERNEL_ROWS
+
+
+def _scatter_row_sum(data, rows_sorted, num_segments, dtype):
+    """The hinted scatter-add, graph by graph, into ``dtype`` (what the two
+    call sites ran before the kernel)."""
+    return jax.vmap(lambda t, r: jnp.zeros(
+        (num_segments, t.shape[-1]), dtype).at[r].add(
+            t.astype(dtype), indices_are_sorted=True))(data, rows_sorted)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _kernel_row_sum(data, rows_sorted, num_segments, dtype):
+    from distegnn_tpu.ops import row_sum
+
+    # the constants are read as this is traced, so that the micro-benchmark's
+    # sweep (scripts/microbench_segsum.py) can set them
+    return row_sum.row_sum(data, rows_sorted, num_segments, tile=row_sum.TILE,
+                           block=row_sum.BLOCK).astype(dtype)
+
+
+def _krs_fwd(data, rows_sorted, num_segments, dtype):
+    token = jnp.zeros(data.shape[:2] + (0,), data.dtype)
+    return _kernel_row_sum(data, rows_sorted, num_segments, dtype), (rows_sorted, token)
+
+
+def _krs_bwd(num_segments, dtype, res, g):
+    # the transpose of the scatter-add the kernel stands for: the same
+    # per-graph gather of the cotangent autodiff makes of it, unchanged
+    rows_sorted, token = res
+    data = jax.ShapeDtypeStruct(token.shape[:2] + g.shape[2:], token.dtype)
+    (ct,) = jax.linear_transpose(
+        lambda d: _scatter_row_sum(d, rows_sorted, num_segments, dtype), data)(g)
+    return ct, None
+
+
+_kernel_row_sum.defvjp(_krs_fwd, _krs_bwd)
+
+
+def sorted_row_sum(data, rows_sorted, num_segments, dtype=None):
+    """BATCHED ``out[b, n] = sum_e data[b, e] [rows_sorted[b, e] == n]``
+    (``[B, E, F]``, ``[B, E]`` -> ``[B, N, F]``) for ASCENDING ids
+    (GraphBatch.edges_sorted; padding rows at slot N-1), in ``dtype``
+    (default: the data's). On a TPU at ``B x E`` >= :data:`_MIN_KERNEL_ROWS`
+    one Pallas kernel over the whole batch (``ops/row_sum.py``: ids ``b N +
+    r`` ascend over it), accumulating in f32, f32 data contracted exactly;
+    its backward is the scatter-add's own transpose, a gather. Elsewhere the
+    scatter-add that says its ids are sorted, graph by graph, so the compiler
+    neither sorts them nor permutes the rows first. Each traced call that
+    takes the kernel adds one to the ``obs`` counter ``edge/row_sum_kernel``."""
+    dtype = jnp.dtype(dtype or data.dtype)
+    B, E = rows_sorted.shape
+    if not _row_sum_kernel_engages(B * E):
+        return _scatter_row_sum(data, rows_sorted, num_segments, dtype)
+    from distegnn_tpu.ops.blocked import _count_row_sum_kernel
+
+    _count_row_sum_kernel()
+    return _kernel_row_sum(data, rows_sorted, num_segments, dtype)
 
 
 @jax.custom_vjp
@@ -88,7 +148,7 @@ def _grs_fwd(h, rows_sorted):
 
 def _grs_bwd(res, g):
     rows_sorted, n = res
-    return jax.vmap(lambda t, r: sorted_row_sum(t, r, n))(g, rows_sorted), None
+    return sorted_row_sum(g, rows_sorted, n), None
 
 
 gather_rows_sorted.defvjp(_grs_fwd, _grs_bwd)

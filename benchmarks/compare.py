@@ -159,8 +159,10 @@ def decide(nums: dict, limits: dict):
 
 
 def reference_record(inputs: dict, w0: dict, half: bool = False, mlp_mantissa=None) -> dict:
-    from benchmarks.reference import fastegnn
+    """The plain reference of the configuration's family (``family.py``)
+    through the first steps."""
+    from benchmarks import family
 
-    return fastegnn.follow(w0, inputs["model"], inputs["train"], inputs["batches"],
-                           inputs["block"], half=half, mlp_mantissa=mlp_mantissa,
-                           edge_block=inputs.get("edge_block"))
+    return family.of(inputs["model"]).reference.follow(
+        w0, inputs["model"], inputs["train"], inputs["batches"], inputs["block"], half=half,
+        mlp_mantissa=mlp_mantissa, edge_block=inputs.get("edge_block"))

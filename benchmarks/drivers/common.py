@@ -1,5 +1,5 @@
-"""What the two training drivers share: the mapping between the benchmark's
-flat weights and the program's FastEGNN parameter tree, the program's
+"""What the training drivers share: the mapping between the benchmark's flat
+weights and the program's parameter tree (one for each family), the program's
 configuration loaded from the benchmark's copy of the yaml, and small helpers
 to look into an optax state. Drivers are the only benchmark code that imports
 ``distegnn_tpu``."""
@@ -16,8 +16,8 @@ _DENSE = ("Dense_0",)
 _COORD_HEADS = ("phi_x", "phi_xv", "phi_X")
 
 
-def tree_path(name: str) -> tuple:
-    """Flat weight name -> key path inside the program's ``params`` dict."""
+def fastegnn_tree_path(name: str) -> tuple:
+    """Flat weight name -> key path inside FastEGNN's ``params`` dict."""
     leaf = {"w": "kernel", "b": "bias"}
     if name == "virtual_feat":
         return ("virtual_node_feat",)
@@ -36,8 +36,31 @@ def tree_path(name: str) -> tuple:
     return (gcl, mlp) + mid + (f"TorchDense_{idx}",) + _DENSE + (leaf[kind],)
 
 
-def to_tree(weights: dict) -> dict:
-    """Flat weights -> ``{"params": ...}`` as ``FastEGNN.apply`` takes it."""
+def fasttfn_tree_path(name: str) -> tuple:
+    """Flat weight name -> key path inside FastTFN's ``params`` dict: its
+    ``phi_e`` is a plain MLP, the TFN sits under ``tfn_layer/conv_0``, whose
+    ``RadialFunc`` calls its three Denses ``Dense_i`` and its two
+    normalizations ``LayerNorm_i`` (weight ``scale``, bias ``bias``)."""
+    parts = name.split(".")
+    if len(parts) < 2 or parts[1] not in ("phi_e", "tfn"):
+        return fastegnn_tree_path(name)
+    gcl = "gcl_" + parts[0][1:]
+    leaf = {"w": "kernel", "b": "bias", "g": "scale"}
+    if parts[1] == "phi_e":
+        return (gcl, "phi_e", f"TorchDense_{parts[2]}") + _DENSE + (leaf[parts[3]],)
+    conv = (gcl, "tfn_layer", "conv_0")
+    if parts[2] == "self":
+        return conv + ("self_1",)
+    radial = {"r01": "radial_0_1", "r11": "radial_1_1"}[parts[2]]
+    sub = parts[3].replace("ln", "LayerNorm_") if parts[3].startswith("ln") else "Dense_" + parts[3]
+    return conv + (radial, sub, leaf[parts[4]])
+
+
+def to_tree(weights: dict, tree_path=fastegnn_tree_path) -> dict:
+    """Flat weights -> ``{"params": ...}`` as the family's ``apply`` takes it.
+    A driver calls it through its family (``family.Family.to_tree``); the
+    default is FastEGNN's mapping, for callers written before a second family
+    came."""
     root: dict = {}
     for name, value in weights.items():
         if name == "virtual_feat":
@@ -50,9 +73,9 @@ def to_tree(weights: dict) -> dict:
     return {"params": root}
 
 
-def to_plain(tree: dict, names) -> dict:
+def to_plain(tree: dict, names, tree_path=fastegnn_tree_path) -> dict:
     """The program's tree (parameters, gradients, moments) -> flat host
-    arrays under the benchmark's names."""
+    arrays under the benchmark's names (``tree_path`` as ``to_tree``'s)."""
     out = {}
     for name in names:
         node = tree["params"]
@@ -106,10 +129,12 @@ def load_meta(config_file: str) -> dict:
 
 
 def model_dims(cfg) -> dict:
+    """The sizes the benchmark's weights, reference and counts need, and the
+    family's name (``family.of`` refuses one it does not know)."""
     m = cfg.model
     return {k: int(m[k]) for k in ("hidden_nf", "n_layers", "virtual_channels",
                                    "node_feat_nf", "node_attr_nf", "edge_attr_nf")} | {
-        "normalize": bool(m.normalize)}
+        "normalize": bool(m.normalize), "model_name": str(m.model_name)}
 
 
 def train_spec(cfg, clip_norm) -> dict:

@@ -23,6 +23,7 @@ import time
 import jax
 import numpy as np
 
+from benchmarks import family
 from benchmarks.drivers import common, train_stream
 from benchmarks.reference import graphs as ref_graphs
 from benchmarks.reference.water3d_graphs import water_graph
@@ -36,6 +37,7 @@ class Driver:
         self.meta = common.load_meta(config_file)
         self.cfg = common.load_program_config(config_file, self.meta, seed, overrides)
         self.dims = common.model_dims(self.cfg)
+        self.family = family.of(self.dims)     # an unknown name ends the run here
         self.chips = 1
         self.first, self.losses = [], []
         self.count = 0
@@ -124,7 +126,7 @@ class Driver:
         self.cfg.seed = self.run.loaders[0].seed = int(seed)
         self.names = list(weights)
         self.w0 = {k: np.asarray(v) for k, v in weights.items()}
-        self.state = TrainState.create(common.to_tree(weights), self.tx)
+        self.state = TrainState.create(self.family.to_tree(weights), self.tx)
         self.first, self.losses = [], []
         self.count = 0
         self.epoch = 1          # train() numbers its epochs from 1
@@ -173,14 +175,14 @@ class Driver:
         clipped, so Adam's first moment after the first update is a tenth of
         the first gradient as the optimizer got it, weight decay folded in."""
         first = jax.device_get([{k: f[k] for k in ("loss", "loss_total")} for f in self.first])
-        plain = lambda state, field: common.to_plain(jax.device_get(
+        plain = lambda state, field: self.family.to_plain(jax.device_get(
             common.find_field(state.opt_state, field)), self.names)
         wd = float(self.cfg.train.weight_decay)
         grad = {k: 10.0 * v - wd * self.w0[k] for k, v in plain(self.state_first, "mu").items()}
         return {"loss": np.asarray([f["loss"] for f in first], np.float64),
                 "loss_total": np.asarray([f["loss_total"] for f in first], np.float64),
                 "grad": grad, "mu": plain(self.state_last, "mu"),
-                "w": common.to_plain(jax.device_get(self.state_last.params), self.names),
+                "w": self.family.to_plain(jax.device_get(self.state_last.params), self.names),
                 "w0": self.w0}
 
     def _raw_graph(self, k: int):

@@ -15,6 +15,7 @@ import time
 import jax
 import numpy as np
 
+from benchmarks import family
 from benchmarks.drivers import common
 from benchmarks.reference import graphs as ref_graphs
 
@@ -25,6 +26,7 @@ class Driver:
         self.meta = common.load_meta(config_file)
         self.cfg = common.load_program_config(config_file, self.meta, seed, overrides)
         self.dims = common.model_dims(self.cfg)
+        self.family = family.of(self.dims)     # an unknown name ends the run here
         self.chips = 1
         self.fed = []          # (perm, epoch_key) of every dispatched epoch
         self.losses = []
@@ -110,7 +112,7 @@ class Driver:
         self.cfg.seed = self.runner.seed = self.runner.loader.seed = int(seed)
         self.names = list(weights)
         self.w0 = {k: np.asarray(v) for k, v in weights.items()}
-        self.state = TrainState.create(common.to_tree(weights), self.tx)
+        self.state = TrainState.create(self.family.to_tree(weights), self.tx)
         self.fed, self.losses = [], []
         self.epoch = 1          # train() numbers its epochs from 1
         self._epoch()
@@ -145,8 +147,8 @@ class Driver:
         st = jax.device_get(self.state_last)
         return {"loss": np.asarray([self.losses[0]], np.float64), "loss_total": None,
                 "grad": None,
-                "mu": common.to_plain(common.find_field(st.opt_state, "mu"), self.names),
-                "w": common.to_plain(st.params, self.names), "w0": self.w0}
+                "mu": self.family.to_plain(common.find_field(st.opt_state, "mu"), self.names),
+                "w": self.family.to_plain(st.params, self.names), "w0": self.w0}
 
     def reference_inputs(self) -> dict:
         """The raw batches of the first epoch, as the runner's permutation

@@ -27,6 +27,7 @@ import time
 import jax
 import numpy as np
 
+from benchmarks import family
 from benchmarks.drivers import common
 from benchmarks.reference import graphs as ref_graphs
 
@@ -105,6 +106,7 @@ class Driver:
         self.meta = common.load_meta(config_file)
         self.cfg = common.load_program_config(config_file, self.meta, seed, overrides)
         self.dims = common.model_dims(self.cfg)
+        self.family = family.of(self.dims)     # an unknown name ends the run here
         mesh = (self.cfg.get("parallel") or {}).get("mesh") or {}
         self.chips = int(mesh.get("graph") or 1)      # partitions = devices on the graph axis
         self.first, self.losses = [], []
@@ -194,7 +196,7 @@ class Driver:
             ld.seed = int(seed)
         self.names = list(weights)
         self.w0 = {k: np.asarray(v) for k, v in weights.items()}
-        self.state = TrainState.create(common.to_tree(weights), self.tx)
+        self.state = TrainState.create(self.family.to_tree(weights), self.tx)
         self.first, self.losses = [], []
         self.count = 0
         self.epoch = 1          # train() numbers its epochs from 1
@@ -259,10 +261,10 @@ class Driver:
         """What the timed path produced in its first steps, under the
         benchmark's names (host arrays)."""
         first = jax.device_get([{k: f[k] for k in ("loss", "loss_total")} for f in self.first])
-        w_last = common.to_plain(jax.device_get(self.state_last.params), self.names)
-        grad = common.to_plain(jax.device_get(
+        w_last = self.family.to_plain(jax.device_get(self.state_last.params), self.names)
+        grad = self.family.to_plain(jax.device_get(
             common.find_field(self.state_first.opt_state, "acc_grads")), self.names)
-        inner_mu = common.to_plain(jax.device_get(
+        inner_mu = self.family.to_plain(jax.device_get(
             common.find_field(self.state_last.opt_state, "mu")), self.names)
         return {"loss": np.asarray([f["loss"] for f in first], np.float64),
                 "loss_total": np.asarray([f["loss_total"] for f in first], np.float64),

@@ -1,6 +1,6 @@
-"""Plain reference of FastEGNN / DistEGNN training: forward, loss (MSE + MMD),
-gradient and the torch-Adam update, in straightforward ``jax.numpy`` float32
-at matmul precision ``highest``.
+"""Plain reference of FastEGNN / DistEGNN training: the forward, in
+straightforward ``jax.numpy`` float32 at matmul precision ``highest``, and
+``follow``, which trains it by ``train.py``'s loss, gradient and Adam.
 
 Written from the paper's equations (arXiv:2506.19482, FastEGNN layer: real
 edge messages, C virtual nodes, three global means a layer) and the public
@@ -54,11 +54,8 @@ virtual nodes' means run over the nodes of ALL partitions (so ``V`` is one
 global set, and ``k(V,V)`` counts once because the shares ``n_p / n`` sum to
 1); each partition draws from its own nodes at the share ``n_p / n``.
 
-Departures from the published training script, each because the
-configuration as run states it: the MMD term draws its ``samples * C`` target
-nodes with replacement (the drawn indices are an input here, so both sides
-see the same nodes); MMD distances are floored at 1e-12 before the square
-root.
+The loss, its gradient by blocks of graphs, accumulation, clip and Adam are
+``train.py``'s, which every family's reference shares.
 """
 
 from __future__ import annotations
@@ -67,7 +64,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from benchmarks.reference import train
 
 EPS = 1e-8
 
@@ -134,13 +132,31 @@ def _edge_sums(p, normalize, mantissa, pack, w, h, x, row, col, eattr, ew):
             jax.ops.segment_sum(ew[:, None], row, n))
 
 
+def virtual_messages(w, p, mantissa, h, x, X, Hv):
+    """Virtual edges of layer ``p``, every node seeing the C virtual nodes:
+    (X_c - x_i [n,3,C], the messages phi_ev([h_i, Hv_c, |X_c - x_i|, M_c])
+    [n,C,H] with M = (X - mean x)^T (X - mean x)). FastTFN's are the same."""
+    n, H = h.shape
+    C = X.shape[1]
+    vdiff = X[None, :, :] - x[:, :, None]                        # [n,3,C]
+    vrad = jnp.sqrt(jnp.sum(vdiff * vdiff, axis=1))              # [n,C]
+    Xc = X - jnp.mean(x, axis=0)[:, None]
+    mX = Xc.T @ Xc                                               # [C,C]
+    v_in = jnp.concatenate([
+        jnp.broadcast_to(h[:, None, :], (n, C, H)),
+        jnp.broadcast_to(Hv.T[None], (n, C, H)),
+        vrad[:, :, None],
+        jnp.broadcast_to(mX[None], (n, C, C)),
+    ], axis=-1)
+    return vdiff, _mlp(w, p + "phi_ev", v_in, act_last=True, mantissa=mantissa)   # [n,C,H]
+
+
 def _layer(w, l, normalize, mantissa, edge_block, h, x, X, Hv, vel, attr, row, col, eattr, ew):
     """One FastEGNN layer on one graph. h [n,H], x [n,3], X [3,C] virtual
     coordinates, Hv [H,C] virtual features. ``edge_block``: None, or the
     number of edges worked at a time (module docstring)."""
     p = f"l{l}."
     n, H = h.shape
-    C = X.shape[1]
     mlp = functools.partial(_mlp, mantissa=mantissa)
 
     sums = functools.partial(_edge_sums, p, normalize, mantissa, edge_block is not None)
@@ -160,19 +176,7 @@ def _layer(w, l, normalize, mantissa, edge_block, h, x, X, Hv, vel, attr, row, c
         (m_sum, dx_sum, deg), _ = jax.lax.scan(
             lambda acc, blk: (jax.tree.map(jnp.add, acc, one(w, h, x, *blk)), None), zero, blocks)
     deg = jnp.maximum(deg, 1.0)
-
-    # virtual edges: every node sees the C virtual nodes
-    vdiff = X[None, :, :] - x[:, :, None]                        # [n,3,C]
-    vrad = jnp.sqrt(jnp.sum(vdiff * vdiff, axis=1))              # [n,C]
-    Xc = X - jnp.mean(x, axis=0)[:, None]
-    mX = Xc.T @ Xc                                               # [C,C]
-    v_in = jnp.concatenate([
-        jnp.broadcast_to(h[:, None, :], (n, C, H)),
-        jnp.broadcast_to(Hv.T[None], (n, C, H)),
-        vrad[:, :, None],
-        jnp.broadcast_to(mX[None], (n, C, C)),
-    ], axis=-1)
-    mv = mlp(w, p + "phi_ev", v_in, act_last=True)              # [n,C,H]
+    vdiff, mv = virtual_messages(w, p, mantissa, h, x, X, Hv)
 
     # coordinates: mean over incoming edges, mean over virtual nodes, velocity
     x_new = x + dx_sum / deg
@@ -208,25 +212,11 @@ def forward(w, model, g, mantissa=None, edge_block=None):
     return x, X
 
 
-def _kernel_sum(a, b, sigma, w=None):
-    """sum_ij w_i k(a_i, b_j); ``w`` None: every row of ``a`` at weight 1."""
-    d2 = jnp.sum((a[:, None, :] - b[None, :, :]) ** 2, axis=-1)
-    k = jnp.exp(-jnp.sqrt(jnp.maximum(d2, 1e-24)) / (2.0 * sigma * sigma))
-    return jnp.sum(k if w is None else k * w[:, None])
-
-
 def _block_terms(w, model, mmd, blk, mantissa, edge_block):
-    """Sums over one block of graphs: squared error over the rows that count
-    (``loss_rows``, all ones unless a fault is planted), k(V,V), k(samples,V),
-    the last with each drawn node at its weight ``mmd_w`` where the batch
-    carries one (the partitioned loss, module docstring)."""
+    """Sums over one block of graphs (``train.graph_terms`` of each)."""
     def one(g):
         pred, X = forward(w, model, g, mantissa, edge_block)
-        sse = jnp.sum((pred - g["target"]) ** 2 * g["loss_rows"][:, None])
-        V = X.T
-        k_vv = _kernel_sum(V, V, mmd["sigma"])
-        k_rv = _kernel_sum(g["target"][g["mmd_idx"]], V, mmd["sigma"], g.get("mmd_w"))
-        return sse, k_vv, k_rv
+        return train.graph_terms(pred, X, g, mmd)
 
     sse, k_vv, k_rv = jax.vmap(one)(blk)
     return jnp.sum(sse), jnp.sum(k_vv), jnp.sum(k_rv)
@@ -237,113 +227,16 @@ def _block_grad(w, blk, rows, *, model_key, mmd_key, G, mantissa=None, edge_bloc
     """(mse share, mmd share), gradient of their weighted sum, for one block
     of a batch of ``G`` graphs in which ``rows`` rows count towards the MSE.
     ``mantissa``: the control, see ``_dense``."""
-    model, mmd = dict(model_key), dict(mmd_key)
-    C = model["virtual_channels"]
-    S = mmd["samples"] * C
-
-    def loss(w):
-        sse, k_vv, k_rv = _block_terms(w, model, mmd, blk, mantissa, edge_block)
-        mse = sse / (rows * 3)
-        mmd_l = k_vv / G / C / C - 2.0 * k_rv / G / S / C
-        return mse + mmd["weight"] * mmd_l, (mse, mmd_l)
-
-    (_, (mse, mmd_l)), grads = jax.value_and_grad(loss, has_aux=True)(w)
-    return mse, mmd_l, grads
+    terms = functools.partial(_block_terms, mantissa=mantissa, edge_block=edge_block)
+    return train.loss_and_grad(terms, w, blk, rows, dict(model_key), dict(mmd_key), G)
 
 
-def _hashable(d):
-    return tuple(sorted(d.items()))
+_hashable = train.hashable
 
 
-def micro_step(w, model, mmd, batch, block, half=False, mantissa=None, edge_block=None):
-    """Loss and gradient of one micro-batch (``G`` stacked graphs), summed
-    over blocks of ``block`` graphs, each graph's edges walked ``edge_block``
-    at a time where that is given. Returns (mse, mse + weight*mmd, grads).
-
-    ``half`` plants the fault "half of the batch left out, the mean taken
-    over the rest": of several graphs the second half, of one graph the rows
-    its ``second_half`` marks (the half the program's loader puts last)."""
-    G, n = batch["loc"].shape[:2]
-    second = batch.pop("second_half") if "second_half" in batch else jnp.zeros((G, n))
-    if not half:
-        keep = jnp.ones((G, n), jnp.float32)
-    elif G > 1:
-        keep = jnp.broadcast_to((jnp.arange(G) < (G + 1) // 2)[:, None], (G, n)).astype(jnp.float32)
-    else:
-        keep = 1.0 - second.astype(jnp.float32)
-    batch = dict(batch, loss_rows=keep)
-    rows = jnp.sum(keep)
-    if G % block:
-        raise ValueError(f"batch of {G} graphs is not a multiple of block {block}")
-    mse = mm = 0.0
-    grads = None
-    with jax.default_matmul_precision("highest"):
-        for s in range(0, G, block):
-            blk = {k: v[s:s + block] for k, v in batch.items()}
-            a, b, g = _block_grad(w, blk, rows, model_key=_hashable(model),
-                                  mmd_key=_hashable(mmd), G=G, mantissa=mantissa,
-                                  edge_block=edge_block)
-            mse, mm = mse + a, mm + b
-            grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
-    return mse, mse + mmd["weight"] * mm, grads
-
-
-@functools.partial(jax.jit, static_argnames=("lr", "wd", "clip"))
-def _adam_update(w, g, mu, nu, t, *, lr, wd, clip):
-    """torch.optim.Adam with L2 weight decay folded into the gradient, after
-    an optional clip of the global norm; ``t`` counts updates from 1. Also
-    returns each leaf's norm of the gradient as the moments get it."""
-    if clip is not None:
-        norm = jnp.sqrt(sum(jnp.sum(x * x) for x in g.values()))
-        scale = jnp.where(norm < clip, 1.0, clip / norm)
-        g = {k: x * scale for k, x in g.items()}
-    g = {k: g[k] + wd * w[k] for k in g}
-    mu = {k: 0.9 * mu[k] + 0.1 * g[k] for k in g}
-    nu = {k: 0.999 * nu[k] + 0.001 * g[k] * g[k] for k in g}
-    c1, c2 = 1.0 - 0.9 ** t, 1.0 - 0.999 ** t
-    w = {k: w[k] - lr * (mu[k] / c1) / (jnp.sqrt(nu[k] / c2) + 1e-8) for k in g}
-    return w, mu, nu, {k: jnp.sqrt(jnp.sum(x * x)) for k, x in g.items()}
-
-
-def follow(w0, model, train, batches, block, half=False, mlp_mantissa=None, edge_block=None):
-    """Follow the first ``len(batches)`` micro-steps of training from ``w0``.
-
-    ``train``: learning_rate, weight_decay, clip_norm (or None),
-    accumulation_steps, mmd {sigma, weight, samples}. Returns host numpy:
-    ``loss`` [steps] (the logged MSE), ``loss_total`` [steps], ``grad_first``
-    (the first micro-batch's gradient), ``mu`` (Adam's first moment after the
-    last update), ``w`` (weights after the last micro-step), ``update_norms``
-    (per leaf, [updates]: the norm of each accumulated, clipped gradient as
-    Adam got it; ``mu`` is their decayed sum, so they bound it, and say how
-    large it is when they do not cancel). ``edge_block``: see the module docstring."""
-    acc_k = int(train["accumulation_steps"])
-    w = dict(w0)
-    mu = {k: jnp.zeros_like(v) for k, v in w.items()}
-    nu = {k: jnp.zeros_like(v) for k, v in w.items()}
-    acc, t = None, 0
-    losses, totals, norms, grad_first = [], [], [], None
-    for i, batch in enumerate(batches):
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        mse, total, g = micro_step(w, model, train["mmd"], batch, block, half=half,
-                                   mantissa=mlp_mantissa, edge_block=edge_block)
-        losses.append(mse)
-        totals.append(total)
-        if i == 0:
-            grad_first = g
-        acc = g if acc is None else jax.tree.map(jnp.add, acc, g)
-        if (i + 1) % acc_k == 0:
-            t += 1
-            mean = {k: v / acc_k for k, v in acc.items()}
-            clip = train.get("clip_norm")
-            w, mu, nu, norm = _adam_update(
-                w, mean, mu, nu, float(t), lr=float(train["learning_rate"]),
-                wd=float(train["weight_decay"]),
-                clip=None if clip is None else float(clip))
-            norms.append(norm)
-            acc = None
-    get = lambda tree: {k: np.asarray(v) for k, v in tree.items()}
-    norms = jax.device_get(norms)
-    return {"loss": np.asarray(jnp.stack(losses)),
-            "loss_total": np.asarray(jnp.stack(totals)),
-            "grad_first": get(grad_first), "mu": get(mu), "w": get(w),
-            "update_norms": {k: np.asarray([n[k] for n in norms], np.float32) for k in w}}
+def follow(w0, model, train_spec, batches, block, half=False, mlp_mantissa=None, edge_block=None):
+    """``train.follow`` with this forward: the first ``len(batches)``
+    micro-steps from ``w0``, ``block`` graphs at a time, each graph's edges
+    walked ``edge_block`` at a time where that is given (module docstring)."""
+    return train.follow(_block_grad, w0, model, train_spec, batches, block, half=half,
+                        mantissa=mlp_mantissa, edge_block=edge_block)

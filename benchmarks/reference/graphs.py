@@ -4,8 +4,9 @@ published pipelines define it (GLAD-RUC/DistEGNN ``datasets/process_dataset.py``
 Fluid113K   nodes carry features [viscosity, mass, |v|] and attributes
             [viscosity, mass]; a directed edge for every ordered pair closer
             than ``radius``; edge attributes [distance, distance].
-nbody_100   nodes carry features [|v|, charge / max charge], no attributes;
-            all ordered pairs; edge attributes [distance, distance].
+nbody_100   nodes carry features [|v|, charge / max charge], no attributes,
+            and the raw charge (``charge``, FastTFN's degree-0 input); all
+            ordered pairs; edge attributes [distance, distance].
 
 Independent of ``distegnn_tpu``: the neighbour search is scipy's k-d tree,
 nodes keep their raw order and edges come in the tree's order.
@@ -48,9 +49,10 @@ def nbody_graph(loc, vel, charges, target) -> dict:
     row, col = np.nonzero(~np.eye(n, dtype=bool))
     feat = np.concatenate([np.linalg.norm(vel, axis=1, keepdims=True),
                            charges / charges.max()], axis=1)
-    return _finish(np.asarray(loc, np.float32), np.asarray(vel, np.float32),
-                   np.asarray(target, np.float32), feat,
-                   np.zeros((n, 0), np.float32), row, col)
+    g = _finish(np.asarray(loc, np.float32), np.asarray(vel, np.float32),
+                np.asarray(target, np.float32), feat,
+                np.zeros((n, 0), np.float32), row, col)
+    return dict(g, charge=np.asarray(charges, np.float32).reshape(n))
 
 
 def stack(graphs: list, edges: int = None) -> dict:

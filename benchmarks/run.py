@@ -162,7 +162,7 @@ def run(argv=None, benchmark_file=None, platform=REQUIRED_PLATFORM) -> dict:
     device = check_device(int(cell["chips"]), platform)
     meter = CompileMeter()
 
-    from benchmarks import compare, tracing, weights
+    from benchmarks import compare, tracing
 
     # traffic/ and limits/ sit beside the directory of the configuration's file
     beside = os.path.dirname(os.path.dirname(os.path.join(base, config["file"])))
@@ -175,7 +175,7 @@ def run(argv=None, benchmark_file=None, platform=REQUIRED_PLATFORM) -> dict:
         if driver.chips != int(cell["chips"]):
             raise SystemExit(f"configuration {config['name']!r} runs on {driver.chips} chip(s), "
                              f"the cell asks for {cell['chips']}")
-        w0 = weights.make_weights(args.seed, driver.dims)
+        w0 = driver.family.make_weights(args.seed, driver.dims)
         driver.setup(w0)
         setup = {"setup_s": time.perf_counter() - _T_START, "compile_s": meter.seconds,
                  "data_prep_s": driver.prep_s}

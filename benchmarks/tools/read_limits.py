@@ -6,8 +6,9 @@ is long; the benchmark's own runs never call this):
         [--control] [--half 3] [--out chiprun_out/limits_W.jsonl]
 
 For each seed the cell's driver starts a fresh state from the seed, drives the
-first steps through the window's own call, and the plain reference follows
-them: one JSON line of the compared numbers, ``correct`` and ``over`` as
+first steps through the window's own call, and the plain reference of the
+configuration's family (``model.model_name``, ``benchmarks/family.py``)
+follows them: one JSON line of the compared numbers, ``correct`` and ``over`` as
 ``compare.decide`` gives them under the cell's own limits file (a control or
 a fault has to read ``correct`` false), with ``moment`` beside them:
 Adam's first moment over all leaves laid end to end (``diff``
@@ -64,7 +65,7 @@ def main() -> int:
     import jax
     import numpy as np
 
-    from benchmarks import compare, run, weights
+    from benchmarks import compare, run
     from benchmarks.drivers import common
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
@@ -133,7 +134,7 @@ def main() -> int:
     def program(seed):
         """(record, reference inputs, seconds) of ``seed``'s first steps."""
         t0 = time.perf_counter()
-        driver.start(weights.make_weights(seed, driver.dims), seed)
+        driver.start(driver.family.make_weights(seed, driver.dims), seed)
         return driver.program_record(), driver.reference_inputs(), time.perf_counter() - t0
 
     seeds = [int(s) for s in args.seeds.split(",")]

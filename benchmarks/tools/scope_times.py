@@ -323,7 +323,7 @@ def trace_cell(workload: str, seed: int, seconds, out: str, benchmark_file=None,
     -> (devices, spans, meta, HLO text), with the HLO text written under
     ``out`` before anything is joined. ``benchmark_file`` and ``platform``
     are for the tests, as in ``run.run``."""
-    from benchmarks import run, weights
+    from benchmarks import run
 
     benchmark_file = benchmark_file or os.path.join(ROOT, "BENCHMARK.json")
     bench, cell, config = run.load_cell(benchmark_file, workload)
@@ -351,7 +351,7 @@ def trace_cell(workload: str, seed: int, seconds, out: str, benchmark_file=None,
     trace_dir = os.path.join(ROOT, "benchmarks", ".work", "trace_kept", cell["name"])
     with contextlib.redirect_stdout(sys.stderr):
         driver = driver_mod.Driver(config_file, mix, seed)
-        driver.setup(weights.make_weights(seed, driver.dims))
+        driver.setup(driver.family.make_weights(seed, driver.dims))
         shutil.rmtree(trace_dir, ignore_errors=True)
         obs.clear_spans()
         jax.profiler.start_trace(trace_dir)

@@ -28,7 +28,7 @@ from distegnn_tpu.models.common import (
     MLP, CoordMLP, HoistedEdgeMLP, TorchDense, resolve_dtype,
 )
 from distegnn_tpu.ops.blocked import (REMAT_KEPT, EdgeOps,
-                                      blocked_slot_inv_deg)
+                                      blocked_slot_inv_deg, col_sorted_view)
 from distegnn_tpu.ops.graph import GraphBatch
 from distegnn_tpu.ops.segment import masked_sum
 from distegnn_tpu.parallel.collectives import (
@@ -104,6 +104,8 @@ class EGCLVel(nn.Module):
         slot: Optional[jnp.ndarray] = None,     # [B, E] blocked-layout slots
         inv_deg: Optional[jnp.ndarray] = None,  # [B, N, 1] 1/max(in-degree, 1)
         oh: Optional[jnp.ndarray] = None,       # [B, nb, epb, block] einsum incidence
+        # plain layout: ([B, E] col_sorted, [B, E] col_order), EdgeOps.gather_cols
+        col_view: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
         # tiled serving (serve/tiled.py): the layer runs over ONE tile of a
         # larger scene. tile_coord_mean is the precomputed SCENE-global
         # coordinate mean (replaces psum #1 — a tile-local mean would be
@@ -120,7 +122,8 @@ class EGCLVel(nn.Module):
         node_mask = g.node_mask                      # [B, N]
         edge_mask = g.edge_mask                      # [B, E]
         nm = node_mask[..., None]
-        ops = EdgeOps(g, slot, inv_deg, oh, seg_impl=self.seg_impl)
+        ops = EdgeOps(g, slot, inv_deg, oh, seg_impl=self.seg_impl,
+                      col_view=col_view)
 
         if self.coords_agg not in ("sum", "mean"):
             raise ValueError(f"Wrong coords_agg parameter {self.coords_agg!r}")
@@ -372,6 +375,9 @@ class FastEGNN(nn.Module):
         # blocked layout: slot ids + in-degree reciprocal (+ einsum incidence),
         # shared by all layers
         slot, inv_deg, oh = blocked_slot_inv_deg(g, self.blocked_impl)
+        # plain layout: the col-sorted view, one sort shared by all layers and
+        # kept by a rematted layer as an input, never sorted again
+        col_view = col_sorted_view(g)
 
         layer_cls = EGCLVel
         if self.remat:
@@ -399,7 +405,7 @@ class FastEGNN(nn.Module):
                 agg_dtype=self.agg_dtype,
                 name=f"gcl_{i}",
             )(h, x, v, X, Hv, g, gravity=gravity, slot=slot, inv_deg=inv_deg,
-              oh=oh)
+              oh=oh, col_view=col_view)
 
         return x, X
 

@@ -596,12 +596,30 @@ def blocked_slot_inv_deg(g, impl: str = "einsum"):
     return slot, 1.0 / jnp.maximum(deg, 1.0), oh
 
 
+def col_sorted_view(g):
+    """``(col_sorted, col_order)`` of a plain GraphBatch (ops/segment.py
+    :func:`sort_cols`), or None when g is blocked. Wrappers call this ONCE per
+    forward, beside :func:`blocked_slot_inv_deg` and outside any rematted
+    layer: the col list is layer-invariant, so one sort serves the L col
+    transposes (:meth:`EdgeOps.gather_cols`). A forward without a backward
+    reads nothing of it, and the compiler drops the sort."""
+    if g.edge_block > 0:
+        return None
+    from distegnn_tpu.ops.segment import sort_cols
+
+    return sort_cols(g.col)
+
+
 def _count_gather_pass():
     obs.get_registry().counter("edge/gather_passes").add()
 
 
 def _count_sorted_row_pass():
     obs.get_registry().counter("edge/sorted_row_passes").add()
+
+
+def _count_sorted_col_pass():
+    obs.get_registry().counter("edge/sorted_col_passes").add()
 
 
 def _count_row_sum_kernel():
@@ -630,7 +648,10 @@ class EdgeOps:
     ``slot``/``inv_deg``/``oh`` come from :func:`blocked_slot_inv_deg`
     (hoisted once per forward; plain arrays, so layers stay remat-able).
     ``oh is not None`` selects the einsum lowering, otherwise the Pallas
-    kernels.
+    kernels. ``col_view`` comes from :func:`col_sorted_view` (hoisted the
+    same way): with it the plain col gather transposes into a sorted segment
+    sum over the col-sorted edges (ops/segment.py ``gather_cols_sorted``);
+    without it, into autodiff's unsorted scatter-add.
 
     Both directions pack what shares an index set into ONE pass, because a
     gather or a scatter on the chip costs per ROW, not per byte (PERF.md
@@ -657,8 +678,9 @@ class EdgeOps:
     it is TRACED (a count per compiled program, not per step)."""
 
     def __init__(self, g, slot=None, inv_deg=None, oh=None,
-                 seg_impl: str = "scatter"):
+                 seg_impl: str = "scatter", col_view=None):
         self.g, self.slot, self.inv_deg, self.oh = g, slot, inv_deg, oh
+        self.col_view = col_view
         self.blocked = slot is not None
         if seg_impl not in ("scatter", "cumsum", "ell"):
             raise ValueError(f"unknown seg_impl {seg_impl!r}")
@@ -719,6 +741,11 @@ class EdgeOps:
             D = g.max_in_degree
             return jax.vmap(lambda h, c, p, r, m: paired_gather_cols_ell(
                 h, c, p, r, m, D))(data, g.col, g.edge_pair, g.row, g.edge_mask)
+        if self.col_view is not None:
+            from distegnn_tpu.ops.segment import gather_cols_sorted
+
+            _count_sorted_col_pass()
+            return gather_cols_sorted(data, g.col, *self.col_view)
         return jnp.take_along_axis(data, g.col[..., None], axis=1)
 
     @jax.named_scope("edge_gather")

@@ -4,7 +4,7 @@ generator (``benchmarks/traffic/generate.py``), one candidate lowering beside
 the other. A hand tool: no cell runs it, a CPU run of it times nothing.
 
     python3 scripts/microbench_segsum.py [--shapes fluid113k,fluid800k,nbody,water3d]
-        [--candidates today,hint,rule,cumsum,parts,kernel] [--steps 10] [--no-layer]
+        [--candidates today,hint,rule,cumsum,parts,kernel,col] [--steps 10] [--no-layer]
         [--profile]
     python3 scripts/microbench_segsum.py --count [--shapes ...]
 
@@ -44,6 +44,17 @@ Candidates (``--candidates``), of ``sum_e ct[e] -> node row[e]`` over ascending
            64]`` streams, the mask and the count column packed, then
            summed: the packed operand the kernel makes XLA write is in
            the time)
+  col      the COL-end transpose, ``sum_e ct[e] -> node col[e]`` over the
+           unsorted ``col``: autodiff's transpose of the unhinted
+           ``jnp.take_along_axis`` (``col_transpose/today``, the parent's)
+           against the col-sorted view (``ops/segment.py`` ``sort_cols`` +
+           ``gather_cols_sorted``: one sort, one flat gather of the
+           cotangent into col order, ``sorted_row_sum``) with the kernel
+           (``view_kernel``) and with the hinted scatter (``view_rule``);
+           the view's parts alone (``col_parts/sort``, ``perm_gather``,
+           ``kernel``); and the layer gradient with the view
+           (``layer_grad/col_view``) and with it held off
+           (``layer_grad/col_off``), the kernel on in both
 
 For each: the forward gather, the transpose alone, and (unless ``--no-layer``)
 ``jax.grad`` of one FastEGNN layer of the cell's configuration with that row
@@ -51,7 +62,7 @@ pass. Prints ms a call and ns a row (B x E rows); ``--profile`` traces three
 calls of each layer gradient and lists its dearest device ops; the JSON goes
 to ``chiprun_out/microbench_segsum.json``. ``--count`` times nothing: it
 traces each shape's cell configuration at the cell's step batch and prints
-the ``edge/row_sum_kernel`` count (8 on the chip, 0 on the CPU).
+the ``edge/row_sum_kernel`` count (12 on the chip, 0 on the CPU).
 """
 
 from __future__ import annotations
@@ -71,7 +82,7 @@ if ROOT not in sys.path:
 
 WIDTH = 67                      # a layer's packed width: H = 64 products + 3 coordinates
 SHAPES = ("fluid113k", "fluid800k", "nbody", "water3d")
-CANDIDATES = ("today", "hint", "rule", "cumsum", "parts", "kernel")
+CANDIDATES = ("today", "hint", "rule", "cumsum", "parts", "kernel", "col")
 # (TILE, BLOCK) of ops/row_sum.py tried alone; the fastest is kept. PR 36's
 # first sweep also tried the operand as [E, F] rows and the three bf16 terms
 # as three passes or at 'highest': slower everywhere (PERF.md section 6)
@@ -214,6 +225,22 @@ def row_pass(candidate: str):
             EdgeOps.gather_rows = kept
 
 
+@contextlib.contextmanager
+def col_view_engaged(on: bool):
+    """FastEGNN's col-sorted view (``ops/blocked.py`` ``col_sorted_view``) as
+    the tree makes it (``on``) or held off, so that the col gather transposes
+    as autodiff's unsorted scatter-add."""
+    from distegnn_tpu.models import fast_egnn
+
+    kept = fast_egnn.col_sorted_view
+    if not on:
+        fast_egnn.col_sorted_view = lambda g: None
+    try:
+        yield
+    finally:
+        fast_egnn.col_sorted_view = kept
+
+
 def dearest_ops(fn, args, calls: int = 3, top: int = 8) -> list:
     """``[(op, ms a call)]`` of the device ops that took longest in ``calls``
     traced calls of ``fn``."""
@@ -313,9 +340,10 @@ def aggregate(g, candidate: str):
     return jax.jit(agg)
 
 
-def layer_grad(config_file: str, g, candidate: str):
+def layer_grad(config_file: str, g, candidate: str, col_view: bool = True):
     """jitted ``params, g -> grad`` of one FastEGNN layer of the cell's
-    configuration (its dtype, remat and matmul precision), and the params."""
+    configuration (its dtype, remat and matmul precision), and the params;
+    ``col_view`` False holds the col-sorted view off."""
     import jax
     import jax.numpy as jnp
     from benchmarks.drivers import common
@@ -331,7 +359,7 @@ def layer_grad(config_file: str, g, candidate: str):
         x, _ = model.apply(params, g)
         return jnp.sum((x - g.target) ** 2 * g.node_mask[..., None])
 
-    with row_pass(candidate):
+    with row_pass(candidate), col_view_engaged(col_view):
         params = model.init(jax.random.PRNGKey(0), g)
         fn = jax.jit(jax.grad(loss))
         fn.lower(params, g)            # traced here, under the candidate's row pass
@@ -339,10 +367,60 @@ def layer_grad(config_file: str, g, candidate: str):
     return fn, params
 
 
+def col_pass(g, ct, steps: int, report) -> dict:
+    """The ``col`` candidate's transposes and parts (see the module's
+    docstring), each with its worst segment against autodiff's transpose,
+    relative to the segment's sum of absolute values."""
+    import jax
+    import jax.numpy as jnp
+    from distegnn_tpu.ops.segment import gather_cols_sorted, sort_cols, sorted_row_sum
+
+    B, E = g.col.shape
+    N = g.node_mask.shape[1]
+    col, one = g.col, jnp.float32(1.0)
+    zeros = jnp.zeros((B, N, WIDTH), jnp.float32)
+
+    def today(ct, s, c):
+        return jax.vjp(lambda t: jnp.take_along_axis(t, c[..., None], axis=1),
+                       zeros)[1](ct * s)[0]
+
+    def view(ct, s, c):
+        cs, co = sort_cols(c)
+        return jax.vjp(lambda t: gather_cols_sorted(t, c, cs, co), zeros)[1](ct * s)[0]
+
+    fn = jax.jit(today)
+    report("col_transpose/today", timed(fn, ct, one, col, steps=steps))
+    want = np.asarray(fn(ct, one, col))
+    scale = np.asarray(jax.jit(lambda ct, c: jax.vmap(
+        lambda d, i: jnp.zeros((N, WIDTH), jnp.float32).at[i].add(jnp.abs(d)))(ct, c))(
+            ct, col))
+    found = {}
+    for on, name in ((True, "view_kernel"), (False, "view_rule")):
+        with kernel_engaged(on):
+            # a fresh function each: jit keys its cache on the function
+            fn = jax.jit(lambda ct, s, c: view(ct, s, c))
+            report(f"col_transpose/{name}", timed(fn, ct, one, col, steps=steps))
+            err = float((np.abs(np.asarray(fn(ct, one, col)) - want)
+                         / np.maximum(scale, 1e-30)).max())
+        found[f"rel_err/col_{name}"] = err
+        print(f"worst segment |col {name} - today| / sum|ct| = {err:.3e}", flush=True)
+    sort = jax.jit(sort_cols)
+    report("col_parts/sort", timed(sort, col, steps=steps))
+    cs, co = sort(col)
+    perm = jax.jit(lambda ct, s, co: (ct * s).reshape(B * E, WIDTH).at[
+        (co + (jnp.arange(B, dtype=co.dtype) * E)[:, None]).reshape(-1)].get(
+            mode="promise_in_bounds", unique_indices=True).reshape(B, E, WIDTH))
+    report("col_parts/perm_gather", timed(perm, ct, one, co, steps=steps))
+    with kernel_engaged(True):
+        fn = jax.jit(lambda d, s, r: sorted_row_sum(d * s, r, N))
+        report("col_parts/kernel", timed(fn, perm(ct, one, co), one, cs, steps=steps))
+    return found
+
+
 def count_kernel_calls(shape: str) -> float:
     """``edge/row_sum_kernel`` over ONE traced gradient of the cell's model
     configuration at the cell's step-batch shapes (:data:`CELL_SHAPES`;
-    traced only, nothing compiled or run): ``2 x L`` where the kernel engages
+    traced only, nothing compiled or run): ``3 x L`` where the kernel engages
     (a TPU, ``B x E`` over the row minimum), 0 elsewhere."""
     import jax
     import jax.numpy as jnp
@@ -399,6 +477,17 @@ def bench_shape(shape: str, candidates, steps: int, layer: bool, small: bool,
 
     if "kernel" in candidates:
         out["kernel_variants"] = kernel_variants(ct, g.row, N, steps, report)
+
+    if "col" in candidates:
+        out.update(col_pass(g, ct, steps, report))
+        for view in (True, False) if layer else ():
+            name = f"layer_grad/col_{'view' if view else 'off'}"
+            fn, params = layer_grad(config_file, g, "kernel", col_view=view)
+            report(name, timed(fn, params, g, steps=steps))
+            if profile:
+                out[name]["dearest_ops"] = ops = dearest_ops(fn, (params, g))
+                for op, ms in ops:
+                    print(f"    {ms:8.3f} ms  {op[:230]}", flush=True)
 
     sums = {}
     for c in candidates:

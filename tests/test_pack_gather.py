@@ -196,11 +196,12 @@ def _edge_eqns(jaxpr, found, outer=""):
                          ids=["f32", "bf16_remat"])
 def test_two_gathers_and_two_scatter_adds_a_layer(rng, kw):
     """The gradient's jaxpr: per layer one gather per edge end and, as its
-    transpose, one scatter-add per edge end (four and four before the pack),
-    one segment sum and, as its transpose, one gather. Remat repeats NONE of
-    them: the rematted layer keeps what the edge passes return (it repeated
-    the forward's two gathers and the segment sum while it kept the layer's
-    inputs alone)."""
+    transpose, one scatter-add per edge end (four and four before the pack;
+    the col end's transpose first gathers the cotangent into col order, so
+    the edge gathers are three a layer), one segment sum and, as its
+    transpose, one gather. Remat repeats NONE of them: the rematted layer
+    keeps what the edge passes return (it repeated the forward's two gathers
+    and the segment sum while it kept the layer's inputs alone)."""
     g = _batch(rng, "scatter")
     model = FastEGNN(**MODEL, **kw)
     params = model.init(jax.random.PRNGKey(0), g)
@@ -209,7 +210,7 @@ def test_two_gathers_and_two_scatter_adds_a_layer(rng, kw):
     jaxpr = jax.make_jaxpr(jax.grad(_loss(model, g), argnums=(0, 1)))(params, g.loc, g.node_feat)
     assert passes.value - before == 2 * L
     found = _edge_eqns(jaxpr.jaxpr, [])
-    assert found.count(("edge_gather", "gather")) == 2 * L
+    assert found.count(("edge_gather", "gather")) == 3 * L
     assert found.count(("edge_gather", "scatter-add")) == 2 * L
     assert found.count(("edge_aggregate", "scatter-add")) == L
     assert found.count(("edge_aggregate", "gather")) == L
@@ -245,8 +246,10 @@ def test_remat_keeps_what_the_edge_passes_return_and_nothing_else_of_edge_size(r
         assert {v for v in named if E not in v[0]} <= {((B, N, 3), f32), ((B, N, H), f32)}
     else:
         assert named == sorted((edge + [((B, N, 3 + H + 1), f32)]) * L)
-    # nothing else with an edge axis lives from the forward to the backward
-    assert sorted((s, d) for s, d, _ in kept if E in s) == sorted(edge * L)
+    # nothing else with an edge axis lives from the forward to the backward,
+    # but for the plain batch's col-sorted view, made once for all layers
+    view = [((B, E), jnp.dtype(jnp.int32))] * 2 if lowering == "scatter" else []
+    assert sorted((s, d) for s, d, _ in kept if E in s) == sorted(edge * L + view)
     # while without remat the MLPs' edge-sized activations do
     kept = _kept(_model(lowering, compute_dtype=dtype), params, g)
     assert sum(E in s for s, _, _ in kept) > 10 * L

@@ -180,10 +180,11 @@ CONFIGS = {"bf16_remat": dict(compute_dtype="bf16", remat=True),
 def test_row_sum_kernel_counter_reads_2L_a_step_program(rng, config, monkeypatch,
                                                         fresh_traces):
     """``edge/row_sum_kernel``: a traced training step (forward and
-    gradient) takes the kernel ``2 x L`` times, the L aggregations and the L
-    row transposes, under remat too (a rematted layer keeps the sum, and the
-    transpose is traced once); 0 where it does not engage (the CPU here, and
-    a TPU below the row minimum)."""
+    gradient) takes the kernel ``3 x L`` times, the L aggregations, the L
+    row transposes and the L col transposes (over the col-sorted view), under
+    remat too (a rematted layer keeps the sum, and the transposes are traced
+    once); 0 where it does not engage (the CPU here, and a TPU below the row
+    minimum)."""
     g = _graphs(rng)
     model = FastEGNN(**MODEL, **CONFIGS[config])
     params = model.init(jax.random.PRNGKey(0), g)
@@ -195,7 +196,7 @@ def test_row_sum_kernel_counter_reads_2L_a_step_program(rng, config, monkeypatch
     _force_kernel(monkeypatch)
     before = counter.value
     jax.make_jaxpr(step)(params, g.loc)
-    assert counter.value - before == 2 * L
+    assert counter.value - before == 3 * L
 
 
 def test_the_kernel_engages_on_a_tpu_at_the_row_minimum(monkeypatch):
